@@ -24,11 +24,9 @@ FL cases:
                        its equivalence is pinned by tests/test_wire.py
                        and it is a real-TPU perf lever only.
 
-The compile-cache experiment runs LAST (it flips the process-global
-jax persistent-cache config): a fresh temp cache dir, two scheme
-builds of the fl_delayed_int4 case, AOT-compile each — cold seeds the
-cache, warm must deserialize (scripts/ci.sh gates warm < 20% cold on
-the train-driver path).
+The persistent compile cache is gated across processes in scripts/ci.sh
+(two `launch.train --aot-warmup` runs sharing one
+JAX_COMPILATION_CACHE_DIR).
 
     PYTHONPATH=src python -m benchmarks.scaled --quick
 """
@@ -38,14 +36,13 @@ import argparse
 import dataclasses
 import json
 import os
-import tempfile
 import time
 
 import numpy as np
 
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig, WirelessConfig
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.nn import use_mesh
 from repro.schemes import Experiment, build_scheme
 
@@ -81,22 +78,6 @@ def _wcfg(case: str):
 CASES = ("cl", "fl", "sl", "fl_barrier_q4", "fl_delayed_int4")
 
 
-def _compile_cache_walls(cfg, shape) -> dict:
-    """Cold-vs-warm AOT compile of the fl_delayed_int4 round program
-    against a FRESH persistent cache dir. Process-global config flip —
-    call after the timing cases."""
-    from repro.launch.compile_cache import enable_persistent_cache
-    d = tempfile.mkdtemp(prefix="repro_jax_cache_")
-    enable_persistent_cache(d)
-    w = _wcfg("fl_delayed_int4")
-    with use_mesh(make_test_mesh()):
-        cold = build_scheme(w, cfg=cfg, shape=shape).warmup_compile()
-        warm = build_scheme(w, cfg=cfg, shape=shape).warmup_compile()
-    return {"cache_dir": d, "cold_compile_s": round(cold, 4),
-            "warm_compile_s": round(warm, 4),
-            "warm_frac": round(warm / max(cold, 1e-9), 4)}
-
-
 def run(full: bool = False, seed: int = 0) -> dict:
     steady_cycles = 8 if full else 4      # >=4 post-compile samples
     cycles = 1 + steady_cycles
@@ -106,7 +87,7 @@ def run(full: bool = False, seed: int = 0) -> dict:
            "batch": shape.global_batch,
            "baseline_pr5_fl_steady_s": BASELINE_PR5_FL_STEADY_S,
            "cases": {}}
-    with use_mesh(make_test_mesh()):
+    with use_mesh(make_mesh((1, 1), ("data", "model"))):
         for case in CASES:
             walls, t0 = [], [time.perf_counter()]
 
@@ -136,7 +117,6 @@ def run(full: bool = False, seed: int = 0) -> dict:
                 "final_loss": res.loss[-1],
                 "final_accuracy": res.final_accuracy,
             }
-    out["compile_cache"] = _compile_cache_walls(cfg, shape)
     return out
 
 
@@ -155,9 +135,6 @@ def main(full: bool = False):
     d = res["cases"]["fl_delayed_int4"]["steady_wall_s"]
     rows.append("scaled,fl_delayed_int4,speedup_vs_pr5_baseline,"
                 f"{res['baseline_pr5_fl_steady_s'] / max(d, 1e-9):.2f}")
-    cc = res["compile_cache"]
-    rows.append(f"scaled,compile_cache,cold_s,{cc['cold_compile_s']:.4f}")
-    rows.append(f"scaled,compile_cache,warm_s,{cc['warm_compile_s']:.4f}")
     return rows
 
 

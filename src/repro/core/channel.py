@@ -76,7 +76,7 @@ def flip_bits(key, codewords: jax.Array, n_bits: int, p) -> jax.Array:
     where the old path paid `n_bits` separate bernoulli draws. `p`
     broadcasts against `codewords` (per-row fading)."""
     rand = jax.random.bits(key, codewords.shape, jnp.uint32)
-    return codewords ^ W.bit_flip_mask(rand, n_bits, p)
+    return codewords ^ W.bit_flip_mask(rand, n_bits, W.flip_threshold(p))
 
 
 def transmit_quantized(key, x: jax.Array, bits: int, snr_db: float,

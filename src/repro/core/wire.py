@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quantization as Q
+from repro.kernels import resolve_interpret
 
 WIRE_COLS = 256     # packed row width (lane-size multiple)
 _ROW_ALIGN = 8      # float32 sublane tile: R padded to a multiple of this
@@ -87,12 +88,20 @@ def fmix32(x: jax.Array) -> jax.Array:
     return x
 
 
-def bit_flip_mask(rand: jax.Array, n_bits: int, p) -> jax.Array:
-    """XOR mask with each of the low `n_bits` planes set iid w.p. `p`,
-    derived from ONE uint32 word per element. `p` is float32 and
-    broadcasts against `rand` (e.g. a per-row [R, 1] vector). Shared by
-    the jnp paths and the Pallas kernel bodies (identical ops)."""
-    thresh = (jnp.asarray(p, jnp.float32) * 4294967296.0).astype(jnp.uint32)
+def flip_threshold(p) -> jax.Array:
+    """uint32 threshold of a float32 bit-error probability `p`: a bit
+    plane flips iff its hashed word is below p * 2^32. Computed outside
+    the Pallas kernel bodies, because Mosaic has no float32 -> uint32
+    cast."""
+    return (jnp.asarray(p, jnp.float32) * 4294967296.0).astype(jnp.uint32)
+
+
+def bit_flip_mask(rand: jax.Array, n_bits: int, thresh) -> jax.Array:
+    """XOR mask with each of the low `n_bits` planes set iid w.p. p,
+    derived from ONE uint32 word per element. `thresh` is
+    `flip_threshold(p)` and broadcasts against `rand` (e.g. a per-row
+    [R, 1] vector). Shared by the jnp paths and the Pallas kernel
+    bodies (identical ops)."""
     flips = jnp.zeros_like(rand)
     for b in range(n_bits):
         salt = ((b + 1) * GOLDEN) & 0xFFFFFFFF
@@ -384,7 +393,7 @@ def wire_transform(buf: jax.Array, rand: jax.Array, scale, p, bits: int,
     else:
         r = jnp.round(x)
     q = jnp.clip(r, -qm, qm).astype(jnp.int32)
-    flips = bit_flip_mask(rand, bits, p)
+    flips = bit_flip_mask(rand, bits, flip_threshold(p))
     if nibble_packed:
         # bits <= 4 -> codes and flip masks both fit one nibble
         byte = Q.pack_nibbles((q + jnp.int32(qm)).astype(jnp.uint32))
@@ -476,7 +485,7 @@ def _transmit_per_leaf(leaves, plan: WirePlan, rand, p, bits: int):
             code = Q.quantize_offset(q, bits)
             r0, nr, size = plan.row_start[i], plan.rows[i], plan.sizes[i]
             rs = rand[ui, r0:r0 + nr].reshape(-1)[:size].reshape(x.shape)
-            code = code ^ bit_flip_mask(rs, bits, p[ui, i])
+            code = code ^ bit_flip_mask(rs, bits, flip_threshold(p[ui, i]))
             q_hat = Q.unquantize_offset(code, bits)
             row.append(Q.dequantize(q_hat, s).astype(plan.dtypes[i]))
         outs.append(row)
@@ -552,8 +561,7 @@ def _transmit_stacked_planned(key, leaves, plan: WirePlan, bits: int,
         # off kb — a DIFFERENT stream than the host jax.random.bits
         # words, which is why it hides behind the flag (host-vs-kernel
         # bitwise parity only holds with it off).
-        tpu_rng = K.TPU_KERNEL_RNG and not interpret \
-            and jax.default_backend() == "tpu"
+        tpu_rng = K.TPU_KERNEL_RNG and not interpret
         seed = jax.random.bits(kb, (1, 1), jnp.uint32).astype(jnp.int32) \
             if tpu_rng else None
         y = K.packed_wire_2d(buf.reshape(n * r, c), rand.reshape(n * r, c),
@@ -606,7 +614,8 @@ def _check_rounding(rounding: str, impl: str) -> str:
 def transmit_stacked(key, tree, bits: int, snr_db, fading: bool = True,
                      perfect: bool = False, arq_attempts: int = 1,
                      arq_min_f2: float = 0.25, impl: str = "packed",
-                     interpret: bool = True, return_diag: bool = False,
+                     interpret: bool | None = None,
+                     return_diag: bool = False,
                      wire_dtype: str = "float32", arq_max_tx: int = 0,
                      ge_p_gb: float = 0.0, ge_p_bg: float = 0.5,
                      rounding: str = "nearest"):
@@ -647,7 +656,7 @@ def transmit_stacked(key, tree, bits: int, snr_db, fading: bool = True,
     out, n_tx, erased = _transmit_stacked_planned(
         key, tuple(leaves), plan, int(bits), snr_db, bool(fading),
         bool(perfect), int(arq_attempts), float(arq_min_f2), impl,
-        bool(interpret),
+        resolve_interpret(interpret),
         wire_dtype=_check_wire_dtype(wire_dtype, int(bits), impl),
         arq_max_tx=int(arq_max_tx), ge_p_gb=float(ge_p_gb),
         ge_p_bg=float(ge_p_bg),
@@ -741,7 +750,8 @@ def _transmit_stacked_mean_planned(key, leaves, plan: WirePlan, bits: int,
 def transmit_stacked_mean(key, tree, bits: int, snr_db,
                           fading: bool = True, perfect: bool = False,
                           arq_attempts: int = 1, arq_min_f2: float = 0.25,
-                          impl: str = "kernel", interpret: bool = True,
+                          impl: str = "kernel",
+                          interpret: bool | None = None,
                           wire_dtype: str = "float32", arq_max_tx: int = 0,
                           ge_p_gb: float = 0.0, ge_p_bg: float = 0.5):
     """Fused transmit-and-aggregate of a stacked [N, ...] upload: one
@@ -765,7 +775,7 @@ def transmit_stacked_mean(key, tree, bits: int, snr_db,
     out, n_tx, erased, n_alive = _transmit_stacked_mean_planned(
         key, tuple(leaves), plan, int(bits), snr_db, bool(fading),
         bool(perfect), int(arq_attempts), float(arq_min_f2), impl,
-        bool(interpret),
+        resolve_interpret(interpret),
         wire_dtype=_check_wire_dtype(wire_dtype, int(bits), impl),
         arq_max_tx=int(arq_max_tx), ge_p_gb=float(ge_p_gb),
         ge_p_bg=float(ge_p_bg))
@@ -776,7 +786,8 @@ def transmit_stacked_mean(key, tree, bits: int, snr_db,
 def transmit_tree(key, tree, bits: int, snr_db, fading: bool = True,
                   perfect: bool = False, arq_attempts: int = 1,
                   arq_min_f2: float = 0.25, impl: str = "packed",
-                  interpret: bool = True, return_diag: bool = False,
+                  interpret: bool | None = None,
+                  return_diag: bool = False,
                   wire_dtype: str = "float32", arq_max_tx: int = 0,
                   ge_p_gb: float = 0.0, ge_p_bg: float = 0.5,
                   rounding: str = "nearest"):
@@ -802,7 +813,8 @@ def transmit_tree(key, tree, bits: int, snr_db, fading: bool = True,
     stacked = tuple(l[None] for l in leaves)
     out, n_tx, erased = _transmit_stacked_planned(
         key, stacked, plan, int(bits), snr_db, bool(fading), bool(perfect),
-        int(arq_attempts), float(arq_min_f2), impl, bool(interpret),
+        int(arq_attempts), float(arq_min_f2), impl,
+        resolve_interpret(interpret),
         wire_dtype=_check_wire_dtype(wire_dtype, int(bits), impl),
         arq_max_tx=int(arq_max_tx), ge_p_gb=float(ge_p_gb),
         ge_p_bg=float(ge_p_bg),

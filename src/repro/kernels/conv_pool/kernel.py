@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 BLOCK_B = 8
 
 
@@ -41,7 +43,7 @@ def _conv_pool_kernel(x_ref, w_ref, b_ref, o_ref, *, K: int, T_out: int,
 
 
 def conv_pool(x: jax.Array, w: jax.Array, b: jax.Array,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """x [B, T, E], w [K, E, F], b [F] -> [B, (T-K+1)//2, F]."""
     B, T, E = x.shape
     K, _, F = w.shape
@@ -59,5 +61,5 @@ def conv_pool(x: jax.Array, w: jax.Array, b: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, P, F), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, P, F), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w, b)

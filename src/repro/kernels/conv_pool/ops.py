@@ -12,7 +12,7 @@ from repro.kernels.conv_pool.kernel import conv_pool, BLOCK_B
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def user_conv_pool(x: jax.Array, w: jax.Array, b: jax.Array,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Alignment-safe fused conv+relu+pool. x [B,T,E] float."""
     B, T, E = x.shape
     K, _, F = w.shape

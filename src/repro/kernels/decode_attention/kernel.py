@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 BLOCK_S = 512
 NEG_INF = -1e30
 
@@ -104,7 +106,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, tables: jax.Array,
                            length: jax.Array, window: int = 0,
                            scale: float | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """Flash-decode over a PAGED cache: q [B, Hkv, G, hd]; pools
     [n_pages, Hkv, page, hd] shared by all slots; `tables` [B, n_lp]
     int32 maps each row's logical page j to its physical pool page —
@@ -142,7 +144,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(tables, jnp.int32),
       jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,)),
       q, k_pool, v_pool)
@@ -151,7 +153,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      length: jax.Array, window: int = 0,
                      scale: float | None = None,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """q [B, Hkv, G, hd]; k/v [B, Hkv, S, hd]; length scalar int32 OR a
     per-batch-row [B] vector (continuous-batching decode: every slot
     masks its own prefix; a scalar is broadcast to all rows).
@@ -181,6 +183,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,)),
       q, k, v)

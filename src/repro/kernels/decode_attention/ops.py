@@ -15,7 +15,7 @@ from repro.kernels.decode_attention.kernel import (decode_attention,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def gqa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                length: jax.Array, window: int = 0,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """q [B, H, hd]; caches [B, Hkv, S, hd]; `length` a scalar or a
     per-row [B] vector of valid-prefix counts. Returns [B, H, hd] fp32."""
     B, H, hd = q.shape
@@ -40,7 +40,7 @@ def gqa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def gqa_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                      tables: jax.Array, length: jax.Array, window: int = 0,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """q [B, H, hd]; pools [n_pages, Hkv, page, hd]; `tables` [B, n_lp]
     per-slot page tables; `length` scalar or per-row [B] valid-prefix
     counts. Returns [B, H, hd] fp32."""

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 BLOCK_B = 128
 
 
@@ -41,7 +43,7 @@ def _lstm_kernel(xw_ref, wh_ref, h_ref, c_ref, *, seq_len: int):
 
 
 def lstm_final_state(xw: jax.Array, wh: jax.Array,
-                     interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                     interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
     """xw [B, T, 4H] (x@Wx + b precomputed), wh [H, 4H].
     Returns (h_T, c_T) each [B, H] fp32."""
     B, T, H4 = xw.shape
@@ -64,6 +66,6 @@ def lstm_final_state(xw: jax.Array, wh: jax.Array,
         ],
         out_shape=[jax.ShapeDtypeStruct(((B + pad), H), jnp.float32),
                    jax.ShapeDtypeStruct(((B + pad), H), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xw.astype(jnp.float32), wh.astype(jnp.float32))
     return h[:B], c[:B]

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 BLOCK_S = 512
 NEG_INF = -1e30
 
@@ -82,7 +84,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             v_pool: jax.Array, tables: jax.Array,
                             start: jax.Array, g: int, window: int = 0,
                             scale: float | None = None,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool | None = None) -> jax.Array:
     """Flash-prefill over a PAGED cache: q [B, Hkv, C*G, hd] chunk-major
     query rows; pools [n_pages, Hkv, page, hd]; `tables` [B, n_lp]
     per-slot page tables (scalar-prefetched into the KV BlockSpec index
@@ -120,7 +122,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, CG, hd), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(tables, jnp.int32),
       jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (B,)),
       q, k_pool, v_pool)
@@ -129,7 +131,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
 def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       start: jax.Array, g: int, window: int = 0,
                       scale: float | None = None,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """q [B, Hkv, C*G, hd] (chunk-major query rows: row r = chunk
     position r // G, head-group member r % G); k/v [B, Hkv, S, hd];
     `start` [B] int32 — per-row global position of chunk token 0 (the
@@ -161,6 +163,6 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((CG, 1), jnp.float32),
             pltpu.VMEM((CG, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (B,)),
       q, k, v)

@@ -16,7 +16,7 @@ from repro.kernels.prefill_attention.kernel import (paged_prefill_attention,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def gqa_prefill(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                 start: jax.Array, window: int = 0,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """q [B, C, H, hd] — a C-token prompt chunk per slot; caches
     [B, Hkv, S, hd] already holding the chunk's own K/V columns;
     `start` [B] per-row global position of chunk token 0.
@@ -48,7 +48,7 @@ def gqa_prefill(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def gqa_prefill_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       tables: jax.Array, start: jax.Array, window: int = 0,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """q [B, C, H, hd] prompt chunks; pools [n_pages, Hkv, page, hd]
     already holding the chunk's own K/V columns; `tables` [B, n_lp]
     per-slot page tables; `start` [B]. Returns [B, C, H, hd] fp32."""

@@ -35,10 +35,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.wire import GOLDEN as _GOLDEN          # noqa: F401 (re-export)
-from repro.core.wire import bit_flip_mask, fmix32
+from repro.core.wire import bit_flip_mask, flip_threshold, fmix32
+from repro.kernels import resolve_interpret
 
 BLOCK_M = 128
 BLOCK_N = 512
+ROW_TILE = 8        # float32 sublane tile: row blocks are multiples of it
 
 # Opt-in: on real TPU (compiled, not interpret) generate the per-element
 # rand word with pltpu.prng_random_bits INSIDE the kernel instead of the
@@ -51,7 +53,7 @@ TPU_KERNEL_RNG = False
 _finalize = fmix32
 
 
-def _qc_kernel(x_ref, rand_ref, p_ref, o_ref, *, bits: int):
+def _qc_kernel(x_ref, rand_ref, t_ref, o_ref, *, bits: int):
     x = x_ref[...]
     qmax = float(2 ** (bits - 1) - 1)
     # blockwise symmetric scale (Eq. 1)
@@ -61,15 +63,17 @@ def _qc_kernel(x_ref, rand_ref, p_ref, o_ref, *, bits: int):
     code = (q + jnp.int32(qmax)).astype(jnp.uint32)
 
     # per-bit-plane Bernoulli(p) flips from one rand word per element
-    code = code ^ bit_flip_mask(rand_ref[...], bits, p_ref[0])
+    code = code ^ bit_flip_mask(rand_ref[...], bits, t_ref[0])
 
     q_hat = jnp.clip(code.astype(jnp.int32) - jnp.int32(qmax), -qmax, qmax)
     o_ref[...] = (q_hat.astype(jnp.float32) * scale).astype(o_ref.dtype)
 
 
-def _wire_tile(x, rand, scale, p, *, bits: int, code_dtype=jnp.uint32):
+def _wire_tile(x, rand, scale, thresh, *, bits: int,
+               code_dtype=jnp.uint32):
     """One tile of the packed-wire math (quantize -> flip -> dequantize),
-    shared by the plain and fused-mean kernel bodies. Returns float32.
+    shared by the plain and fused-mean kernel bodies. `thresh` is the
+    per-row uint32 `flip_threshold(p)`. Returns float32.
 
     `code_dtype=jnp.uint8` is the on-wire int8 mode (bits <= 8): the
     codeword tile lives as one byte per element between quantize and
@@ -84,22 +88,22 @@ def _wire_tile(x, rand, scale, p, *, bits: int, code_dtype=jnp.uint32):
     qmax = float(2 ** (bits - 1) - 1)
     q = jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int32)
     code = (q + jnp.int32(qmax)).astype(code_dtype)
-    code = code ^ bit_flip_mask(rand, bits, p).astype(code_dtype)
+    code = code ^ bit_flip_mask(rand, bits, thresh).astype(code_dtype)
     q_hat = jnp.clip(code.astype(jnp.int32) - jnp.int32(qmax), -qmax, qmax)
     return q_hat.astype(jnp.float32) * scale
 
 
-def _packed_kernel(x_ref, rand_ref, scale_ref, p_ref, o_ref, *, bits: int,
+def _packed_kernel(x_ref, rand_ref, scale_ref, t_ref, o_ref, *, bits: int,
                    code_dtype=jnp.uint32):
-    """Packed-wire body: per-ROW quantization scale and bit-error prob
+    """Packed-wire body: per-ROW quantization scale and flip threshold
     (delivered as [bm, 1] tiles) instead of a blockwise scale — each row
     belongs to exactly one packet (leaf / user), see core/wire.py."""
-    y = _wire_tile(x_ref[...], rand_ref[...], scale_ref[...], p_ref[...],
+    y = _wire_tile(x_ref[...], rand_ref[...], scale_ref[...], t_ref[...],
                    bits=bits, code_dtype=code_dtype)
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def _packed_kernel_tpu_rng(seed_ref, x_ref, scale_ref, p_ref, o_ref, *,
+def _packed_kernel_tpu_rng(seed_ref, x_ref, scale_ref, t_ref, o_ref, *,
                            bits: int, code_dtype, grid_j: int):
     """Packed-wire body with the rand word generated IN-KERNEL by the
     TPU hardware PRNG (pltpu.prng_random_bits) instead of arriving as a
@@ -112,12 +116,12 @@ def _packed_kernel_tpu_rng(seed_ref, x_ref, scale_ref, p_ref, o_ref, *,
     i, j = pl.program_id(0), pl.program_id(1)
     pltpu.prng_seed(seed_ref[0, 0], i * grid_j + j)
     rand = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
-    y = _wire_tile(x_ref[...], rand, scale_ref[...], p_ref[...],
+    y = _wire_tile(x_ref[...], rand, scale_ref[...], t_ref[...],
                    bits=bits, code_dtype=code_dtype)
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def _packed_mean_kernel(x_ref, rand_ref, scale_ref, p_ref, w_ref, o_ref, *,
+def _packed_mean_kernel(x_ref, rand_ref, scale_ref, t_ref, w_ref, o_ref, *,
                         bits: int, code_dtype=jnp.uint32):
     """Fused quant -> channel -> dequant -> WEIGHTED-MEAN body for a
     stacked N-user upload: the user axis is the innermost grid dim, and
@@ -127,7 +131,7 @@ def _packed_mean_kernel(x_ref, rand_ref, scale_ref, p_ref, w_ref, o_ref, *,
     accumulate in ascending order, matching the jnp fallback's ordered
     sum bit-for-bit (core/wire._transmit_stacked_mean_planned)."""
     u = pl.program_id(2)
-    y = _wire_tile(x_ref[...], rand_ref[...], scale_ref[...], p_ref[...],
+    y = _wire_tile(x_ref[...], rand_ref[...], scale_ref[...], t_ref[...],
                    bits=bits, code_dtype=code_dtype)
     contrib = (w_ref[...] * y).astype(o_ref.dtype)
 
@@ -144,27 +148,52 @@ def _code_dtype_for(wire_dtype: str):
     return jnp.uint8 if wire_dtype in ("int8", "int4") else jnp.uint32
 
 
+def _row_block(r: int) -> int:
+    """Largest row block (at most BLOCK_M) dividing `r`, a multiple of
+    ROW_TILE — the TPU tiles a block's rows in whole sublane tiles."""
+    return next(b for b in (BLOCK_M, 64, 32, 16, ROW_TILE) if r % b == 0)
+
+
+def _pad_rows(a: jax.Array, rows: int, value=0) -> jax.Array:
+    """Pad the row axis (-2) of `a` up to `rows`; the kernels' padding
+    rows are computed and then sliced off."""
+    extra = rows - a.shape[-2]
+    if not extra:
+        return a
+    pad = [(0, 0)] * (a.ndim - 2) + [(0, extra), (0, 0)]
+    return jnp.pad(a, pad, constant_values=value)
+
+
 def packed_wire_2d(buf: jax.Array, rand: jax.Array, scale_row: jax.Array,
                    p_row: jax.Array, bits: int,
-                   interpret: bool = True,
+                   interpret: bool | None = None,
                    wire_dtype: str = "float32",
                    rng_mode: str = "host",
                    seed: jax.Array | None = None) -> jax.Array:
     """buf [R, C] float32, rand [R, C] uint32, scale_row/p_row [R, 1]
     float32. Grid over the packed 2D view; one launch per pytree (or per
-    N-user upload when the caller stacks users into R).
+    N-user upload when the caller stacks users into R). Any R is
+    accepted: rows pad to a ROW_TILE multiple inside.
     `wire_dtype="int8"` (bits <= 8) keeps the codeword tile in uint8 —
     4x less VMEM for the buffer that crosses the channel; `"int4"`
     (bits <= 4) bills two codewords per byte (see _wire_tile).
     `rng_mode="tpu"` (compiled TPU only; gated by TPU_KERNEL_RNG at the
     wire layer) generates the rand words in-kernel from `seed` [1, 1]
     int32 and ignores `rand`; interpret mode must stay "host"."""
+    interpret = resolve_interpret(interpret)
     R, C = buf.shape
-    bm = next(b for b in (BLOCK_M, 64, 32, 16, 8, 4, 2, 1) if R % b == 0)
+    rp = -(-R // ROW_TILE) * ROW_TILE
+    bm = _row_block(rp)
     bn = min(BLOCK_N, C)
     assert C % bn == 0, (R, C, bm, bn)
-    grid = (R // bm, C // bn)
+    grid = (rp // bm, C // bn)
     code_dtype = _code_dtype_for(wire_dtype)
+    buf_p = _pad_rows(buf, rp)
+    scale_p = _pad_rows(scale_row, rp, 1.0)
+    thresh_p = _pad_rows(flip_threshold(p_row), rp)
+    row_spec = pl.BlockSpec((bm, 1), lambda i, j: (i, 0))
+    tile_spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
+    out_shape = jax.ShapeDtypeStruct((rp, C), buf.dtype)
     if rng_mode not in ("host", "tpu"):
         raise ValueError(f"unknown rng_mode {rng_mode!r}")
     if rng_mode == "tpu":
@@ -175,74 +204,73 @@ def packed_wire_2d(buf: jax.Array, rand: jax.Array, scale_row: jax.Array,
                 "host-side rand-word input (rng_mode='host')")
         if seed is None:
             raise ValueError("rng_mode='tpu' requires a [1, 1] int32 seed")
-        return pl.pallas_call(
+        out = pl.pallas_call(
             functools.partial(_packed_kernel_tpu_rng, bits=bits,
                               code_dtype=code_dtype, grid_j=C // bn),
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-                pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-                pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((R, C), buf.dtype),
+            in_specs=[pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+                      tile_spec, row_spec, row_spec],
+            out_specs=tile_spec,
+            out_shape=out_shape,
             interpret=interpret,
-        )(seed, buf, scale_row, p_row)
-    return pl.pallas_call(
+        )(seed, buf_p, scale_p, thresh_p)
+        return out[:R]
+    out = pl.pallas_call(
         functools.partial(_packed_kernel, bits=bits, code_dtype=code_dtype),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, C), buf.dtype),
+        in_specs=[tile_spec, tile_spec, row_spec, row_spec],
+        out_specs=tile_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(buf, rand, scale_row, p_row)
+    )(buf_p, _pad_rows(rand, rp), scale_p, thresh_p)
+    return out[:R]
 
 
 def packed_wire_mean_2d(buf: jax.Array, rand: jax.Array,
                         scale_row: jax.Array, p_row: jax.Array,
                         w_row: jax.Array, bits: int, n: int,
-                        interpret: bool = True,
+                        interpret: bool | None = None,
                         wire_dtype: str = "float32") -> jax.Array:
     """Fused stacked transmit + weighted mean: buf/rand [N*R, C] (users
     stacked along rows), scale_row/p_row/w_row [N*R, 1] -> [R, C] the
     weighted sum over users of the dequantized rows. ONE kernel launch
     for FL's whole quantize -> channel -> dequantize -> aggregate upload
     (grid (R/bm, C/bn, N), user axis innermost so each output block is
-    revisited consecutively)."""
+    revisited consecutively). Each user's R rows pad to a ROW_TILE
+    multiple inside, with zero weight."""
+    interpret = resolve_interpret(interpret)
     NR, C = buf.shape
     assert NR % n == 0, (NR, n)
     R = NR // n
-    bm = next(b for b in (BLOCK_M, 64, 32, 16, 8, 4, 2, 1) if R % b == 0)
+    rp = -(-R // ROW_TILE) * ROW_TILE
+    bm = _row_block(rp)
     bn = min(BLOCK_N, C)
     assert C % bn == 0, (R, C, bm, bn)
-    gi = R // bm
+    gi = rp // bm
     grid = (gi, C // bn, n)
     code_dtype = _code_dtype_for(wire_dtype)
-    return pl.pallas_call(
+
+    def per_user(a, value=0):
+        a = _pad_rows(a.reshape(n, R, a.shape[-1]), rp, value)
+        return a.reshape(n * rp, a.shape[-1])
+
+    row_spec = pl.BlockSpec((bm, 1), lambda i, j, u: (u * gi + i, 0))
+    tile_spec = pl.BlockSpec((bm, bn), lambda i, j, u: (u * gi + i, j))
+    out = pl.pallas_call(
         functools.partial(_packed_mean_kernel, bits=bits,
                           code_dtype=code_dtype),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, u: (u * gi + i, j)),
-            pl.BlockSpec((bm, bn), lambda i, j, u: (u * gi + i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, u: (u * gi + i, 0)),
-            pl.BlockSpec((bm, 1), lambda i, j, u: (u * gi + i, 0)),
-            pl.BlockSpec((bm, 1), lambda i, j, u: (u * gi + i, 0)),
-        ],
+        in_specs=[tile_spec, tile_spec, row_spec, row_spec, row_spec],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, u: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rp, C), jnp.float32),
         interpret=interpret,
-    )(buf, rand, scale_row, p_row, w_row)
+    )(per_user(buf), per_user(rand), per_user(scale_row, 1.0),
+      per_user(flip_threshold(p_row)), per_user(w_row))
+    return out[:R]
 
 
 def quant_channel_2d(x: jax.Array, rand: jax.Array, p: jax.Array,
-                     bits: int, interpret: bool = True) -> jax.Array:
+                     bits: int, interpret: bool | None = None) -> jax.Array:
     """x [M, N] float, rand [M, N] uint32, p [1] float32 (bit-error prob)."""
     M, N = x.shape
     bm, bn = min(BLOCK_M, M), min(BLOCK_N, N)
@@ -258,5 +286,5 @@ def quant_channel_2d(x: jax.Array, rand: jax.Array, p: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        interpret=interpret,
-    )(x, rand, p)
+        interpret=resolve_interpret(interpret),
+    )(x, rand, flip_threshold(p))

@@ -22,7 +22,7 @@ from repro.kernels.quant_channel.kernel import quant_channel_2d, BLOCK_N
 @functools.partial(jax.jit, static_argnames=("bits", "fading", "interpret"))
 def transmit(key: jax.Array, x: jax.Array, bits: int = 8,
              snr_db: float = 20.0, fading: bool = True,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """Quantize+channel+dequantize `x` (any shape/float dtype)."""
     kf, kb = jax.random.split(key)
     f2 = CH.rayleigh_gain(kf) if fading else jnp.float32(1.0)

@@ -1,14 +1,15 @@
-"""Persistent XLA compile cache for the launch drivers.
+"""Persistent XLA compile cache for the launch drivers and `chip_smoke.py`.
 
-`enable_persistent_cache()` points jax's compilation cache at a
-repo-local directory (override with REPRO_JAX_CACHE_DIR) and drops the
+`enable_persistent_cache()` points jax's compilation cache at
+`$JAX_COMPILATION_CACHE_DIR` when that is set, and otherwise at the
+fixed repo-local `.jax_cache/` (the path is part of what makes a later
+process hit: a directory that moves never does). It also drops the
 size/compile-time admission thresholds so even the smoke-scale programs
-are cached. The effect is cross-PROCESS: the first `train.py` run pays
-the full XLA wall and seeds the cache; every later run of the same
-program (same arch/shape/mesh/donation/sharding signature) deserializes
-the executable instead of recompiling — `--aot-warmup` then reports a
-near-zero compile wall (scripts/ci.sh gates the second run at <20% of
-the first).
+are cached. The effect is cross-PROCESS: the first run pays the full
+XLA wall and seeds the cache; every later run of the same program (same
+arch/shape/mesh/donation/sharding signature) deserializes the executable
+instead of recompiling — `--aot-warmup` then reports a near-zero compile
+wall (scripts/ci.sh gates the second run at <20% of the first).
 
 Why a module and not three lines in each driver: the cache only helps
 if every entry point configures it IDENTICALLY (the cache key includes
@@ -21,24 +22,22 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.dirname(__file__), "..", "..", "..", ".jax_cache")
+REPO_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
 
 
 def cache_dir() -> str:
-    """Resolved cache directory: $REPRO_JAX_CACHE_DIR or the repo-local
-    `.jax_cache/` next to benchmarks/."""
-    return os.path.abspath(
-        os.environ.get("REPRO_JAX_CACHE_DIR", DEFAULT_CACHE_DIR))
+    """$JAX_COMPILATION_CACHE_DIR if set, else the repo's `.jax_cache/`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_CACHE_DIR
 
 
-def enable_persistent_cache(path: str | None = None) -> str:
-    """Enable jax's persistent compilation cache at `path` (default
-    `cache_dir()`); returns the directory used. Idempotent — safe to
-    call from every driver entry point, before or after backend init
-    (the cache is consulted per-compile, not at startup)."""
-    d = path or cache_dir()
+def enable_persistent_cache() -> str:
+    """Enable jax's persistent compilation cache at `cache_dir()`;
+    returns the directory used. Idempotent — safe to call from every
+    driver entry point, before or after backend init."""
+    d = cache_dir()
     os.makedirs(d, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", d)
     # admit EVERYTHING: the smoke programs compile in <1s and would be
@@ -46,15 +45,9 @@ def enable_persistent_cache(path: str | None = None) -> str:
     # exactly what ci.sh re-runs
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        # the cache module latches "disabled" at the process's FIRST
-        # compile; without a reset, enabling after any jit ran (the
-        # benchmark's in-process cold/warm experiment does) is a no-op
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass      # older/newer jax without the private hook: config
-        #           set before the first compile still takes effect
+    # the cache module latches its state at the process's FIRST
+    # compile; without a reset, enabling after any jit ran is a no-op
+    compilation_cache.reset_cache()
     return d
 
 

@@ -6,6 +6,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (roofline §EXPERIMENTS.md)
 PEAK_FLOPS_BF16 = 197e12          # per chip
@@ -13,25 +14,21 @@ HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link
 
 
+def make_mesh(shape, axes, devices=None):
+    """A mesh of `shape` over the first devices, every axis Auto — the
+    only kind `nn.sharding.constrain` (with_sharding_constraint) can
+    name. Raises when there are fewer devices than the shape needs."""
+    n = math.prod(shape)
+    devices = list(jax.devices() if devices is None else devices)
+    if len(devices) < n:
+        raise RuntimeError(
+            f"mesh {tuple(shape)} needs {n} devices, have {len(devices)}")
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = math.prod(shape)
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices, have {len(devices)} — run via "
-            "launch/dryrun.py which sets xla_force_host_platform_device_count")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
-
-
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Tiny mesh for CI-style tests (4 host devices). Degrades to an
-    all-ones mesh over the same axis names when the host has fewer
-    devices (the in-process pytest/CLI case: 1 CPU device) — every
-    sharding rule then resolves to replication, same code path."""
-    n = math.prod(shape)
-    if len(jax.devices()) < n:
-        shape = (1,) * len(shape)
-        n = 1
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes)

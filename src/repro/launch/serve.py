@@ -29,7 +29,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import api as M
 from repro.nn import init_params, use_mesh
 from repro.runtime.serve_step import make_decode_step
@@ -78,7 +78,8 @@ def parse_args(argv=None):
                          "pages (0 = dense-parity capacity)")
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--greedy", action="store_true")
-    ap.add_argument("--mesh", default="none", choices=["none", "test"])
+    ap.add_argument("--mesh", default="none", choices=["none", "test"],
+                    help="test: a one-device (data, model) mesh")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--aot-warmup", action="store_true",
                     help="compile the decode step AND every prefill "
@@ -199,7 +200,8 @@ def main(argv=None) -> dict:
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    mesh = make_test_mesh() if args.mesh == "test" else None
+    mesh = make_mesh((1, 1), ("data", "model")) \
+        if args.mesh == "test" else None
     if cfg.family not in SLOT_FAMILIES:
         print(f"{cfg.family}: scalar-index decode only — legacy loop")
         return legacy_main(args, cfg, mesh)

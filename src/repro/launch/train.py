@@ -39,7 +39,7 @@ import numpy as np
 from repro.checkpoint.ckpt import latest_experiment_cycle
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig, WirelessConfig
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.nn import use_mesh
 from repro.schemes import BATCH, Experiment, build_scheme
 
@@ -114,7 +114,8 @@ def parse_args(argv=None):
                     help="checkpoint every k cycles")
     ap.add_argument("--log-every", type=int, default=1,
                     help="print every k cycles")
-    ap.add_argument("--mesh", default="none", choices=["none", "test"])
+    ap.add_argument("--mesh", default="none", choices=["none", "test"],
+                    help="test: a one-device (data, model) mesh")
     ap.add_argument("--seed", type=int, default=0)
     return ap.parse_args(argv)
 
@@ -147,7 +148,8 @@ def main(argv=None) -> dict:
     wcfg = build_wcfg(args)
     n_train = args.n_train or (3072 if tiny else 512)
     n_test = args.n_test or (512 if tiny else 128)
-    mesh = make_test_mesh() if args.mesh == "test" else None
+    mesh = make_mesh((1, 1), ("data", "model")) \
+        if args.mesh == "test" else None
 
     data = None
     if args.fleet_size > 0:

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import resolve_interpret
 from repro.nn import Spec, constrain
 
 NEG_INF = -1e30
@@ -274,25 +275,30 @@ def paged_insert(pool, tables, cols, vals, keep):
                                         mode="drop")
 
 
-def _serve_kernel_route() -> bool:
-    use_kernel = _os.environ.get("REPRO_SERVE_KERNEL", "auto")
-    on_tpu = jax.default_backend() == "tpu"
-    return use_kernel == "1" or (use_kernel == "auto" and on_tpu)
+def use_attn_kernel(kernel: bool | None = None) -> bool:
+    """The serving attention route: the Pallas kernels when `kernel` is
+    True, the plain jnp attention when False, and — when None — the
+    kernels exactly when the program runs on a TPU (elsewhere they
+    would only run in the Pallas interpreter)."""
+    return not resolve_interpret() if kernel is None else bool(kernel)
+
+
+def _pages_kernel(pages) -> bool:
+    return use_attn_kernel(None if pages is None else pages.get("kernel"))
 
 
 def decode_attention_slots_paged(q, k_pool, v_pool, tables, lengths,
-                                 window: int = 0):
+                                 window: int = 0, kernel: bool = False):
     """Per-slot flash-decode over the shared page pool: q [B,H,hd],
     pools [n_pages,Hkv,page,hd], `tables` [B,n_lp], `lengths` [B].
-    Kernel route streams pool pages straight off the scalar-prefetched
-    page table; the jnp fallback gathers a dense per-slot view first —
-    both are bit-equivalent to dense decode on the valid prefix."""
-    on_tpu = jax.default_backend() == "tpu"
-    if _serve_kernel_route():
+    `kernel=True` streams pool pages straight off the scalar-prefetched
+    page table (Pallas); the jnp route gathers a dense per-slot view
+    first — both are bit-equivalent to dense decode on the valid
+    prefix."""
+    if kernel:
         from repro.kernels.decode_attention.ops import gqa_decode_paged
         return gqa_decode_paged(q, k_pool, v_pool, tables, lengths,
-                                window=window,
-                                interpret=not on_tpu).astype(q.dtype)
+                                window=window).astype(q.dtype)
     return decode_attention_jnp(q, paged_view(k_pool, tables),
                                 paged_view(v_pool, tables), lengths,
                                 window=window).astype(q.dtype)
@@ -305,25 +311,25 @@ def attention_prefill_slots(p, x, cfg, cache_k, cache_v, start, n_valid,
     >= n_valid[b] are padded tail and masked out of the KV insert. One
     bulk K/V column write + one chunk-vs-cache attention launch replace
     C decode steps. `pages` = {"tables": [B,n_lp], "page_size": int,
-    "active": [B] bool or None} switches the cache to the shared page
-    pool. Returns (out [B,C,d], new_k, new_v)."""
+    "active": [B] bool or None, "kernel": bool or None} switches the
+    cache to the shared page pool and picks the attention route
+    (`use_attn_kernel`). Returns (out [B,C,d], new_k, new_v)."""
     B, C, _ = x.shape
     hd = cfg.hd
     positions = start[:, None] + jnp.arange(C)[None]        # [B, C]
     q, k, v = _qkv(p, x, cfg, positions)                    # [B,C,H|Hkv,hd]
     valid = jnp.arange(C)[None, :] < n_valid[:, None]       # [B, C]
-    on_tpu = jax.default_backend() == "tpu"
+    kernel = _pages_kernel(pages)
     if pages is not None:
         keep = valid
         if pages.get("active") is not None:
             keep &= pages["active"][:, None]
         cache_k = paged_insert(cache_k, pages["tables"], positions, k, keep)
         cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
-        if _serve_kernel_route():
+        if kernel:
             from repro.kernels.prefill_attention.ops import gqa_prefill_paged
             out = gqa_prefill_paged(q, cache_k, cache_v, pages["tables"],
-                                    start, window=window,
-                                    interpret=not on_tpu)
+                                    start, window=window)
         else:
             out = prefill_attention_jnp(q, paged_view(cache_k, pages["tables"]),
                                         paged_view(cache_v, pages["tables"]),
@@ -336,10 +342,9 @@ def attention_prefill_slots(p, x, cfg, cache_k, cache_v, start, n_valid,
             k.astype(cache_k.dtype), mode="drop")
         cache_v = cache_v.at[rows, :, cols, :].set(
             v.astype(cache_v.dtype), mode="drop")
-        if _serve_kernel_route():
+        if kernel:
             from repro.kernels.prefill_attention.ops import gqa_prefill
-            out = gqa_prefill(q, cache_k, cache_v, start, window=window,
-                              interpret=not on_tpu)
+            out = gqa_prefill(q, cache_k, cache_v, start, window=window)
         else:
             out = prefill_attention_jnp(q, cache_k, cache_v, start,
                                         window=window)
@@ -367,8 +372,6 @@ def decode_attention_dist(q, k_cache, v_cache, length, window, mesh,
     the dynamic window slice, which XLA could only realize by
     all-gathering the entire cache (350 GB/step for long_500k —
     EXPERIMENTS.md §Perf-3)."""
-    from jax.experimental.shard_map import shard_map
-
     B, Hkv, S, hd = k_cache.shape
     H = q.shape[1]
     G = H // Hkv
@@ -403,28 +406,26 @@ def decode_attention_dist(q, k_cache, v_cache, length, window, mesh,
         out = acc / jnp.maximum(s[..., None], 1e-30)
         return out.reshape(Bl, H, hd).astype(ql.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, None, axis, None),
                   P(bspec, None, axis, None)),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache)
 
 
-def decode_attention_slots(q, k_cache, v_cache, lengths, window: int = 0):
+def decode_attention_slots(q, k_cache, v_cache, lengths, window: int = 0,
+                           kernel: bool = False):
     """Per-slot flash-decode: q [B,H,hd], caches [B,Hkv,S,hd],
     `lengths` [B] — each row attends its OWN prefix (the serving
     engine's hot path, where every slot is at a different depth).
-    Routed through the Pallas decode_attention kernel on TPU (or when
-    REPRO_SERVE_KERNEL=1 forces interpret mode); the pure-jnp masked
-    softmax is the bit-equivalent fallback everywhere else."""
-    use_kernel = _os.environ.get("REPRO_SERVE_KERNEL", "auto")
-    on_tpu = jax.default_backend() == "tpu"
-    if use_kernel == "1" or (use_kernel == "auto" and on_tpu):
+    `kernel=True` routes through the Pallas decode_attention kernel;
+    the pure-jnp masked softmax is the bit-equivalent plain route."""
+    if kernel:
         from repro.kernels.decode_attention.ops import gqa_decode
-        return gqa_decode(q, k_cache, v_cache, lengths, window=window,
-                          interpret=not on_tpu).astype(q.dtype)
+        return gqa_decode(q, k_cache, v_cache, lengths,
+                          window=window).astype(q.dtype)
     return decode_attention_jnp(q, k_cache, v_cache, lengths,
                                 window=window).astype(q.dtype)
 
@@ -438,11 +439,13 @@ def attention_decode_slots(p, x, cfg, cache_k, cache_v, indices, window=0,
     perturbs its neighbours. With `pages` = {"tables", "page_size",
     "active"} the caches are the shared page pool [n_pages,Hkv,page,hd]
     and writes land through each slot's page table (inactive rows'
-    writes are dropped — the pool has no batch axis to select over)."""
+    writes are dropped — the pool has no batch axis to select over);
+    `pages["kernel"]` picks the attention route (`use_attn_kernel`)."""
     B = x.shape[0]
     hd = cfg.hd
     positions = indices[:, None]                           # [B,1]
     q, k, v = _qkv(p, x, cfg, positions)
+    kernel = _pages_kernel(pages)
     if pages is not None:
         keep = jnp.ones((B, 1), bool) if pages.get("active") is None \
             else pages["active"][:, None]
@@ -450,7 +453,7 @@ def attention_decode_slots(p, x, cfg, cache_k, cache_v, indices, window=0,
         cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
         out = decode_attention_slots_paged(q[:, 0], cache_k, cache_v,
                                            pages["tables"], indices + 1,
-                                           window)
+                                           window, kernel)
     else:
         S = cache_k.shape[2]
         hit = jnp.arange(S)[None, :] == indices[:, None]   # [B,S]
@@ -461,7 +464,7 @@ def attention_decode_slots(p, x, cfg, cache_k, cache_v, indices, window=0,
                             v.transpose(0, 2, 1, 3).astype(cache_v.dtype),
                             cache_v)
         out = decode_attention_slots(q[:, 0], cache_k, cache_v, indices + 1,
-                                     window)
+                                     window, kernel)
     out = out.reshape(B, 1, cfg.n_heads * hd).astype(x.dtype)
     return constrain(linear(p["wo"], out), "batch", "seq",
                      "act_embed"), cache_k, cache_v

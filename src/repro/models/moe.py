@@ -163,8 +163,6 @@ def _moe_ep(p: dict, x: jax.Array, cfg, mesh) -> tuple[jax.Array, dict]:
     outputs combine with one psum over the expert axis. Collective cost
     per layer = one [T_local, d] all-reduce (+ the small replicated
     router weights), instead of resharding the full dispatch buffers."""
-    from jax.experimental.shard_map import shard_map
-
     axis = "model"
     n_sh = mesh.shape[axis]
     El = cfg.n_experts // n_sh
@@ -192,12 +190,12 @@ def _moe_ep(p: dict, x: jax.Array, cfg, mesh) -> tuple[jax.Array, dict]:
         dr = jax.lax.pmean(jnp.mean(dr), bax + (axis,))
         return y, lb, dr
 
-    y, lb, dr = shard_map(
+    y, lb, dr = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(None, None), P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), P(bax if bax else None, None, None)),
         out_specs=(P(bax if bax else None, None, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(p["router"]["w"], p["wi"], p["wg"], p["wo"], x)
     if cfg.shared_expert:
         y = y + apply_mlp(p["shared"], x)

@@ -19,12 +19,17 @@ start[b].. and rows with n_valid=0 are untouched. Two implementations:
     per chunk. The TPU hot path; float-tolerance (not bitwise) vs scan.
 
 "auto" resolves to fused on TPU when the family has one, scan elsewhere.
+
+The paged steps take `kernel`: True routes attention through the Pallas
+paged kernels, False through the plain jnp attention, None (default) —
+the kernels exactly on a TPU (`models/layers.use_attn_kernel`).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.models import api as M
 from repro.models import transformer
 from repro.runtime.train_step import window_for
@@ -64,7 +69,8 @@ def paged_cache_specs(cfg, n_pages: int, page_size: int):
     return sds, axes
 
 
-def make_paged_decode_step(cfg, shape_cfg, page_size: int):
+def make_paged_decode_step(cfg, shape_cfg, page_size: int,
+                           kernel: bool | None = None):
     """Decode against the shared page pool. `tables` [B, n_lp] per-slot
     page tables; `active` [B] bool — inactive rows' pool writes are
     DROPPED in-graph (the pool has no batch axis for the engine to
@@ -75,7 +81,8 @@ def make_paged_decode_step(cfg, shape_cfg, page_size: int):
     window = window_for(cfg, shape_cfg)
 
     def decode_step(params, cache, token, index, tables, active):
-        pages = {"tables": tables, "page_size": page_size, "active": active}
+        pages = {"tables": tables, "page_size": page_size, "active": active,
+                 "kernel": kernel}
         logits, cache = model.decode_step(params, cache, token, index, cfg,
                                           window, pages=pages)
         return logits, cache
@@ -86,7 +93,7 @@ def make_paged_decode_step(cfg, shape_cfg, page_size: int):
 # ------------------------------------------------------------- prefill
 def _resolve_prefill_impl(model, impl: str) -> str:
     if impl == "auto":
-        impl = "fused" if (jax.default_backend() == "tpu"
+        impl = "fused" if (not resolve_interpret()
                            and model.prefill_step is not None) else "scan"
     if impl == "fused" and model.prefill_step is None:
         raise ValueError("family has no fused prefill_step")
@@ -149,7 +156,7 @@ def make_prefill_step(cfg, shape_cfg, impl: str = "auto"):
 
 
 def make_paged_prefill_step(cfg, shape_cfg, page_size: int,
-                            impl: str = "auto"):
+                            impl: str = "auto", kernel: bool | None = None):
     """Chunked prefill over the shared page pool; the step additionally
     takes `tables` [B, n_lp]. Row masking happens at the pool write
     (dropped scatters), not by batch select."""
@@ -163,7 +170,7 @@ def make_paged_prefill_step(cfg, shape_cfg, page_size: int,
     if impl == "fused":
         def prefill_fused(params, cache, tokens, start, n_valid, tables):
             pages = {"tables": tables, "page_size": page_size,
-                     "active": None}
+                     "active": None, "kernel": kernel}
             return model.prefill_step(params, cache, tokens, start, n_valid,
                                       cfg, window, pages=pages)
         return prefill_fused
@@ -175,7 +182,7 @@ def make_paged_prefill_step(cfg, shape_cfg, page_size: int,
             cache, lg = carry
             tok = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=1)
             pages = {"tables": tables, "page_size": page_size,
-                     "active": i < n_valid}
+                     "active": i < n_valid, "kernel": kernel}
             logits, cache = model.decode_step(params, cache, tok, start + i,
                                               cfg, window, pages=pages)
             lg = jnp.where((i == n_valid - 1)[:, None],

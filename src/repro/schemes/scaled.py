@@ -194,18 +194,18 @@ class _ScaledScheme:
         raise NotImplementedError
 
     def _step_cost_flops(self) -> float:
-        """FLOPs of one compiled round program, from XLA's pre-compile
-        cost analysis of the SAME jitted step the rounds execute
-        (`Lowered.cost_analysis()['flops']`) — no hand-derived formula
-        to drift from the model code, and abstract lowering means no
-        compile and no device memory. Cached per scheme; 0.0 when the
-        backend exposes no cost model."""
+        """FLOPs of one compiled round program, from XLA's cost analysis
+        of the SAME jitted step the rounds execute — no hand-derived
+        formula to drift from the model code. Lowered on abstract
+        inputs (no device memory); the TPU backend has no pre-compile
+        cost model (`Lowered.cost_analysis()` is None), so there the
+        compiled program is asked. Cached per scheme."""
         if self._cost_flops is None:
-            try:
-                self._cost_flops = float(
-                    self._lower_for_cost().cost_analysis()["flops"])
-            except Exception:
-                self._cost_flops = 0.0
+            lowered = self._lower_for_cost()
+            cost = lowered.cost_analysis()
+            if cost is None:
+                cost = lowered.compile().cost_analysis()
+            self._cost_flops = float(cost["flops"])
         return self._cost_flops
 
     def flops(self, steps_total: int):

@@ -22,8 +22,8 @@ Two admission planes (`prefill=`):
   lax.scan, so cache contents and first-token logits are BIT-IDENTICAL
   to the token path — including the paper classifier's O(1) streaming
   cache (conv taps / pending pool / LSTM h,c admit via that one batched
-  scan); `REPRO_PREFILL_IMPL=fused` (auto on TPU) switches attention
-  families to the vectorized bulk-insert + flash-prefill-kernel path.
+  scan). On a TPU, attention families take the "fused" implementation
+  instead: the vectorized bulk-insert + flash-prefill-kernel path.
 * "token" — the PR-7 path, kept bitwise: the prompt feeds through the
   per-slot decode step one token per cycle.
 
@@ -70,7 +70,6 @@ attempt a `fold_in(fold_in(kreq, 1), a)`; downlink attempt a
 from __future__ import annotations
 
 import dataclasses
-import os as _os
 import time
 from functools import partial
 from typing import Optional, Tuple
@@ -262,7 +261,6 @@ class ServeEngine:
         cfg, B = self.cfg, self.n_slots
         sc = ShapeConfig("serve", S, B, "decode")
         paged = self.kv == "paged"
-        impl = _os.environ.get("REPRO_PREFILL_IMPL", "auto")
         out = {"buckets": prefill_buckets(self.chunk_size)}
 
         def sample(lg, keys, temperature, greedy):
@@ -296,7 +294,7 @@ class ServeEngine:
             out["zero_pages"] = zero_pages
 
             if self.prefill == "chunked":
-                pf = make_paged_prefill_step(cfg, sc, self.page_size, impl)
+                pf = make_paged_prefill_step(cfg, sc, self.page_size)
 
                 @partial(jax.jit, static_argnames=("greedy",))
                 def prefill_sample(params, cache, tokens, start, n_valid,
@@ -342,7 +340,7 @@ class ServeEngine:
             out["reset"] = reset_slot
 
             if self.prefill == "chunked":
-                pf = make_prefill_step(cfg, sc, impl)
+                pf = make_prefill_step(cfg, sc)
 
                 @partial(jax.jit, static_argnames=("greedy",))
                 def prefill_sample(params, cache, tokens, start, n_valid,
@@ -358,13 +356,24 @@ class ServeEngine:
 
     def warmup_compile(self, max_seq_len: int) -> float:
         """AOT-compile every jitted entry point the serve loop will hit
-        for `max_seq_len`: the batched decode-sample step AND (chunked
-        mode) one prefill-sample executable per power-of-two bucket.
-        Returns the COMPILE wall seconds — tracing/lowering is done
-        first and excluded, because it is paid by every process while
-        the persistent compile cache (launch/compile_cache.py) only
-        short-circuits XLA compilation: on a warm cache the returned
-        wall collapses to deserialization time."""
+        for `max_seq_len` (`lower`). Returns the COMPILE wall seconds —
+        tracing/lowering is done first and excluded, because it is paid
+        by every process while the persistent compile cache
+        (launch/compile_cache.py) only short-circuits XLA compilation:
+        on a warm cache the returned wall collapses to deserialization
+        time."""
+        lowered = self.lower(max_seq_len)
+        t0 = time.perf_counter()
+        for low in lowered.values():
+            low.compile()
+        return time.perf_counter() - t0
+
+    def lower(self, max_seq_len: int) -> dict:
+        """Lower, on abstract inputs, every jitted entry point the serve
+        loop will hit for `max_seq_len`: {"decode": the batched
+        decode-sample step, "zero_pages" (paged KV), and (chunked mode)
+        "prefill_<C>": one prefill-sample program per power-of-two
+        bucket C}."""
         S = max(8, int(max_seq_len))
         built = self._build(S)
         cfg, B = self.cfg, self.n_slots
@@ -385,34 +394,31 @@ class ServeEngine:
         keys = jax.ShapeDtypeStruct((B, 2), jnp.uint32)
         act = jax.ShapeDtypeStruct((B,), jnp.bool_)
         temp = jax.ShapeDtypeStruct((), jnp.float32)
-        lowered = []
+        lowered = {}
         if paged:
             tbl = jax.ShapeDtypeStruct((B, built["n_lp"]), i32)
-            lowered.append(built["decode"].lower(
+            lowered["decode"] = built["decode"].lower(
                 params_sds, cache_sds, tok, idx, keys, tbl, act, temp,
-                greedy=self.greedy))
-            lowered.append(built["zero_pages"].lower(
-                cache_sds, jax.ShapeDtypeStruct((built["n_lp"],), i32)))
+                greedy=self.greedy)
+            lowered["zero_pages"] = built["zero_pages"].lower(
+                cache_sds, jax.ShapeDtypeStruct((built["n_lp"],), i32))
         else:
-            lowered.append(built["decode"].lower(
+            lowered["decode"] = built["decode"].lower(
                 params_sds, cache_sds, tok, idx, keys, act, temp,
-                greedy=self.greedy))
+                greedy=self.greedy)
         if "prefill_sample" in built:
             for C in built["buckets"]:
                 toks = jax.ShapeDtypeStruct((B, C), i32)
                 nv = jax.ShapeDtypeStruct((B,), i32)
                 if paged:
-                    lowered.append(built["prefill_sample"].lower(
+                    lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
                         params_sds, cache_sds, toks, idx, nv, tbl, keys,
-                        temp, greedy=self.greedy))
+                        temp, greedy=self.greedy)
                 else:
-                    lowered.append(built["prefill_sample"].lower(
+                    lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
                         params_sds, cache_sds, toks, idx, nv, keys,
-                        temp, greedy=self.greedy))
-        t0 = time.perf_counter()
-        for low in lowered:
-            low.compile()
-        return time.perf_counter() - t0
+                        temp, greedy=self.greedy)
+        return lowered
 
     # ------------------------------------------------------------- radio
     def _bill(self, res: RequestResult, d, leg: str) -> None:

@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def make_mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+def make_mesh(shape=(2, 4), axes=("data", "model")):
+    from repro.launch.mesh import make_mesh as auto_mesh
+    return auto_mesh(shape, axes)
 
 
 def check_decode_attention_dist():
@@ -140,7 +141,7 @@ def check_scaled_fl_scheme_pod():
             res = exp.run()
         return res, exp
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     res_m, exp_m = run(mesh)
     res_0, _ = run(None)
     assert np.isfinite(res_m.loss).all()
@@ -190,7 +191,7 @@ def check_fleet_pod():
     ref_reps, ref_det = bill(None)
     assert sum(r.erased_bits for r in ref_reps) > 0   # chaos fired
     for k in (1, 2, 4, 8):
-        reps, det = bill(jax.make_mesh((k,), ("pod",)))
+        reps, det = bill(make_mesh((k,), ("pod",)))
         for c, (a, b) in enumerate(zip(ref_reps, reps)):
             for f in ("bits", "n_tx", "energy_j", "erased_bits",
                       "outage_s", "steps", "loss"):
@@ -205,6 +206,25 @@ def check_fleet_pod():
     print("OK fleet_pod")
 
 
+def check_chip_smoke_pod():
+    """chip_smoke.py's four-chip phase on four host devices: the pod-mesh
+    FL round (one user per `pod` slot) against the same round on one
+    device — cycle-1 loss within rtol 2e-4, bills equal."""
+    import importlib.util
+    from repro.configs import get_arch
+    from repro.configs.base import ShapeConfig
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(os.path.dirname(__file__), "..",
+                                   "chip_smoke.py"))
+    cs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cs)
+    out = cs.phase_pod_fl(get_arch("qwen1.5-0.5b").reduced(),
+                          ShapeConfig("t", 16, 2, "train"),
+                          make_mesh((4, 1, 1), ("pod", "data", "model")))
+    assert out["mesh"] == {"pod": 4, "data": 1, "model": 1}
+    print("OK chip_smoke_pod")
+
+
 CHECKS = {
     "decode_attention_dist": check_decode_attention_dist,
     "moe_ep": check_moe_ep,
@@ -212,6 +232,7 @@ CHECKS = {
     "fl_pod_step": check_fl_pod_step,
     "scaled_fl_scheme_pod": check_scaled_fl_scheme_pod,
     "fleet_pod": check_fleet_pod,
+    "chip_smoke_pod": check_chip_smoke_pod,
 }
 
 if __name__ == "__main__":

@@ -21,6 +21,6 @@ def run_check(name: str):
 
 @pytest.mark.parametrize("name", ["decode_attention_dist", "moe_ep",
                                   "train_step_sharded", "fl_pod_step",
-                                  "fleet_pod"])
+                                  "fleet_pod", "chip_smoke_pod"])
 def test_distributed(name):
     run_check(name)

@@ -342,13 +342,13 @@ def test_scaled_cl_parity_vs_legacy_loop():
     """ScaledCentralizedScheme through Experiment == the deleted
     launch/train.py loop (same step factory, same key folds, same
     batches): identical loss trajectory and bitwise-identical params."""
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_mesh
     from repro.nn import use_mesh
     from repro.runtime.train_step import init_train_state, make_train_step
     from repro.schemes import ScaledCentralizedScheme
     cfg, shape = _scaled_cfg_shape()
     seed, cycles, spc, lr = 0, 2, 2, 1e-3
-    with use_mesh(make_test_mesh()):
+    with use_mesh(make_mesh((1, 1), ("data", "model"))):
         scheme = build_scheme(None, cfg=cfg, shape=shape,
                               steps_per_cycle=spc)
         assert isinstance(scheme, ScaledCentralizedScheme)
@@ -382,7 +382,7 @@ def test_scaled_fl_parity_vs_legacy_loop():
     """ScaledFederatedScheme through Experiment == a straight
     make_fl_train_step cycle loop, with the sync billed at the paper's
     per-user convention (no ARQ: one tx per (user, leaf) packet)."""
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_mesh
     from repro.nn import use_mesh
     from repro.runtime.fl_runtime import make_fl_train_step
     from repro.runtime.train_step import init_train_state
@@ -392,7 +392,7 @@ def test_scaled_fl_parity_vs_legacy_loop():
     seed, cycles, lr = 0, 2, 1e-3
     wcfg = WirelessConfig(mode="fl", quant_bits=8, local_steps=2,
                           n_users=2)
-    with use_mesh(make_test_mesh()):
+    with use_mesh(make_mesh((1, 1), ("data", "model"))):
         scheme = build_scheme(wcfg, cfg=cfg, shape=shape)
         assert isinstance(scheme, ScaledFederatedScheme)
         exp = Experiment(scheme, cycles=cycles, seed=seed, n_train=64,

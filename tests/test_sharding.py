@@ -11,7 +11,7 @@ import pytest
 from _hyp import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.nn.sharding import resolve_spec, use_mesh, constrain
 from repro.optim import sgd_momentum, adamw, clip_by_global_norm, global_norm
 from repro.optim.clip import clip_array_by_norm
@@ -26,8 +26,8 @@ pytestmark = pytest.mark.skipif(
 @pytest.fixture(scope="module")
 def mesh():
     if len(jax.devices()) >= 4:
-        return make_test_mesh((2, 2), ("data", "model"))
-    return make_test_mesh((1, 1), ("data", "model"))
+        return make_mesh((2, 2), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # ------------------------------------------------------------- resolver
@@ -43,9 +43,9 @@ def test_resolver_basic(mesh):
        d1=st.sampled_from([1, 2, 5, 16, 128]))
 def test_resolver_divisibility_invariant(d0, d1):
     """An axis is only assigned when the mesh-axis size divides the dim."""
-    mesh = make_test_mesh((1, 1), ("data", "model")) \
+    mesh = make_mesh((1, 1), ("data", "model")) \
         if len(jax.devices()) < 4 else \
-        make_test_mesh((2, 2), ("data", "model"))
+        make_mesh((2, 2), ("data", "model"))
     spec = resolve_spec((d0, d1), ("batch", "mlp"), mesh)
     parts = tuple(spec) + (None,) * (2 - len(tuple(spec)))
     for dim, part in zip((d0, d1), parts):
@@ -87,10 +87,8 @@ def test_constrain_under_mesh(mesh):
 def test_users_axis_resolves_to_pod():
     """The FL user axis maps onto `pod` (and batch degrades to data,
     pod being taken) — the scaled FL scheme's pod-mesh layout."""
-    if len(jax.devices()) >= 8:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    else:
-        mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    shape = (2, 2, 2) if len(jax.devices()) >= 8 else (1, 1, 1)
+    mesh = make_mesh(shape, ("pod", "data", "model"))
     spec = resolve_spec((2, 8, 16), ("users", "batch", None), mesh)
     assert spec == P("pod", "data")
 

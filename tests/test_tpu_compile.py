@@ -1,0 +1,111 @@
+"""Compile rehearsals for one TPU v5e chip: the main path's Pallas kernels
+at real widths, compiled by the TPU compiler (interpret=False) for a
+described, unattached v5e. Nothing runs; a kernel the chip's compiler
+would refuse (tile alignment, unsupported casts, too much VMEM) fails
+here at no chip time. The topology is described inside a fixture — never
+at import — so that every xdist worker collects the same tests and only
+the worker given this file loads the TPU library."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# qwen1.5-0.5b serving widths: 16 query and 16 KV heads of 64, 16-token
+# pages, 8 slots, bfloat16 caches; a 32-token prefill chunk
+B, H, HD, PAGE, N_LP, CHUNK = 8, 16, 64, 16, 8, 32
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A SingleDeviceSharding on chip 0 of a described v5e:2x2, with the
+    persistent compile cache off (its entries for an unattached TPU
+    cannot be read back here)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_was = jax.config.jax_enable_compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            try:
+                topo = topologies.get_topology_desc(
+                    platform="tpu", topology_name="v5e:2x2")
+            except Exception as e:  # noqa: BLE001 - any failure means skip
+                pytest.skip(f"no v5e:2x2 topology can be described here: "
+                            f"{e}")
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_was)
+            compilation_cache.reset_cache()
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_paged_attention_kernels_compile(one_chip, kind):
+    from repro.kernels.decode_attention.ops import gqa_decode_paged
+    from repro.kernels.prefill_attention.ops import gqa_prefill_paged
+    pool = ((B * N_LP, H, PAGE, HD), BF16)
+    tables = ((B, N_LP), jnp.int32)
+    pos = ((B,), jnp.int32)
+    if kind == "decode":
+        _compile(lambda q, k, v, t, n: gqa_decode_paged(
+            q, k, v, t, n, interpret=False),
+            [((B, H, HD), BF16), pool, pool, tables, pos], one_chip)
+    else:
+        _compile(lambda q, k, v, t, s: gqa_prefill_paged(
+            q, k, v, t, s, interpret=False),
+            [((B, CHUNK, H, HD), BF16), pool, pool, tables, pos], one_chip)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_dense_attention_kernels_compile(one_chip, kind):
+    from repro.kernels.decode_attention.ops import gqa_decode
+    from repro.kernels.prefill_attention.ops import gqa_prefill
+    cache = ((B, H, N_LP * PAGE, HD), BF16)
+    pos = ((B,), jnp.int32)
+    if kind == "decode":
+        _compile(lambda q, k, v, n: gqa_decode(q, k, v, n, interpret=False),
+                 [((B, H, HD), BF16), cache, cache, pos], one_chip)
+    else:
+        _compile(lambda q, k, v, s: gqa_prefill(q, k, v, s, interpret=False),
+                 [((B, CHUNK, H, HD), BF16), cache, cache, pos], one_chip)
+
+
+def _paper_fl_rows() -> int:
+    """Packed rows of ONE user's upload of the paper model (89,673
+    parameters) — what the FL sync stacks per user."""
+    from repro.core import wire as W
+    from repro.models.api import param_specs
+    from repro.nn import shapes_tree
+    from repro.schemes.federated import CFG
+    return W.plan_for(shapes_tree(param_specs(CFG))).n_rows
+
+
+@pytest.mark.parametrize("rows", ["paper", 13])
+@pytest.mark.parametrize("fused_mean", [False, True])
+def test_packed_wire_kernels_compile(one_chip, rows, fused_mean):
+    """The FL upload of 3 users through the packed wire: per-user rows of
+    the paper model (a multiple of 8 by construction) and a row count
+    that is not, which the kernel pads inside."""
+    from repro.kernels.quant_channel.kernel import (packed_wire_2d,
+                                                    packed_wire_mean_2d)
+    n = 3
+    r = _paper_fl_rows() if rows == "paper" else rows
+    col = ((n * r, 1), jnp.float32)
+    shapes = [((n * r, 256), jnp.float32), ((n * r, 256), jnp.uint32),
+              col, col]
+    if fused_mean:
+        _compile(lambda b, rd, s, p, w: packed_wire_mean_2d(
+            b, rd, s, p, w, 8, n, interpret=False), shapes + [col],
+            one_chip)
+    else:
+        _compile(lambda b, rd, s, p: packed_wire_2d(
+            b, rd, s, p, 8, interpret=False), shapes, one_chip)
