@@ -14,12 +14,12 @@ HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link
 
 
-def make_mesh(shape, axes, devices=None):
+def make_mesh(shape, axes):
     """A mesh of `shape` over the first devices, every axis Auto — the
     only kind `nn.sharding.constrain` (with_sharding_constraint) can
     name. Raises when there are fewer devices than the shape needs."""
     n = math.prod(shape)
-    devices = list(jax.devices() if devices is None else devices)
+    devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(
             f"mesh {tuple(shape)} needs {n} devices, have {len(devices)}")

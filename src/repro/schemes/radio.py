@@ -170,6 +170,17 @@ class Radio:
         return "kernel" if (self.use_kernel and not self.perfect) \
             else "packed"
 
+    def wire_kwargs(self) -> dict:
+        """The link knobs a float send passes to the packed wire
+        (`wire.transmit_tree` / `wire.transmit_stacked`) after the key,
+        tree, quant_bits and snr_db."""
+        return dict(fading=self.fading, perfect=self.perfect,
+                    arq_attempts=self.arq_attempts,
+                    arq_min_f2=self.arq_min_f2, impl=self._impl(),
+                    wire_dtype=self.wire_dtype, arq_max_tx=self.arq_max_tx,
+                    ge_p_gb=self.ge_p_gb, ge_p_bg=self.ge_p_bg,
+                    rounding=self.rounding)
+
     def _deliver(self, payload, n_tx, sizes, erased=None) -> Delivery:
         n_tx = np.asarray(n_tx, np.float64)
         sizes = np.asarray(sizes, np.float64)
@@ -203,12 +214,8 @@ class Radio:
         """Transmit every leaf of a pytree (one packet per tensor) via
         the fused packed wire. SL legs, single-user weight uploads."""
         payload, diag = W.transmit_tree(
-            key, tree, self.quant_bits, self.snr_db, fading=self.fading,
-            perfect=self.perfect, arq_attempts=self.arq_attempts,
-            arq_min_f2=self.arq_min_f2, impl=self._impl(),
-            return_diag=True, wire_dtype=self.wire_dtype,
-            arq_max_tx=self.arq_max_tx, ge_p_gb=self.ge_p_gb,
-            ge_p_bg=self.ge_p_bg, rounding=self.rounding)
+            key, tree, self.quant_bits, self.snr_db, return_diag=True,
+            **self.wire_kwargs())
         sizes = [int(l.size) for l in jax.tree.leaves(tree)]
         return self._deliver(payload, diag["n_tx"], sizes, diag["erased"])
 
@@ -219,12 +226,8 @@ class Radio:
         user axis; aggregation is the caller's (scheme's) job."""
         leaves = jax.tree.leaves(tree)
         payload, diag = W.transmit_stacked(
-            key, tree, self.quant_bits, self.snr_db, fading=self.fading,
-            perfect=self.perfect, arq_attempts=self.arq_attempts,
-            arq_min_f2=self.arq_min_f2, impl=self._impl(),
-            return_diag=True, wire_dtype=self.wire_dtype,
-            arq_max_tx=self.arq_max_tx, ge_p_gb=self.ge_p_gb,
-            ge_p_bg=self.ge_p_bg, rounding=self.rounding)
+            key, tree, self.quant_bits, self.snr_db, return_diag=True,
+            **self.wire_kwargs())
         sizes = [int(l.size) // int(l.shape[0]) for l in leaves]
         return self._deliver(payload, diag["n_tx"], sizes, diag["erased"])
 

@@ -237,9 +237,12 @@ class ScaledCentralizedScheme(_ScaledScheme):
 
     def __init__(self, cfg, shape=None, wcfg=None, **kw):
         super().__init__(cfg, shape, wcfg, **kw)
+        # the train state is donated, as in `lower_step`: each step
+        # updates it in place, so a cycle holds one state, not one per
+        # step (`round` never reads the state it passed in again)
         self._exe = jax.jit(make_train_step(
             cfg, self.shape, None, optimizer=self.optimizer,
-            n_data_shards=self.n_data_shards))
+            n_data_shards=self.n_data_shards), donate_argnums=(0,))
 
     def _step_wcfg(self):
         return None
@@ -321,7 +324,7 @@ class ScaledSplitScheme(ScaledCentralizedScheme):
         self.perfect_eval = perfect_eval
         self._exe = jax.jit(make_train_step(
             cfg, self.shape, wcfg, optimizer=self.optimizer,
-            n_data_shards=self.n_data_shards))
+            n_data_shards=self.n_data_shards), donate_argnums=(0,))
         self._n_micro = auto_microbatch(cfg, self.shape,
                                         self.n_data_shards)
         # one leg's payload per optimizer step (all microbatches)
@@ -453,8 +456,9 @@ class ScaledFederatedScheme(_ScaledScheme):
         from repro.nn import current_mesh
         self._mesh = current_mesh()
         self._train_sh = None
+        # the train state is donated, as in `lower_step`
         if self._mesh is None:
-            self._exe = jax.jit(step)
+            self._exe = jax.jit(step, donate_argnums=(0,))
         else:
             state_sh = train_state_sds_and_shardings(
                 cfg, None, self._mesh, "sgd", n_users=self.n_users)[1]
@@ -462,7 +466,7 @@ class ScaledFederatedScheme(_ScaledScheme):
             self._train_sh = self._as_train(state_sh)
             self._exe = jax.jit(
                 step, in_shardings=(self._train_sh, batch_sh, None, None),
-                out_shardings=(self._train_sh, None))
+                out_shardings=(self._train_sh, None), donate_argnums=(0,))
         # per-packet payload of the stacked sync: one packet per
         # (user, model leaf), sized by the per-user leaf
         specs = M.param_specs(cfg)
@@ -501,6 +505,10 @@ class ScaledFederatedScheme(_ScaledScheme):
             lambda p: jnp.broadcast_to(p, (self.n_users,) + p.shape),
             state0)
         train = self._as_train(user_states)
+        if self.sync == "delayed":
+            # the aggregate gets buffers of its own: the round donates
+            # the whole carry, and no buffer can be donated twice
+            train["agg"] = jax.tree.map(jnp.copy, train["agg"])
         if self._train_sh is not None:
             # commit to the executable's declared input shardings so
             # round 0 presents the same arg signature as every later

@@ -16,17 +16,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def make_mesh(shape=(2, 4), axes=("data", "model")):
-    from repro.launch.mesh import make_mesh as auto_mesh
-    return auto_mesh(shape, axes)
+from repro.launch.mesh import make_mesh
 
 
 def check_decode_attention_dist():
     """Sharded flash-decode == single-device reference."""
     from repro.models.layers import decode_attention_jnp, \
         decode_attention_dist
-    mesh = make_mesh()
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     kq, kk, kv = jax.random.split(key, 3)
     B, Hkv, G, S, hd = 2, 4, 2, 64, 16
@@ -49,7 +46,7 @@ def check_moe_ep():
     from repro.configs import get_arch
     from repro.models.moe import _moe_chunked, _moe_ep, moe_specs
     from repro.nn import init_params, use_mesh
-    mesh = make_mesh()
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = dataclasses.replace(get_arch("qwen3-moe-235b-a22b").reduced(),
                               capacity_factor=8.0)   # no drops -> exact
     p = init_params(jax.random.PRNGKey(0), moe_specs(cfg))
@@ -83,7 +80,7 @@ def check_train_step_sharded():
     step = make_train_step(cfg, shape, None)
     _, m_ref = jax.jit(step)(state0, batch, jax.random.PRNGKey(1))
 
-    mesh = make_mesh()
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         state0 = init_train_state(key, cfg, None, "adamw")
         _, m_sh = jax.jit(step)(state0, batch, jax.random.PRNGKey(1))
@@ -102,7 +99,7 @@ def check_fl_pod_step():
     cfg = get_arch("qwen1.5-0.5b").reduced()
     shape = ShapeConfig("t", 32, 4, "train", microbatch=4)
     wcfg = WirelessConfig(mode="fl", quant_bits=8, local_steps=2)
-    mesh = make_mesh()
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         state = init_train_state(jax.random.PRNGKey(0), cfg, None, "sgd")
         state = jax.tree.map(

@@ -467,6 +467,29 @@ def test_scaled_sl_parity_and_drawn_arq_billing():
         assert rep.bits == pytest.approx(rep.n_tx * leg * 8)
 
 
+@pytest.mark.parametrize("mode,sync", [("cl", "barrier"), ("sl", "barrier"),
+                                       ("fl", "barrier"), ("fl", "delayed")])
+def test_scaled_round_donates_train_state(mode, sync):
+    """The scaled schemes' run program donates the train state, like the
+    `lower_step` program the dry-run reads memory from: a round frees
+    the state it was given, and two rounds in a row run (the delayed-FL
+    carry holds no buffer twice)."""
+    cfg, shape = _scaled_cfg_shape()
+    wcfg = None if mode == "cl" else WirelessConfig(
+        mode=mode, quant_bits=8, local_steps=2, n_users=2, sync=sync)
+    scheme = build_scheme(wcfg, cfg=cfg, shape=shape, steps_per_cycle=2)
+    (xtr, ytr), _ = scheme.default_data(64, 16, 0)
+    state, _ = scheme.init(0, xtr, ytr)
+    rng = np.random.default_rng(1)
+    for cyc in range(2):
+        before = jax.tree.leaves(state.train)
+        state, rep = scheme.round(state, scheme.cycle_batches(state, rng, cyc),
+                                  scheme.round_key(0, cyc), 1e-3)
+        assert all(x.is_deleted() for x in before)
+        assert np.isfinite(rep.loss)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(state.train))
+
+
 def test_wire_diag_does_not_change_payload():
     """return_diag is accounting-only: same key -> same received tree."""
     tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (33, 9))}
