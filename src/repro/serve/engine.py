@@ -60,6 +60,17 @@ Engine invariants (pinned by tests/test_serve.py):
   admission (dense: batch-row zero; paged: its pages are zeroed when
   reallocated), so no stale KV / recurrent state leaks across users.
 
+Host spans: each pass of the serve loop is a `serve.cycle` span; inside
+it `serve.admit` (one request into a slot, holding `serve.prompt`, the
+prompt draw, and `serve.uplink`), `serve.keys` (one sampling-key
+derivation), `serve.prefill.wait` / `serve.decode.wait` (the host
+waiting on a step's tokens) and `serve.downlink`; per-request spans
+carry `rid`. They are `jax.profiler.TraceAnnotation`s, on the device
+trace's timeline while a profiler records, and their host seconds and
+counts add up in `ServeReport.spans` either way, beside
+`ServeReport.host_syncs`, the blocking device-to-host reads the engine
+makes (prompt draws, delivered payloads, sampling keys, step tokens).
+
 RNG streams (all under `PRNGKey(trace.seed + 13)`, disjoint from every
 training stream — docs/ACCOUNTING.md §RNG): per request rid,
 `kreq = fold_in(base, rid)`; prompt content `fold_in(kreq, 3)`; uplink
@@ -69,6 +80,7 @@ attempt a `fold_in(fold_in(kreq, 1), a)`; downlink attempt a
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -106,12 +118,10 @@ class RequestResult:
     tokens: Tuple[int, ...] = ()
     prompt_len: int = 0
     snr_db: float = 0.0
-    admit_cycle: int = -1
-    complete_cycle: int = -1
     latency_cycles: int = -1     # completion - arrival + 1 (queue incl.)
     first_token_cycle: int = -1
     ttft_cycles: int = -1        # first token - arrival + 1 (queue incl.)
-    ttft_s: float = -1.0         # admission -> first token, wall seconds
+    ttft_s: float = -1.0         # admission -> first token, seconds
     uplink_bits: float = 0.0
     downlink_bits: float = 0.0
     bits: float = 0.0
@@ -133,6 +143,10 @@ class ServeReport:
     kv: str = "dense"
     n_pages: int = 0             # paged: pool size (0 for dense)
     peak_pages: int = 0          # paged: high-water pages in use
+    #: blocking device-to-host reads the engine made (not the Radio's)
+    host_syncs: int = 0
+    #: span name -> (host seconds, count) of each `serve.*` span
+    spans: dict = dataclasses.field(default_factory=dict)
 
     @property
     def generated_tokens(self) -> int:
@@ -477,27 +491,68 @@ class ServeEngine:
             cache = self._model.init_cache(cfg, B, S)
             tables = None
         qi, cycle = 0, 0
-        t0 = time.time()
+        syncs = 0
+        spans = {}
+        t0 = time.perf_counter()
 
-        def admit(r) -> Optional[dict]:
+        @contextlib.contextmanager
+        def span(name: str, **meta):
+            """A profiler span (`TraceAnnotation`, recorded only while a
+            profiler traces) whose host seconds and count also add up
+            in the report."""
+            ts = time.perf_counter()
+            with jax.profiler.TraceAnnotation(name, **meta):
+                yield
+            tot = spans.setdefault(name, [0.0, 0])
+            tot[0] += time.perf_counter() - ts
+            tot[1] += 1
+
+        def sample_key(st, t: int) -> np.ndarray:
+            nonlocal syncs
+            with span("serve.keys", rid=st["r"].rid):
+                syncs += 1
+                return np.asarray(jax.random.fold_in(
+                    jax.random.fold_in(st["kreq"], 9), t))
+
+        def admit(b: int, r, need: int) -> bool:
+            """Request `r` into slot `b`: its prompt drawn and sent up,
+            then the slot's pages (paged) or row (dense) zeroed. False
+            when every uplink try was erased: the request is abandoned
+            and its bill stands."""
+            nonlocal cache, syncs
             kreq = jax.random.fold_in(base, r.rid)
             res = RequestResult(r.rid, prompt_len=r.prompt_len,
                                 snr_db=r.snr_db)
             results[r.rid] = res
-            prompt = np.asarray(jax.random.randint(
-                jax.random.fold_in(kreq, 3), (r.prompt_len,), 1,
-                cfg.vocab_size, jnp.int32))
+            with span("serve.prompt", rid=r.rid):
+                syncs += 1
+                prompt = np.asarray(jax.random.randint(
+                    jax.random.fold_in(kreq, 3), (r.prompt_len,), 1,
+                    cfg.vocab_size, jnp.int32))
             radio = dataclasses.replace(self.radio, snr_db=r.snr_db)
-            rx, erased = self._send_row(radio, jax.random.fold_in(kreq, 1),
-                                        prompt, cfg.vocab_size, res, "up")
+            with span("serve.uplink", rid=r.rid):
+                rx, erased = self._send_row(
+                    radio, jax.random.fold_in(kreq, 1), prompt,
+                    cfg.vocab_size, res, "up")
             if erased:
-                res.status = "uplink_erased"     # abandoned, bill stands
-                return None
+                res.status = "uplink_erased"
+                return False
+            syncs += 1
             res.status = "serving"
-            res.admit_cycle = cycle
-            return {"r": r, "res": res, "kreq": kreq, "radio": radio,
-                    "prompt": rx, "pos": 0, "last": 0, "new": [],
-                    "admit_wall": time.time()}
+            slots[b] = {"r": r, "res": res, "kreq": kreq, "radio": radio,
+                        "prompt": rx, "pos": 0, "last": 0, "new": [],
+                        "admit_wall": time.perf_counter()}
+            if paged:
+                pids = pool.alloc(need)
+                slots[b]["pgs"] = pids
+                tables[b, :] = 0
+                tables[b, :len(pids)] = pids
+                cache = built["zero_pages"](cache, jnp.asarray(
+                    np.pad(pids, (0, n_lp - len(pids)),
+                           constant_values=n_pages), jnp.int32))
+            else:
+                cache = built["reset"](cache, jnp.int32(b))
+            return True
 
         def push_token(st, tok: int) -> None:
             st["new"].append(tok)
@@ -506,162 +561,156 @@ class ServeEngine:
                 res = st["res"]
                 res.first_token_cycle = cycle
                 res.ttft_cycles = cycle - st["r"].arrival_cycle + 1
-                res.ttft_s = time.time() - st["admit_wall"]
+                res.ttft_s = time.perf_counter() - st["admit_wall"]
 
         def complete(st) -> None:
+            nonlocal syncs
             r, res = st["r"], st["res"]
             gen = np.asarray(st["new"], np.int32)
-            _, erased = self._send_row(st["radio"],
-                                       jax.random.fold_in(st["kreq"], 2),
-                                       gen, self.out_vocab, res, "down")
+            with span("serve.downlink", rid=r.rid):
+                _, erased = self._send_row(
+                    st["radio"], jax.random.fold_in(st["kreq"], 2), gen,
+                    self.out_vocab, res, "down")
+            syncs += not erased
             res.status = "downlink_erased" if erased else "ok"
             res.tokens = tuple(int(t) for t in gen)
-            res.complete_cycle = cycle
             res.latency_cycles = cycle - r.arrival_cycle + 1
             if paged:
                 pool.free(st.pop("pgs"))
 
         while qi < len(reqs) or any(s is not None for s in slots):
-            # ---- admission (continuous: any free slot; static: barrier)
-            if not barrier or all(s is None for s in slots):
-                blocked = False          # paged: FIFO head-of-line wait
-                for b in range(B):
-                    if blocked or slots[b] is not None:
-                        continue
-                    while qi < len(reqs) \
-                            and reqs[qi].arrival_cycle <= cycle:
-                        r = reqs[qi]
-                        if paged:
-                            need = pages_needed(r.prompt_len,
-                                                r.max_new_tokens,
-                                                self.page_size)
-                            if need > n_pages:
-                                raise ValueError(
-                                    f"request {r.rid} needs {need} pages "
-                                    f"but the pool has {n_pages}; raise "
-                                    f"page_budget")
-                            if not pool.can_alloc(need):
-                                blocked = True
-                                break
-                        st = admit(r)
-                        qi += 1
-                        if st is not None:
+            with span("serve.cycle"):
+                # ---- admission (continuous: any free slot; static: barrier)
+                if not barrier or all(s is None for s in slots):
+                    blocked = False      # paged: FIFO head-of-line wait
+                    for b in range(B):
+                        if blocked or slots[b] is not None:
+                            continue
+                        while qi < len(reqs) \
+                                and reqs[qi].arrival_cycle <= cycle:
+                            r = reqs[qi]
+                            need = 0
                             if paged:
-                                pids = pool.alloc(need)
-                                st["pgs"] = pids
-                                tables[b, :] = 0
-                                tables[b, :len(pids)] = pids
-                                cache = built["zero_pages"](
-                                    cache,
-                                    jnp.asarray(np.pad(
-                                        pids, (0, n_lp - len(pids)),
-                                        constant_values=n_pages),
-                                        jnp.int32))
-                            else:
-                                cache = built["reset"](cache, jnp.int32(b))
-                            slots[b] = st
-                            break
-            if not any(s is not None for s in slots):
-                if qi < len(reqs):   # idle: jump to the next arrival
-                    cycle = max(cycle + 1, reqs[qi].arrival_cycle)
-                    continue
-                break
+                                need = pages_needed(r.prompt_len,
+                                                    r.max_new_tokens,
+                                                    self.page_size)
+                                if need > n_pages:
+                                    raise ValueError(
+                                        f"request {r.rid} needs {need} "
+                                        f"pages but the pool has "
+                                        f"{n_pages}; raise page_budget")
+                                if not pool.can_alloc(need):
+                                    blocked = True
+                                    break
+                            with span("serve.admit", rid=r.rid):
+                                seated = admit(b, r, need)
+                            qi += 1
+                            if seated:
+                                break
+                if not any(s is not None for s in slots):
+                    if qi < len(reqs):   # idle: jump to the next arrival
+                        cycle = max(cycle + 1, reqs[qi].arrival_cycle)
+                        continue
+                    break
 
-            tables_j = jnp.asarray(tables) if paged else None
-            pre = [b for b, st in enumerate(slots)
-                   if st is not None and chunked
-                   and st["pos"] < st["r"].prompt_len]
-            dec = [b for b, st in enumerate(slots)
-                   if st is not None and not (chunked
-                                              and st["pos"] < st["r"].prompt_len)]
+                tables_j = jnp.asarray(tables) if paged else None
+                pre = [b for b, st in enumerate(slots)
+                       if st is not None and chunked
+                       and st["pos"] < st["r"].prompt_len]
+                dec = [b for b, st in enumerate(slots)
+                       if st is not None and not (
+                           chunked and st["pos"] < st["r"].prompt_len)]
 
-            # ---- bucketed prefill chunks over the prefilling slots
-            if pre:
-                cmax = max(min(slots[b]["r"].prompt_len - slots[b]["pos"],
-                               self.chunk_size) for b in pre)
-                C = bucket_for(cmax, built["buckets"])
-                ptoks = np.zeros((B, C), np.int32)
-                pstart = np.zeros(B, np.int32)
-                pnv = np.zeros(B, np.int32)
-                pkeys = np.zeros((B, 2), np.uint32)
-                for b in pre:
-                    st = slots[b]
-                    c = min(st["r"].prompt_len - st["pos"], self.chunk_size)
-                    ptoks[b, :c] = st["prompt"][st["pos"]:st["pos"] + c]
-                    pstart[b] = st["pos"]
-                    pnv[b] = c
-                    if st["pos"] + c >= st["r"].prompt_len \
-                            and not self.greedy:
-                        pkeys[b] = np.asarray(jax.random.fold_in(
-                            jax.random.fold_in(st["kreq"], 9), 0))
-                if paged:
-                    nxtp, cache = built["prefill_sample"](
-                        self.params, cache, jnp.asarray(ptoks),
-                        jnp.asarray(pstart), jnp.asarray(pnv), tables_j,
-                        jnp.asarray(pkeys), jnp.float32(self.temperature),
-                        self.greedy)
-                else:
-                    nxtp, cache = built["prefill_sample"](
-                        self.params, cache, jnp.asarray(ptoks),
-                        jnp.asarray(pstart), jnp.asarray(pnv),
-                        jnp.asarray(pkeys), jnp.float32(self.temperature),
-                        self.greedy)
-                nxtp = np.asarray(nxtp)
-                for b in pre:
-                    st = slots[b]
-                    c = min(st["r"].prompt_len - st["pos"], self.chunk_size)
-                    st["pos"] += c
-                    if st["pos"] >= st["r"].prompt_len:
-                        push_token(st, int(nxtp[b]))
+                # ---- bucketed prefill chunks over the prefilling slots
+                if pre:
+                    chunk = {b: min(slots[b]["r"].prompt_len
+                                    - slots[b]["pos"], self.chunk_size)
+                             for b in pre}
+                    C = bucket_for(max(chunk.values()), built["buckets"])
+                    ptoks = np.zeros((B, C), np.int32)
+                    pstart = np.zeros(B, np.int32)
+                    pnv = np.zeros(B, np.int32)
+                    pkeys = np.zeros((B, 2), np.uint32)
+                    for b in pre:
+                        st, c = slots[b], chunk[b]
+                        ptoks[b, :c] = st["prompt"][st["pos"]:st["pos"] + c]
+                        pstart[b] = st["pos"]
+                        pnv[b] = c
+                        if st["pos"] + c >= st["r"].prompt_len \
+                                and not self.greedy:
+                            pkeys[b] = sample_key(st, 0)
+                    if paged:
+                        nxtp, cache = built["prefill_sample"](
+                            self.params, cache, jnp.asarray(ptoks),
+                            jnp.asarray(pstart), jnp.asarray(pnv),
+                            tables_j, jnp.asarray(pkeys),
+                            jnp.float32(self.temperature), self.greedy)
+                    else:
+                        nxtp, cache = built["prefill_sample"](
+                            self.params, cache, jnp.asarray(ptoks),
+                            jnp.asarray(pstart), jnp.asarray(pnv),
+                            jnp.asarray(pkeys),
+                            jnp.float32(self.temperature), self.greedy)
+                    with span("serve.prefill.wait"):
+                        syncs += 1
+                        nxtp = np.asarray(nxtp)
+                    for b in pre:
+                        st = slots[b]
+                        st["pos"] += chunk[b]
+                        if st["pos"] >= st["r"].prompt_len:
+                            push_token(st, int(nxtp[b]))
+                            if len(st["new"]) >= st["r"].max_new_tokens:
+                                complete(st)
+                                slots[b] = None
+
+                # ---- one batched decode cycle over the decoding slots
+                if dec:
+                    toks = np.zeros((B, 1), np.int32)
+                    idx = np.zeros(B, np.int32)
+                    keys = np.zeros((B, 2), np.uint32)
+                    active = np.zeros(B, bool)
+                    for b in dec:
+                        st = slots[b]
+                        P = st["r"].prompt_len
+                        toks[b, 0] = st["prompt"][st["pos"]] \
+                            if st["pos"] < P else st["last"]
+                        idx[b] = st["pos"]
+                        active[b] = True
+                        t = st["pos"] - (P - 1)
+                        if t >= 0 and not self.greedy:
+                            keys[b] = sample_key(st, t)
+                    if paged:
+                        nxt, cache = built["decode"](
+                            self.params, cache, jnp.asarray(toks),
+                            jnp.asarray(idx), jnp.asarray(keys), tables_j,
+                            jnp.asarray(active),
+                            jnp.float32(self.temperature), self.greedy)
+                    else:
+                        nxt, cache = built["decode"](
+                            self.params, cache, jnp.asarray(toks),
+                            jnp.asarray(idx), jnp.asarray(keys),
+                            jnp.asarray(active),
+                            jnp.float32(self.temperature), self.greedy)
+                    with span("serve.decode.wait"):
+                        syncs += 1
+                        nxt = np.asarray(nxt)
+                    for b in dec:
+                        st = slots[b]
+                        if st is None:
+                            continue
+                        if st["pos"] >= st["r"].prompt_len - 1:
+                            push_token(st, int(nxt[b]))
+                        st["pos"] += 1
                         if len(st["new"]) >= st["r"].max_new_tokens:
                             complete(st)
                             slots[b] = None
+                cycle += 1
 
-            # ---- one batched decode cycle over the decoding slots
-            if dec:
-                toks = np.zeros((B, 1), np.int32)
-                idx = np.zeros(B, np.int32)
-                keys = np.zeros((B, 2), np.uint32)
-                active = np.zeros(B, bool)
-                for b in dec:
-                    st = slots[b]
-                    P = st["r"].prompt_len
-                    toks[b, 0] = st["prompt"][st["pos"]] if st["pos"] < P \
-                        else st["last"]
-                    idx[b] = st["pos"]
-                    active[b] = True
-                    t = st["pos"] - (P - 1)
-                    if t >= 0 and not self.greedy:
-                        keys[b] = np.asarray(jax.random.fold_in(
-                            jax.random.fold_in(st["kreq"], 9), t))
-                if paged:
-                    nxt, cache = built["decode"](
-                        self.params, cache, jnp.asarray(toks),
-                        jnp.asarray(idx), jnp.asarray(keys), tables_j,
-                        jnp.asarray(active), jnp.float32(self.temperature),
-                        self.greedy)
-                else:
-                    nxt, cache = built["decode"](
-                        self.params, cache, jnp.asarray(toks),
-                        jnp.asarray(idx), jnp.asarray(keys),
-                        jnp.asarray(active), jnp.float32(self.temperature),
-                        self.greedy)
-                nxt = np.asarray(nxt)
-                for b in dec:
-                    st = slots[b]
-                    if st is None:
-                        continue
-                    if st["pos"] >= st["r"].prompt_len - 1:
-                        push_token(st, int(nxt[b]))
-                    st["pos"] += 1
-                    if len(st["new"]) >= st["r"].max_new_tokens:
-                        complete(st)
-                        slots[b] = None
-            cycle += 1
-
-        wall = time.time() - t0
+        wall = time.perf_counter() - t0
         ordered = tuple(results[r.rid] for r in reqs)
         return ServeReport(mode, B, ordered, cycle, wall,
                            prefill=self.prefill, kv=self.kv,
                            n_pages=built.get("n_pages", 0) if paged else 0,
-                           peak_pages=pool.peak_pages if paged else 0)
+                           peak_pages=pool.peak_pages if paged else 0,
+                           host_syncs=syncs,
+                           spans={k: tuple(v) for k, v in spans.items()})

@@ -456,3 +456,75 @@ def test_page_pool_deterministic_alloc_and_guards():
     assert prefill_buckets(20) == (4, 8, 16, 32)
     assert prefill_buckets(1) == (1,)
     assert bucket_for(5, (4, 8, 16)) == 8
+
+
+# ------------------------------------------------ host spans and syncs
+SPAN_TRACE = RequestTrace(5, (Request(0, 0, 3, 1), Request(1, 0, 5, 2),
+                              Request(2, 0, 4, 4)))
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
+def test_host_syncs_and_spans_match_the_hand_count(greedy):
+    """Three requests on four slots, every prompt in one chunk: cycle 0
+    prefills all three (one read-back), cycles 1-3 decode the rest (one
+    read-back each); each request costs a prompt draw, an uplink and a
+    downlink payload, and under sampling one key per generated token."""
+    eng = ServeEngine(TINY, params_for(TINY), n_slots=4, chunk_size=8,
+                      greedy=greedy)
+    rep = eng.serve(SPAN_TRACE)
+    keys = 0 if greedy else 1 + 2 + 4
+    assert rep.cycles == 4
+    assert rep.host_syncs == 3 * 3 + 4 + keys
+    counts = {k: n for k, (_, n) in rep.spans.items()}
+    want = {"serve.cycle": 4, "serve.admit": 3, "serve.prompt": 3,
+            "serve.uplink": 3, "serve.downlink": 3,
+            "serve.prefill.wait": 1, "serve.decode.wait": 3}
+    if keys:
+        want["serve.keys"] = keys
+    assert counts == want
+    assert all(s > 0 for s, _ in rep.spans.values())
+    cycle_s = rep.spans["serve.cycle"][0]
+    assert rep.spans["serve.admit"][0] <= cycle_s <= rep.wall_s
+
+
+def test_erased_payloads_are_not_host_syncs():
+    """On a link that erases whole rows, only delivered payloads are
+    read back: syncs = prompt draws + delivered uplinks and downlinks +
+    step read-backs (greedy: no keys)."""
+    eng = ServeEngine(TINY, params_for(TINY), n_slots=4, radio=HARSH,
+                      max_link_tries=2, greedy=True)
+    rep = eng.serve(make_trace(3, 16, prompt_lens=(3, 8),
+                               new_tokens=(2, 4), snr_dbs=(5.0,)))
+    n = {k: c for k, (_, c) in rep.spans.items()}
+    served = [r for r in rep.results if r.status != "uplink_erased"]
+    assert len(served) < len(rep.results)
+    assert rep.host_syncs == (len(rep.results) + len(served)
+                              + sum(r.status == "ok" for r in served)
+                              + n.get("serve.prefill.wait", 0)
+                              + n.get("serve.decode.wait", 0))
+
+
+def test_profiler_changes_no_token_or_bill(tmp_path):
+    """Serving under `jax.profiler.trace` gives the same tokens and
+    bills as without it, and the trace holds the engine's spans, as
+    many of each as the report counts, with each request's id."""
+    from jax.profiler import ProfileData
+    eng = ServeEngine(TINY, params_for(TINY), n_slots=4, chunk_size=8)
+    plain = eng.serve(SPAN_TRACE)
+    with jax.profiler.trace(str(tmp_path)):
+        traced = eng.serve(SPAN_TRACE)
+    assert [r.tokens for r in traced.results] == \
+        [r.tokens for r in plain.results]
+    assert _bill_rows(traced) == _bill_rows(plain)
+    assert traced.host_syncs == plain.host_syncs
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    seen, rids = {}, set()
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    seen[e.name] = seen.get(e.name, 0) + 1
+                    if e.name == "serve.admit":
+                        rids.add(dict(e.stats)["rid"])
+    assert seen == {k: n for k, (_, n) in traced.spans.items()}
+    assert rids == {0, 1, 2}
