@@ -62,21 +62,24 @@ Engine invariants (pinned by tests/test_serve.py):
 
 Host spans: each pass of the serve loop is a `serve.cycle` span; inside
 it `serve.admit` (one request into a slot, holding `serve.prompt`, the
-prompt draw, and `serve.uplink`), `serve.keys` (one sampling-key
-derivation), `serve.prefill.wait` / `serve.decode.wait` (the host
-waiting on a step's tokens) and `serve.downlink`; per-request spans
-carry `rid`. They are `jax.profiler.TraceAnnotation`s, on the device
-trace's timeline while a profiler records, and their host seconds and
-counts add up in `ServeReport.spans` either way, beside
-`ServeReport.host_syncs`, the blocking device-to-host reads the engine
-makes (prompt draws, delivered payloads, sampling keys, step tokens).
+prompt draw, and `serve.uplink`), `serve.keys` (filling one launch's
+`(rid, t)` rows for the slots it samples), `serve.prefill.wait` /
+`serve.decode.wait` (the host waiting on a step's tokens) and
+`serve.downlink`; per-request spans carry `rid`. They are
+`jax.profiler.TraceAnnotation`s, on the device trace's timeline while a
+profiler records, and their host seconds and counts add up in
+`ServeReport.spans` either way, beside `ServeReport.host_syncs`, the
+blocking device-to-host reads the engine makes (prompt draws, delivered
+payloads, step tokens).
 
 RNG streams (all under `PRNGKey(trace.seed + 13)`, disjoint from every
 training stream — docs/ACCOUNTING.md §RNG): per request rid,
 `kreq = fold_in(base, rid)`; prompt content `fold_in(kreq, 3)`; uplink
 attempt a `fold_in(fold_in(kreq, 1), a)`; downlink attempt a
 `fold_in(fold_in(kreq, 2), a)`; sampling for generated token t
-`fold_in(fold_in(kreq, 9), t)`.
+`fold_in(fold_in(kreq, 9), t)`. The sampling keys are derived inside the
+step programs (`sample_keys`) from `base` and each row's `(rid, t)`, so
+the host sends ids, not keys, and waits on no key.
 """
 from __future__ import annotations
 
@@ -108,6 +111,15 @@ SLOT_FAMILIES = ("dense", "moe", "vlm", "tiny")
 PAGED_FAMILIES = ("dense", "moe", "vlm")
 #: the serving RNG stream offset (docs/ACCOUNTING.md §RNG)
 SERVE_STREAM = 13
+
+
+def sample_keys(base, ids):
+    """[B, 2] sampling keys of rows `ids` = [B, 2] int32 `(rid, t)`
+    under the serving stream's `base` key:
+    `fold_in(fold_in(fold_in(base, rid), 9), t)`, the schedule in the
+    module docstring."""
+    return jax.vmap(lambda r, t: jax.random.fold_in(jax.random.fold_in(
+        jax.random.fold_in(base, r), 9), t))(ids[:, 0], ids[:, 1])
 
 
 @dataclasses.dataclass
@@ -277,11 +289,11 @@ class ServeEngine:
         paged = self.kv == "paged"
         out = {"buckets": prefill_buckets(self.chunk_size)}
 
-        def sample(lg, keys, temperature, greedy):
+        def sample(lg, base, ids, temperature, greedy):
             if greedy:
                 return jnp.argmax(lg, axis=-1)
             return jax.vmap(jax.random.categorical)(
-                keys, lg / jnp.maximum(temperature, 1e-6))
+                sample_keys(base, ids), lg / jnp.maximum(temperature, 1e-6))
 
         if paged:
             n_lp = -(-S // self.page_size)
@@ -290,12 +302,12 @@ class ServeEngine:
             pstep = make_paged_decode_step(cfg, sc, self.page_size)
 
             @partial(jax.jit, static_argnames=("greedy",))
-            def step_sample(params, cache, tokens, idx, keys, tables,
+            def step_sample(params, cache, tokens, idx, base, ids, tables,
                             active, temperature, greedy):
                 logits, cache = pstep(params, cache, tokens, idx, tables,
                                       active)
                 lg = logits[:, 0].astype(jnp.float32)
-                nxt = sample(lg, keys, temperature, greedy)
+                nxt = sample(lg, base, ids, temperature, greedy)
                 return nxt.astype(jnp.int32), cache
 
             @jax.jit
@@ -312,10 +324,10 @@ class ServeEngine:
 
                 @partial(jax.jit, static_argnames=("greedy",))
                 def prefill_sample(params, cache, tokens, start, n_valid,
-                                   tables, keys, temperature, greedy):
+                                   tables, base, ids, temperature, greedy):
                     lg, cache = pf(params, cache, tokens, start, n_valid,
                                    tables)
-                    nxt = sample(lg, keys, temperature, greedy)
+                    nxt = sample(lg, base, ids, temperature, greedy)
                     return nxt.astype(jnp.int32), cache
 
                 out["prefill_sample"] = prefill_sample
@@ -331,13 +343,13 @@ class ServeEngine:
                 return jnp.where(m, new, old)
 
             @partial(jax.jit, static_argnames=("greedy",))
-            def step_sample(params, cache, tokens, idx, keys, active,
+            def step_sample(params, cache, tokens, idx, base, ids, active,
                             temperature, greedy):
                 logits, new_cache = step(params, cache, tokens, idx)
                 cache = {k: batch_select(active, new_cache[k], cache[k],
                                          axes[k]) for k in new_cache}
                 lg = logits[:, 0].astype(jnp.float32)
-                nxt = sample(lg, keys, temperature, greedy)
+                nxt = sample(lg, base, ids, temperature, greedy)
                 return nxt.astype(jnp.int32), cache
 
             @jax.jit
@@ -358,9 +370,9 @@ class ServeEngine:
 
                 @partial(jax.jit, static_argnames=("greedy",))
                 def prefill_sample(params, cache, tokens, start, n_valid,
-                                   keys, temperature, greedy):
+                                   base, ids, temperature, greedy):
                     lg, cache = pf(params, cache, tokens, start, n_valid)
-                    nxt = sample(lg, keys, temperature, greedy)
+                    nxt = sample(lg, base, ids, temperature, greedy)
                     return nxt.astype(jnp.int32), cache
 
                 out["prefill_sample"] = prefill_sample
@@ -405,20 +417,21 @@ class ServeEngine:
         i32 = jnp.int32
         tok = jax.ShapeDtypeStruct((B, 1), i32)
         idx = jax.ShapeDtypeStruct((B,), i32)
-        keys = jax.ShapeDtypeStruct((B, 2), jnp.uint32)
+        base = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        ids = jax.ShapeDtypeStruct((B, 2), i32)
         act = jax.ShapeDtypeStruct((B,), jnp.bool_)
         temp = jax.ShapeDtypeStruct((), jnp.float32)
         lowered = {}
         if paged:
             tbl = jax.ShapeDtypeStruct((B, built["n_lp"]), i32)
             lowered["decode"] = built["decode"].lower(
-                params_sds, cache_sds, tok, idx, keys, tbl, act, temp,
+                params_sds, cache_sds, tok, idx, base, ids, tbl, act, temp,
                 greedy=self.greedy)
             lowered["zero_pages"] = built["zero_pages"].lower(
                 cache_sds, jax.ShapeDtypeStruct((built["n_lp"],), i32))
         else:
             lowered["decode"] = built["decode"].lower(
-                params_sds, cache_sds, tok, idx, keys, act, temp,
+                params_sds, cache_sds, tok, idx, base, ids, act, temp,
                 greedy=self.greedy)
         if "prefill_sample" in built:
             for C in built["buckets"]:
@@ -426,11 +439,11 @@ class ServeEngine:
                 nv = jax.ShapeDtypeStruct((B,), i32)
                 if paged:
                     lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
-                        params_sds, cache_sds, toks, idx, nv, tbl, keys,
-                        temp, greedy=self.greedy)
+                        params_sds, cache_sds, toks, idx, nv, tbl, base,
+                        ids, temp, greedy=self.greedy)
                 else:
                     lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
-                        params_sds, cache_sds, toks, idx, nv, keys,
+                        params_sds, cache_sds, toks, idx, nv, base, ids,
                         temp, greedy=self.greedy)
         return lowered
 
@@ -507,12 +520,16 @@ class ServeEngine:
             tot[0] += time.perf_counter() - ts
             tot[1] += 1
 
-        def sample_key(st, t: int) -> np.ndarray:
-            nonlocal syncs
-            with span("serve.keys", rid=st["r"].rid):
-                syncs += 1
-                return np.asarray(jax.random.fold_in(
-                    jax.random.fold_in(st["kreq"], 9), t))
+        def sample_ids(rows) -> jax.Array:
+            """[B, 2] `(rid, t)` of a launch's sampled `rows`, given as
+            (slot, t) pairs; a slot left out is not sampled and its draw
+            is discarded."""
+            ids = np.zeros((B, 2), np.int32)
+            if rows and not self.greedy:
+                with span("serve.keys"):
+                    for b, t in rows:
+                        ids[b] = (slots[b]["r"].rid, t)
+            return jnp.asarray(ids)
 
         def admit(b: int, r, need: int) -> bool:
             """Request `r` into slot `b`: its prompt drawn and sent up,
@@ -630,26 +647,26 @@ class ServeEngine:
                     ptoks = np.zeros((B, C), np.int32)
                     pstart = np.zeros(B, np.int32)
                     pnv = np.zeros(B, np.int32)
-                    pkeys = np.zeros((B, 2), np.uint32)
+                    rows = []
                     for b in pre:
                         st, c = slots[b], chunk[b]
                         ptoks[b, :c] = st["prompt"][st["pos"]:st["pos"] + c]
                         pstart[b] = st["pos"]
                         pnv[b] = c
-                        if st["pos"] + c >= st["r"].prompt_len \
-                                and not self.greedy:
-                            pkeys[b] = sample_key(st, 0)
+                        if st["pos"] + c >= st["r"].prompt_len:
+                            rows.append((b, 0))
+                    pids = sample_ids(rows)
                     if paged:
                         nxtp, cache = built["prefill_sample"](
                             self.params, cache, jnp.asarray(ptoks),
                             jnp.asarray(pstart), jnp.asarray(pnv),
-                            tables_j, jnp.asarray(pkeys),
+                            tables_j, base, pids,
                             jnp.float32(self.temperature), self.greedy)
                     else:
                         nxtp, cache = built["prefill_sample"](
                             self.params, cache, jnp.asarray(ptoks),
                             jnp.asarray(pstart), jnp.asarray(pnv),
-                            jnp.asarray(pkeys),
+                            base, pids,
                             jnp.float32(self.temperature), self.greedy)
                     with span("serve.prefill.wait"):
                         syncs += 1
@@ -667,8 +684,8 @@ class ServeEngine:
                 if dec:
                     toks = np.zeros((B, 1), np.int32)
                     idx = np.zeros(B, np.int32)
-                    keys = np.zeros((B, 2), np.uint32)
                     active = np.zeros(B, bool)
+                    rows = []
                     for b in dec:
                         st = slots[b]
                         P = st["r"].prompt_len
@@ -677,18 +694,19 @@ class ServeEngine:
                         idx[b] = st["pos"]
                         active[b] = True
                         t = st["pos"] - (P - 1)
-                        if t >= 0 and not self.greedy:
-                            keys[b] = sample_key(st, t)
+                        if t >= 0:
+                            rows.append((b, t))
+                    ids = sample_ids(rows)
                     if paged:
                         nxt, cache = built["decode"](
                             self.params, cache, jnp.asarray(toks),
-                            jnp.asarray(idx), jnp.asarray(keys), tables_j,
+                            jnp.asarray(idx), base, ids, tables_j,
                             jnp.asarray(active),
                             jnp.float32(self.temperature), self.greedy)
                     else:
                         nxt, cache = built["decode"](
                             self.params, cache, jnp.asarray(toks),
-                            jnp.asarray(idx), jnp.asarray(keys),
+                            jnp.asarray(idx), base, ids,
                             jnp.asarray(active),
                             jnp.float32(self.temperature), self.greedy)
                     with span("serve.decode.wait"):

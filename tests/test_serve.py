@@ -468,13 +468,16 @@ def test_host_syncs_and_spans_match_the_hand_count(greedy):
     """Three requests on four slots, every prompt in one chunk: cycle 0
     prefills all three (one read-back), cycles 1-3 decode the rest (one
     read-back each); each request costs a prompt draw, an uplink and a
-    downlink payload, and under sampling one key per generated token."""
+    downlink payload. Sampling keys are derived in the step programs, so
+    they cost no sync; under sampling each launch that samples a row
+    (the prefill and the three decodes) fills its ids in one
+    `serve.keys` span."""
     eng = ServeEngine(TINY, params_for(TINY), n_slots=4, chunk_size=8,
                       greedy=greedy)
     rep = eng.serve(SPAN_TRACE)
-    keys = 0 if greedy else 1 + 2 + 4
+    keys = 0 if greedy else 1 + 3
     assert rep.cycles == 4
-    assert rep.host_syncs == 3 * 3 + 4 + keys
+    assert rep.host_syncs == 3 * 3 + 4
     counts = {k: n for k, (_, n) in rep.spans.items()}
     want = {"serve.cycle": 4, "serve.admit": 3, "serve.prompt": 3,
             "serve.uplink": 3, "serve.downlink": 3,
@@ -528,3 +531,72 @@ def test_profiler_changes_no_token_or_bill(tmp_path):
                         rids.add(dict(e.stats)["rid"])
     assert seen == {k: n for k, (_, n) in traced.spans.items()}
     assert rids == {0, 1, 2}
+
+
+# ------------------------------------------------ sampling keys in-step
+def test_sample_keys_match_the_host_schedule():
+    """The step programs' `sample_keys(base, ids)` gives, bit for bit,
+    the documented schedule fold_in(fold_in(fold_in(base, rid), 9), t)
+    computed eagerly on the host, over a grid of rids and positions."""
+    from repro.serve.engine import SERVE_STREAM, sample_keys
+    base = jax.random.PRNGKey(3700000111 + SERVE_STREAM)
+    grid = [(r, t) for r in (0, 1, 2, 31, 255, 4097, 2 ** 30)
+            for t in (0, 1, 2, 57, 100, 2 ** 20)]
+    got = np.asarray(jax.jit(sample_keys)(base,
+                                          jnp.asarray(grid, jnp.int32)))
+    want = np.stack([np.asarray(jax.random.fold_in(jax.random.fold_in(
+        jax.random.fold_in(base, r), 9), t)) for r, t in grid])
+    np.testing.assert_array_equal(got, want)
+
+
+#: tokens of `_staggered_trace()` at T = 0.8 (chunk 16, pages of 8, a
+#: perfect link), served by the engine as it was when the host derived
+#: each sampling key eagerly (`fold_in`, then a read-back) and passed
+#: the keys into the step programs; on CPU, the same for every
+#: (prefill, kv) mode.
+SAMPLED_GOLDEN = {
+    "qwen1.5-0.5b-reduced":
+        [[512, 609, 907, 320, 832, 897],
+         [405, 905, 231, 721, 578, 782, 757, 319, 902],
+         [480, 550, 1015, 82], [735, 214, 1023, 386, 232],
+         [407, 796, 134, 373, 991, 827, 591, 414], [762, 246, 1002]],
+    "paper-tinylstm":
+        [[1, 1, 0, 0, 1, 1], [0, 1, 0, 1, 1, 1, 1, 0, 1], [1, 0, 1, 0],
+         [1, 1, 1, 1, 0], [1, 0, 0, 0, 1, 0, 1, 1], [1, 0, 0]],
+}
+
+
+@pytest.mark.parametrize("cfg,kv", [(QWEN, "paged"), (QWEN, "dense"),
+                                    (TINY, "dense")],
+                         ids=["qwen1.5-0.5b-reduced-paged",
+                              "qwen1.5-0.5b-reduced-dense",
+                              "paper-tinylstm-dense"])
+def test_sampled_tokens_match_the_host_key_goldens(cfg, kv):
+    """Keys derived inside the step programs sample the very tokens the
+    host-derived keys sampled (`SAMPLED_GOLDEN`, produced by running the
+    earlier engine on CPU)."""
+    eng = ServeEngine(cfg, params_for(cfg), n_slots=3, temperature=0.8,
+                      kv=kv, chunk_size=16, page_size=8)
+    rep = eng.serve(_staggered_trace())
+    assert eng.kv == kv
+    name = "paper-tinylstm" if cfg is TINY else "qwen1.5-0.5b-reduced"
+    assert [list(r.tokens) for r in rep.results] == SAMPLED_GOLDEN[name]
+
+
+@pytest.mark.parametrize("kv", ["paged", "dense"])
+def test_new_trace_seed_does_not_recompile(kv):
+    """The trace's sampling base key is an argument of the step
+    programs, not a constant: serving a second trace with another seed
+    through one engine compiles nothing more (one decode program, the
+    same prefill buckets) and samples other tokens."""
+    eng = ServeEngine(QWEN, params_for(QWEN), n_slots=3, kv=kv,
+                      chunk_size=8, page_size=8)
+    reqs = tuple(Request(rid, 0, 3 + 2 * rid, 4) for rid in range(3))
+    a = eng.serve(RequestTrace(21, reqs))
+    built = eng._compiled[max(8, RequestTrace(21, reqs).max_seq_len())]
+    sizes = {k: built[k]._cache_size()
+             for k in ("decode", "prefill_sample")}
+    b = eng.serve(RequestTrace(22, reqs))
+    assert sizes["decode"] == 1
+    assert {k: built[k]._cache_size() for k in sizes} == sizes
+    assert [r.tokens for r in a.results] != [r.tokens for r in b.results]
