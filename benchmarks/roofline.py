@@ -36,14 +36,19 @@ def param_count(cfg, active_only: bool = False) -> float:
     """Analytic parameter count (embedding + per-layer) for MODEL_FLOPS."""
     d, v = cfg.d_model, cfg.vocab_size
     hd = cfg.hd
-    emb = v * d
-    attn = d * (cfg.n_heads * hd) + 2 * d * (cfg.n_kv_heads * hd) \
-        + (cfg.n_heads * hd) * d
+    emb = v * d * (1 if cfg.tie_embed else 2)         # + untied head
+    if cfg.is_mla:
+        h, r, dn, dr = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim, \
+            cfg.qk_rope_dim
+        attn = d * h * (dn + dr) + d * (r + dr) \
+            + r * h * (dn + cfg.v_head_dim) + h * cfg.v_head_dim * d
+    else:
+        attn = d * (cfg.n_heads * hd) + 2 * d * (cfg.n_kv_heads * hd) \
+            + (cfg.n_heads * hd) * d
     if cfg.is_moe:
         n_e = cfg.top_k if active_only else cfg.n_experts
         mlp = 3 * d * cfg.expert_ff * n_e + d * cfg.n_experts  # + router
-        if cfg.shared_expert:
-            mlp += 3 * d * cfg.expert_ff
+        mlp += 3 * d * cfg.expert_ff * cfg.shared_experts
     elif cfg.family == "ssm":
         # xlstm mLSTM: qkv + gates + out
         di = cfg.ssm_expand * d
@@ -51,7 +56,8 @@ def param_count(cfg, active_only: bool = False) -> float:
     else:
         mlp = 3 * d * cfg.d_ff if cfg.d_ff else 4 * d * d
     n_layers = cfg.n_layers + cfg.enc_layers
-    return float(emb + n_layers * (attn + mlp))
+    dense = cfg.first_dense * (3 * d * cfg.d_ff - mlp)  # leading dense FFNs
+    return float(emb + n_layers * (attn + mlp) + dense)
 
 
 def model_flops(cfg, shape_cfg) -> float:

@@ -11,6 +11,22 @@ _REGISTRY: dict[str, "ArchConfig"] = {}
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN RoPE scaling (arXiv 2309.00071), as DeepSeek-V2 configures it:
+    frequencies ramp from interpolated (`factor`) to original between
+    the correction dims of `beta_fast` and `beta_slow` rotations over
+    `original_max_len` positions; the attention scale gains
+    `mscale(mscale_all_dim)**2` and cos/sin the ratio
+    `mscale(mscale) / mscale(mscale_all_dim)`."""
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str                  # dense | moe | ssm | hybrid | vlm | audio | tiny
@@ -27,8 +43,17 @@ class ArchConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
-    shared_expert: bool = False
+    shared_experts: int = 0      # shared SwiGLU of width shared_experts * moe_d_ff
     moe_chunk: int = 0           # token-chunked dispatch (0 = auto 16k)
+    norm_topk_prob: bool = True  # renormalise the top-k gates (Qwen/Mixtral)
+    # the experts this chip holds, [lo, hi); () = all of them
+    experts_held: tuple = ()
+    first_dense: int = 0         # leading dense (non-MoE) layers, of d_ff
+    # latent attention (MLA, DeepSeek-V2): kv_lora_rank > 0 switches it on
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # SSM / hybrid
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -39,7 +64,10 @@ class ArchConfig:
     qkv_bias: bool = False
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0   # chatglm applies RoPE to half the head dim
+    rope_scaling: Optional[RopeScaling] = None
     norm: str = "rmsnorm"        # rmsnorm | layernorm
+    norm_eps: float = 1e-5
+    tie_embed: bool = True       # LM head = the embedding table
     parallel_block: bool = False # command-r style parallel attn+mlp
     # long context
     sliding_window: int = 0      # 0 = full attention (train); decode long ctx
@@ -71,6 +99,20 @@ class ArchConfig:
     def expert_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
+    @property
+    def held(self) -> tuple:
+        """[lo, hi) of the experts this chip holds."""
+        return tuple(self.experts_held) or (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held
+        return hi - lo
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: <=2 layers, d_model<=512, <=4 experts."""
         d = min(self.d_model, 256)
@@ -91,6 +133,11 @@ class ArchConfig:
             n_frontend_tokens=min(self.n_frontend_tokens, 16) if self.n_frontend_tokens else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=min(self.ssm_head_dim, 32),
+            experts_held=(),
+            kv_lora_rank=min(self.kv_lora_rank, 64),
+            qk_nope_dim=min(self.qk_nope_dim, 32),
+            qk_rope_dim=min(self.qk_rope_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             attn_chunk=64,
             dtype=jnp.float32,
         )

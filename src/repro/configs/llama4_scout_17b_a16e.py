@@ -16,6 +16,6 @@ CONFIG = register(ArchConfig(
     vocab_size=202048,
     n_experts=16,
     top_k=1,
-    shared_expert=True,
+    shared_experts=1,
     rope_theta=500000.0,
 ))

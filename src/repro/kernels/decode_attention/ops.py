@@ -62,3 +62,20 @@ def gqa_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                  window=window, scale=1.0 / (hd ** 0.5),
                                  interpret=interpret)
     return out[:, :, :G, :hd].reshape(B, H, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def mla_decode_paged(q: jax.Array, pool: jax.Array, tables: jax.Array,
+                     length: jax.Array, scale: float,
+                     interpret: bool | None = None) -> jax.Array:
+    """Absorbed latent attention (MLA) over the paged latent pool: one
+    key/value head whose rows are [c | k_pe]; q [B, H, D] holds each
+    head's absorbed query [q_nope W_UK | q_pe]; pool [n_pages, 1, page,
+    D]; the value is the same pool. Multi-query, so the H heads ride
+    the kernel's group axis (H a multiple of 8) and D its lanes (a
+    multiple of 64 that the block spans whole): no padding, no copy of
+    the pool. Returns [B, H, D] fp32; the caller keeps the latent
+    columns."""
+    out = paged_decode_attention(q[:, None], pool, pool, tables, length,
+                                 scale=scale, interpret=interpret)
+    return out[:, 0]

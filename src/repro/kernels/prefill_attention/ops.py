@@ -73,3 +73,19 @@ def gqa_prefill_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                   interpret=interpret)
     out = out.reshape(B, Hkv, C, Gp, hd + dp)[:, :, :, :G, :hd]
     return out.transpose(0, 2, 1, 3, 4).reshape(B, C, H, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def mla_prefill_paged(q: jax.Array, pool: jax.Array, tables: jax.Array,
+                      start: jax.Array, scale: float,
+                      interpret: bool | None = None) -> jax.Array:
+    """Absorbed latent attention (MLA) of prompt chunks over the paged
+    latent pool (see `mla_decode_paged`): q [B, C, H, D]; pool
+    [n_pages, 1, page, D] already holding the chunk's own rows; `start`
+    [B]. The C x H query rows share the one key head. Returns
+    [B, C, H, D] fp32."""
+    B, C, H, D = q.shape
+    out = paged_prefill_attention(q.reshape(B, 1, C * H, D), pool, pool,
+                                  tables, start, g=H, scale=scale,
+                                  interpret=interpret)
+    return out.reshape(B, C, H, D)

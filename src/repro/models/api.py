@@ -4,6 +4,7 @@ Every family exposes:
     specs(cfg)                         -> param Spec tree
     forward(params, batch, cfg, window)-> (logits, aux)
     cache_shapes(cfg, B, S) / init_cache / decode_step   (decoder families)
+    prefill_step, paged_cache_shapes   (where the family has them)
 """
 from __future__ import annotations
 
@@ -27,18 +28,21 @@ class ModelApi:
     # vectorized whole-chunk prefill (serving admission); families
     # without one fall back to runtime/serve_step.py's exact scan
     prefill_step: Optional[Callable] = None
+    # the shared-pool paged cache (cfg, n_pages, page_size) -> {name:
+    # (shape, axes, dtype)}; None where the cache cannot be paged
+    # (recurrent O(1) state has nothing to page)
+    paged_cache_shapes: Optional[Callable] = None
 
+
+_TRANSFORMER = ModelApi(transformer.model_specs, transformer.forward,
+                        transformer.init_cache_shapes, transformer.init_cache,
+                        transformer.decode_step, transformer.prefill_step,
+                        transformer.paged_cache_shapes)
 
 _FAMILIES = {
-    "dense": ModelApi(transformer.model_specs, transformer.forward,
-                      transformer.init_cache_shapes, transformer.init_cache,
-                      transformer.decode_step, transformer.prefill_step),
-    "moe": ModelApi(transformer.model_specs, transformer.forward,
-                    transformer.init_cache_shapes, transformer.init_cache,
-                    transformer.decode_step, transformer.prefill_step),
-    "vlm": ModelApi(transformer.model_specs, transformer.forward,
-                    transformer.init_cache_shapes, transformer.init_cache,
-                    transformer.decode_step, transformer.prefill_step),
+    "dense": _TRANSFORMER,
+    "moe": _TRANSFORMER,
+    "vlm": _TRANSFORMER,
     "ssm": ModelApi(xlstm.model_specs, xlstm.forward,
                     xlstm.cache_shapes, xlstm.init_cache, xlstm.decode_step),
     "hybrid": ModelApi(hybrid.model_specs, hybrid.forward,
@@ -55,6 +59,12 @@ _FAMILIES = {
 
 def get_model(cfg) -> ModelApi:
     return _FAMILIES[cfg.family]
+
+
+def paged_families() -> tuple:
+    """Families whose cache can live in the shared page pool."""
+    return tuple(f for f, m in _FAMILIES.items()
+                 if m.paged_cache_shapes is not None)
 
 
 def param_specs(cfg):

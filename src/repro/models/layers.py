@@ -73,11 +73,46 @@ def linear(p: dict, x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------- RoPE
-def rope_angles(positions: jax.Array, dim: int, theta: float) -> tuple:
-    """positions [...,S] -> (sin, cos) each [...,S,dim/2] fp32."""
-    freqs = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1
+    (1 when the context is not stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, scaling=None) -> jax.Array:
+    """[dim/2] fp32 inverse frequencies; with a `RopeScaling` (YaRN) they
+    ramp linearly, over the pair indices between the correction dims of
+    `beta_fast` and `beta_slow` rotations, from the original frequency
+    to the frequency over `factor`."""
+    base = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if scaling is None:
+        return 1.0 / base
+
+    def corr_dim(rotations):
+        return (dim * math.log(scaling.original_max_len
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(scaling.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    extra = 1.0 - ramp            # 1: keep the original frequency
+    return (1.0 / (scaling.factor * base)) * ramp + (1.0 / base) * extra
+
+
+def rope_angles(positions: jax.Array, dim: int, theta: float,
+                scaling=None) -> tuple:
+    """positions [...,S] -> (sin, cos) each [...,S,dim/2] fp32, scaled
+    by YaRN's cos/sin factor under a `RopeScaling`."""
+    freqs = rope_inv_freq(dim, theta, scaling)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.sin(ang), jnp.cos(ang)
+    m = 1.0 if scaling is None else (
+        yarn_mscale(scaling.factor, scaling.mscale)
+        / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+    return jnp.sin(ang) * m, jnp.cos(ang) * m
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array,
@@ -126,12 +161,14 @@ def _qkv(p, x, cfg, positions):
 
 
 def chunked_attention(q, k, v, cfg, causal: bool = True,
-                      window: int = 0, kv_offset: int = 0) -> jax.Array:
+                      window: int = 0, kv_offset: int = 0,
+                      scale: float | None = None) -> jax.Array:
     """Memory-bounded multi-query-block attention with online softmax.
 
     q [B,Sq,H,hd], k/v [B,Skv,Hkv,hd]. Scans query chunks (outer) and key
     chunks (inner) keeping running (max, sum, acc) — an XLA-level flash
-    attention; scores never materialize beyond [B,H,cq,ck].
+    attention; scores never materialize beyond [B,H,cq,ck]. `scale`
+    multiplies the scores (default 1/sqrt(hd)).
     """
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
@@ -151,7 +188,8 @@ def chunked_attention(q, k, v, cfg, causal: bool = True,
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
         Skv += pk
     nq, nk = Sq // cq, Skv // ck
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     kh = k.reshape(B, nk, ck, k.shape[2], hd)
     vh = v.reshape(B, nk, ck, v.shape[2], hd)
@@ -198,19 +236,21 @@ def chunked_attention(q, k, v, cfg, causal: bool = True,
 
 
 def decode_attention_jnp(q, k_cache, v_cache, length, window: int = 0,
-                         offset=0):
+                         offset=0, scale: float | None = None):
     """One-token GQA attention against a cache. q [B,H,hd],
     caches [B,Hkv,S,hd], `length` = count of valid positions — a global
     scalar, or a per-row [B] vector (continuous-batching serving, where
     every slot sits at its own depth). `offset` = global position of
     cache column 0 (used when the caller pre-slices a window out of a
-    longer cache — §Perf-3)."""
+    longer cache — §Perf-3). `scale` multiplies the scores (default
+    1/sqrt(hd))."""
     B, Hkv, S, hd = k_cache.shape
     H = q.shape[1]
     G = H // Hkv
     qf = q.reshape(B, Hkv, G, hd)
     logits = jnp.einsum("bhgd,bhsd->bhgs", qf, k_cache.astype(qf.dtype))
-    logits = logits.astype(jnp.float32) / math.sqrt(hd)
+    logits = (logits.astype(jnp.float32) / math.sqrt(hd) if scale is None
+              else logits.astype(jnp.float32) * scale)
     pos = offset + jnp.arange(S)
     lth = jnp.asarray(length).reshape(-1, 1)          # [1,1] or [B,1]
     valid = pos[None, :] < lth
@@ -219,23 +259,26 @@ def decode_attention_jnp(q, k_cache, v_cache, length, window: int = 0,
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgs,bhsd->bhgd", w.astype(v_cache.dtype), v_cache)
-    return out.reshape(B, H, hd)
+    return out.reshape(B, H, -1)
 
 
-def prefill_attention_jnp(q, k_cache, v_cache, start, window: int = 0):
+def prefill_attention_jnp(q, k_cache, v_cache, start, window: int = 0,
+                          scale: float | None = None):
     """Chunk GQA attention against a cache. q [B,C,H,hd] — a C-token
     prompt chunk per row; caches [B,Hkv,S,hd] already holding the
     chunk's own K/V columns; `start` = global position of chunk token 0,
     a scalar or per-row [B] vector (staggered admissions). Query c of
     row b attends cache positions <= start[b] + c, optionally
     sliding-window limited — the multi-query generalisation of
-    `decode_attention_jnp` (C=1, start=length-1 coincide bitwise)."""
+    `decode_attention_jnp` (C=1, start=length-1 coincide bitwise).
+    `scale` multiplies the scores (default 1/sqrt(hd))."""
     B, Hkv, S, hd = k_cache.shape
     C, H = q.shape[1], q.shape[2]
     G = H // Hkv
     qf = q.reshape(B, C, Hkv, G, hd)
     logits = jnp.einsum("bchgd,bhsd->bchgs", qf, k_cache.astype(qf.dtype))
-    logits = logits.astype(jnp.float32) / math.sqrt(hd)
+    logits = (logits.astype(jnp.float32) / math.sqrt(hd) if scale is None
+              else logits.astype(jnp.float32) * scale)
     qpos = jnp.asarray(start).reshape(-1, 1) + jnp.arange(C)[None]  # [B|1,C]
     pos = jnp.arange(S)
     valid = pos[None, None, :] <= qpos[..., None]                   # [B,C,S]
@@ -244,7 +287,7 @@ def prefill_attention_jnp(q, k_cache, v_cache, start, window: int = 0):
     logits = jnp.where(valid[:, :, None, None, :], logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bchgs,bhsd->bchgd", w.astype(v_cache.dtype), v_cache)
-    return out.reshape(B, C, H, hd)
+    return out.reshape(B, C, H, -1)
 
 
 # ---------------------------------------------------------------- paged KV

@@ -17,6 +17,10 @@ Three dispatch strategies, picked automatically:
 
 Returns (y, aux) where aux carries the Switch-style load-balance loss and
 router stats.
+
+Serving (`decode_step`/`prefill_step`) takes a fourth path, `moe_held`:
+dropless, over the experts this chip holds (`cfg.experts_held`), as one
+grouped matmul per weight (`kernels/moe_gmm`) with no capacity.
 """
 from __future__ import annotations
 
@@ -26,22 +30,94 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.moe_gmm.ops import moe_gmm
+from repro.kernels.moe_gmm.ref import gmm_ref
 from repro.nn import Spec, constrain
 from repro.nn.sharding import current_mesh
 from repro.models.layers import linear_specs, linear, mlp_specs, apply_mlp
 
 
 def moe_specs(cfg) -> dict:
-    d, ff, E = cfg.d_model, cfg.expert_ff, cfg.n_experts
+    d, ff, E, Eh = cfg.d_model, cfg.expert_ff, cfg.n_experts, cfg.n_held
     s = {
         "router": linear_specs(d, E, ("embed", None)),
-        "wi": Spec((E, d, ff), ("experts", "embed", "expert_mlp"), init="fan_in"),
-        "wg": Spec((E, d, ff), ("experts", "embed", "expert_mlp"), init="fan_in"),
-        "wo": Spec((E, ff, d), ("experts", "expert_mlp", "embed"), init="fan_in"),
+        "wi": Spec((Eh, d, ff), ("experts", "embed", "expert_mlp"), init="fan_in"),
+        "wg": Spec((Eh, d, ff), ("experts", "embed", "expert_mlp"), init="fan_in"),
+        "wo": Spec((Eh, ff, d), ("experts", "expert_mlp", "embed"), init="fan_in"),
     }
-    if cfg.shared_expert:
-        s["shared"] = mlp_specs(cfg, ff)
+    if cfg.shared_experts:
+        s["shared"] = mlp_specs(cfg, ff * cfg.shared_experts)
     return s
+
+
+def route(p: dict, xf: jax.Array, cfg) -> tuple:
+    """Softmax over all E experts, greedy top-k: (gates [T, k] fp32,
+    expert ids [T, k], probs [T, E]). Gates are renormalised over the
+    k when `cfg.norm_topk_prob`."""
+    logits = linear(p["router"], xf.astype(jnp.float32))          # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, cfg.top_k)                   # [T, k]
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    return gate, idx, probs
+
+
+#: the counters `moe_held` returns: rows the held experts computed, the
+#: busiest held expert's rows, held experts with at least one row
+N_STATS = 3
+
+
+def moe_held(p: dict, x: jax.Array, cfg, layer, valid=None,
+             kernel: bool = False) -> tuple:
+    """Dropless MoE over the held experts [lo, hi) = `cfg.held`: route
+    every token over all E experts (`route`), keep the assignments that
+    fall in the held window (and, with `valid` [B, S] bool, only valid
+    tokens'), sort them by expert and run the SwiGLU as three grouped
+    matmuls over the held experts' weights, combine with the gates and
+    add the shared experts once. `p["wi"/"wg"/"wo"]` hold only the held
+    experts, stacked over the layers, [L, n_held, ...]; the kernel reads
+    layer `layer` of them in place (a slice would be a copy). No
+    capacity: a token is never dropped. What the absent experts would
+    add is left out, as on a chip of an expert-parallel deployment.
+    `kernel=True` runs the Pallas grouped matmul, False its jnp
+    reference. Returns (y [B, S, d], counters int32 [N_STATS])."""
+    B, S, d = x.shape
+    T, k = B * S, cfg.top_k
+    lo, hi = cfg.held
+    Eh = hi - lo
+    M = T * min(k, Eh)            # top-k ids are distinct: a bound on rows
+    xf = x.reshape(T, d)
+    with jax.named_scope("moe.route"):
+        gate, idx, _ = route(p, xf, cfg)
+        e = idx - lo
+        keep = (e >= 0) & (e < Eh)
+        if valid is not None:
+            keep &= valid.reshape(T, 1)
+        key = jnp.where(keep, e, Eh).reshape(T * k)
+        order = jnp.argsort(key, stable=True)[:M]
+        sizes = jnp.bincount(key, length=Eh + 1)[:Eh].astype(jnp.int32)
+        n = sizes.sum()
+        rows = order // k
+        g = gate.reshape(T * k)[order]
+    if kernel:
+        def gmm(a, w):
+            return moe_gmm(a, w, sizes, layer)
+    else:
+        def gmm(a, w):
+            return gmm_ref(a, w[layer].astype(x.dtype), sizes)
+    with jax.named_scope("moe.experts"):
+        xs = jnp.take(xf, rows, axis=0)                           # [M, d]
+        h = jax.nn.silu(gmm(xs, p["wg"])) * gmm(xs, p["wi"])
+        out = gmm(h.astype(x.dtype), p["wo"])                     # [M, d]
+        live = (jnp.arange(M) < n)[:, None]
+        out = jnp.where(live, out * g[:, None], 0.0)
+        y = jnp.zeros((T, d), jnp.float32).at[rows].add(out)
+    y = y.reshape(B, S, d).astype(x.dtype)
+    if cfg.shared_experts:
+        with jax.named_scope("moe.shared"):
+            y = y + apply_mlp(p["shared"], x)
+    stats = jnp.stack([n, sizes.max(), (sizes > 0).sum()]).astype(jnp.int32)
+    return y, stats
 
 
 def capacity(n_tokens: int, cfg) -> int:
@@ -94,7 +170,7 @@ def _moe_chunked(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, dict]:
     _, (ys, lb, dropped) = jax.lax.scan(body, None,
                                         xf.reshape(T // chunk, chunk, d))
     y = ys.reshape(B, S, d)
-    if cfg.shared_expert:
+    if cfg.shared_experts:
         y = y + apply_mlp(p["shared"], x)
     return constrain(y, "batch", "seq", "act_embed"), {
         "lb_loss": jnp.mean(lb), "dropped_frac": jnp.mean(dropped)}
@@ -103,7 +179,7 @@ def _moe_chunked(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, dict]:
 def _single(p, xf, cfg, B, S, d):
     y, aux = _moe_core(p, xf, cfg)
     y = y.reshape(B, S, d)
-    if cfg.shared_expert:
+    if cfg.shared_experts:
         y = y + apply_mlp(p["shared"], xf.reshape(B, S, d))
     return constrain(y, "batch", "seq", "act_embed"), aux
 
@@ -116,13 +192,12 @@ def _moe_core(p: dict, xf: jax.Array, cfg, e_lo=0,
     weight slice plus its window and psum the partial outputs."""
     T, d = xf.shape
     E, k = cfg.n_experts, cfg.top_k
-    El = n_local or E
+    if not n_local:                 # the experts this chip holds
+        e_lo, n_local = cfg.held[0], cfg.n_held
+    El = n_local
     C = capacity(T, cfg)
 
-    logits = linear(p["router"], xf.astype(jnp.float32))          # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, k)                           # [T, k]
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)  # renorm (Qwen/Mixtral)
+    gate, idx, probs = route(p, xf, cfg)                          # [T, k]
 
     # position of each (token, slot) within its expert, in flat arrival order
     eflat = idx.reshape(T * k) - e_lo                             # window-rel
@@ -197,7 +272,7 @@ def _moe_ep(p: dict, x: jax.Array, cfg, mesh) -> tuple[jax.Array, dict]:
         out_specs=(P(bax if bax else None, None, None), P(), P()),
         check_vma=False,
     )(p["router"]["w"], p["wi"], p["wg"], p["wo"], x)
-    if cfg.shared_expert:
+    if cfg.shared_experts:
         y = y + apply_mlp(p["shared"], x)
     return constrain(y, "batch", "seq", "act_embed"), {
         "lb_loss": lb, "dropped_frac": dr}

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 
 from repro.kernels import resolve_interpret
 from repro.models import api as M
-from repro.models import transformer
 from repro.runtime.train_step import window_for
 
 
@@ -57,35 +56,46 @@ def cache_specs(cfg, shape_cfg):
 
 
 # ------------------------------------------------------------- paged KV
+def _paged_model(cfg):
+    """The family's ModelApi, which must page its cache."""
+    model = M.get_model(cfg)
+    if model.paged_cache_shapes is None:
+        raise ValueError(f"paged KV unsupported for family {cfg.family!r}")
+    return model
+
+
 def paged_cache_specs(cfg, n_pages: int, page_size: int):
     """(ShapeDtypeStruct tree, logical-axes tree) for the shared-pool
-    paged cache (attention families only — recurrent O(1) caches have
-    nothing to page)."""
-    if cfg.family not in ("dense", "moe", "vlm"):
-        raise ValueError(f"paged KV unsupported for family {cfg.family!r}")
-    shapes = transformer.paged_cache_shapes(cfg, n_pages, page_size)
+    paged cache (families with `ModelApi.paged_cache_shapes` only —
+    recurrent O(1) caches have nothing to page)."""
+    shapes = _paged_model(cfg).paged_cache_shapes(cfg, n_pages, page_size)
     sds = {k: jax.ShapeDtypeStruct(sh, dt) for k, (sh, ax, dt) in shapes.items()}
     axes = {k: ax for k, (sh, ax, dt) in shapes.items()}
     return sds, axes
 
 
+def init_paged_cache(cfg, n_pages: int, page_size: int) -> dict:
+    """The zeroed shared page pool of the family's paged cache."""
+    sds, _ = paged_cache_specs(cfg, n_pages, page_size)
+    return {k: jnp.zeros(s.shape, s.dtype) for k, s in sds.items()}
+
+
 def make_paged_decode_step(cfg, shape_cfg, page_size: int,
-                           kernel: bool | None = None):
+                           kernel: bool | None = None, stats: bool = False):
     """Decode against the shared page pool. `tables` [B, n_lp] per-slot
     page tables; `active` [B] bool — inactive rows' pool writes are
     DROPPED in-graph (the pool has no batch axis for the engine to
-    select over)."""
-    if cfg.family not in ("dense", "moe", "vlm"):
-        raise ValueError(f"paged KV unsupported for family {cfg.family!r}")
-    model = M.get_model(cfg)
+    select over). With `stats` the step returns (logits, cache,
+    counters): the held-expert counters of an MoE model
+    (`transformer.decode_step`), [0] otherwise."""
+    model = _paged_model(cfg)
     window = window_for(cfg, shape_cfg)
 
     def decode_step(params, cache, token, index, tables, active):
         pages = {"tables": tables, "page_size": page_size, "active": active,
                  "kernel": kernel}
-        logits, cache = model.decode_step(params, cache, token, index, cfg,
-                                          window, pages=pages)
-        return logits, cache
+        return model.decode_step(params, cache, token, index, cfg, window,
+                                 pages=pages, stats=stats)
 
     return decode_step
 
@@ -156,13 +166,13 @@ def make_prefill_step(cfg, shape_cfg, impl: str = "auto"):
 
 
 def make_paged_prefill_step(cfg, shape_cfg, page_size: int,
-                            impl: str = "auto", kernel: bool | None = None):
+                            impl: str = "auto", kernel: bool | None = None,
+                            stats: bool = False):
     """Chunked prefill over the shared page pool; the step additionally
     takes `tables` [B, n_lp]. Row masking happens at the pool write
-    (dropped scatters), not by batch select."""
-    if cfg.family not in ("dense", "moe", "vlm"):
-        raise ValueError(f"paged KV unsupported for family {cfg.family!r}")
-    model = M.get_model(cfg)
+    (dropped scatters), not by batch select. With `stats` it returns the
+    counters too, as the paged decode step."""
+    model = _paged_model(cfg)
     window = window_for(cfg, shape_cfg)
     impl = _resolve_prefill_impl(model, impl)
     V = _logit_width(cfg)
@@ -172,7 +182,7 @@ def make_paged_prefill_step(cfg, shape_cfg, page_size: int,
             pages = {"tables": tables, "page_size": page_size,
                      "active": None, "kernel": kernel}
             return model.prefill_step(params, cache, tokens, start, n_valid,
-                                      cfg, window, pages=pages)
+                                      cfg, window, pages=pages, stats=stats)
         return prefill_fused
 
     def prefill_scan(params, cache, tokens, start, n_valid, tables):
@@ -183,15 +193,16 @@ def make_paged_prefill_step(cfg, shape_cfg, page_size: int,
             tok = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=1)
             pages = {"tables": tables, "page_size": page_size,
                      "active": i < n_valid, "kernel": kernel}
-            logits, cache = model.decode_step(params, cache, tok, start + i,
-                                              cfg, window, pages=pages)
+            logits, cache, st = model.decode_step(
+                params, cache, tok, start + i, cfg, window, pages=pages,
+                stats=True)
             lg = jnp.where((i == n_valid - 1)[:, None],
                            logits[:, 0].astype(jnp.float32), lg)
-            return (cache, lg), None
+            return (cache, lg), st
 
-        (cache, lg), _ = jax.lax.scan(
+        (cache, lg), st = jax.lax.scan(
             body, (cache, jnp.zeros((B, V), jnp.float32)),
             jnp.arange(C, dtype=jnp.int32))
-        return lg, cache
+        return (lg, cache, st.sum(0)) if stats else (lg, cache)
 
     return prefill_scan

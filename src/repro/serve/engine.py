@@ -70,7 +70,10 @@ prompt draw, and `serve.uplink`), `serve.keys` (filling one launch's
 profiler records, and their host seconds and counts add up in
 `ServeReport.spans` either way, beside `ServeReport.host_syncs`, the
 blocking device-to-host reads the engine makes (prompt draws, delivered
-payloads, step tokens).
+payloads, step tokens). A paged MoE model's steps append their
+held-expert counters to the sampled tokens, so they ride the same read;
+the engine sums them into `ServeReport.expert_rows`, `expert_rows_max`
+and `expert_groups`.
 
 RNG streams (all under `PRNGKey(trace.seed + 13)`, disjoint from every
 training stream — docs/ACCOUNTING.md §RNG): per request rid,
@@ -95,8 +98,8 @@ import numpy as np
 
 from repro.configs.base import ShapeConfig
 from repro.models import api as M
-from repro.models import transformer as _tfm
-from repro.runtime.serve_step import (make_decode_step,
+from repro.models.moe import N_STATS
+from repro.runtime.serve_step import (init_paged_cache, make_decode_step,
                                       make_paged_decode_step,
                                       make_paged_prefill_step,
                                       make_prefill_step)
@@ -108,7 +111,7 @@ from repro.serve.trace import RequestTrace
 #: families whose decode path accepts a per-slot [B] index vector
 SLOT_FAMILIES = ("dense", "moe", "vlm", "tiny")
 #: families whose KV cache can live in the shared page pool
-PAGED_FAMILIES = ("dense", "moe", "vlm")
+PAGED_FAMILIES = M.paged_families()
 #: the serving RNG stream offset (docs/ACCOUNTING.md §RNG)
 SERVE_STREAM = 13
 
@@ -159,6 +162,12 @@ class ServeReport:
     host_syncs: int = 0
     #: span name -> (host seconds, count) of each `serve.*` span
     spans: dict = dataclasses.field(default_factory=dict)
+    #: paged MoE steps' held-expert counters, summed over steps and
+    #: layers: rows the held experts computed, the busiest held
+    #: expert's rows (per step and layer), held experts that had a row
+    expert_rows: int = 0
+    expert_rows_max: int = 0
+    expert_groups: int = 0
 
     @property
     def generated_tokens(self) -> int:
@@ -295,20 +304,28 @@ class ServeEngine:
             return jax.vmap(jax.random.categorical)(
                 sample_keys(base, ids), lg / jnp.maximum(temperature, 1e-6))
 
+        def with_counters(nxt, st):
+            """The sampled tokens [B], then the step's counters (none
+            for a model without held experts): one int32 array, so the
+            counters ride the tokens' read-back."""
+            nxt = nxt.astype(jnp.int32)
+            return jnp.concatenate([nxt, st]) if st.shape[0] else nxt
+
         if paged:
             n_lp = -(-S // self.page_size)
             n_pages = self.page_budget or B * n_lp
             out["n_lp"], out["n_pages"] = n_lp, int(n_pages)
-            pstep = make_paged_decode_step(cfg, sc, self.page_size)
+            pstep = make_paged_decode_step(cfg, sc, self.page_size,
+                                           stats=True)
 
             @partial(jax.jit, static_argnames=("greedy",))
             def step_sample(params, cache, tokens, idx, base, ids, tables,
                             active, temperature, greedy):
-                logits, cache = pstep(params, cache, tokens, idx, tables,
-                                      active)
+                logits, cache, st = pstep(params, cache, tokens, idx, tables,
+                                          active)
                 lg = logits[:, 0].astype(jnp.float32)
                 nxt = sample(lg, base, ids, temperature, greedy)
-                return nxt.astype(jnp.int32), cache
+                return with_counters(nxt, st), cache
 
             @jax.jit
             def zero_pages(cache, pids):
@@ -320,15 +337,16 @@ class ServeEngine:
             out["zero_pages"] = zero_pages
 
             if self.prefill == "chunked":
-                pf = make_paged_prefill_step(cfg, sc, self.page_size)
+                pf = make_paged_prefill_step(cfg, sc, self.page_size,
+                                             stats=True)
 
                 @partial(jax.jit, static_argnames=("greedy",))
                 def prefill_sample(params, cache, tokens, start, n_valid,
                                    tables, base, ids, temperature, greedy):
-                    lg, cache = pf(params, cache, tokens, start, n_valid,
-                                   tables)
+                    lg, cache, st = pf(params, cache, tokens, start, n_valid,
+                                       tables)
                     nxt = sample(lg, base, ids, temperature, greedy)
-                    return nxt.astype(jnp.int32), cache
+                    return with_counters(nxt, st), cache
 
                 out["prefill_sample"] = prefill_sample
         else:
@@ -409,8 +427,8 @@ class ServeEngine:
             self.params)
         if paged:
             cache_sds = jax.eval_shape(
-                lambda: _tfm.init_paged_cache(cfg, built["n_pages"],
-                                              self.page_size))
+                lambda: init_paged_cache(cfg, built["n_pages"],
+                                         self.page_size))
         else:
             cache_sds = jax.eval_shape(
                 lambda: self._model.init_cache(cfg, B, S))
@@ -497,7 +515,7 @@ class ServeEngine:
         if paged:
             n_lp, n_pages = built["n_lp"], built["n_pages"]
             pool = PagePool(n_pages)
-            cache = _tfm.init_paged_cache(cfg, n_pages, self.page_size)
+            cache = init_paged_cache(cfg, n_pages, self.page_size)
             tables = np.zeros((B, n_lp), np.int32)
         else:
             pool = None
@@ -505,6 +523,7 @@ class ServeEngine:
             tables = None
         qi, cycle = 0, 0
         syncs = 0
+        counters = np.zeros(N_STATS, np.int64)
         spans = {}
         t0 = time.perf_counter()
 
@@ -671,6 +690,7 @@ class ServeEngine:
                     with span("serve.prefill.wait"):
                         syncs += 1
                         nxtp = np.asarray(nxtp)
+                    counters[:len(nxtp) - B] += nxtp[B:]
                     for b in pre:
                         st = slots[b]
                         st["pos"] += chunk[b]
@@ -712,6 +732,7 @@ class ServeEngine:
                     with span("serve.decode.wait"):
                         syncs += 1
                         nxt = np.asarray(nxt)
+                    counters[:len(nxt) - B] += nxt[B:]
                     for b in dec:
                         st = slots[b]
                         if st is None:
@@ -731,4 +752,7 @@ class ServeEngine:
                            n_pages=built.get("n_pages", 0) if paged else 0,
                            peak_pages=pool.peak_pages if paged else 0,
                            host_syncs=syncs,
-                           spans={k: tuple(v) for k, v in spans.items()})
+                           spans={k: tuple(v) for k, v in spans.items()},
+                           expert_rows=int(counters[0]),
+                           expert_rows_max=int(counters[1]),
+                           expert_groups=int(counters[2]))

@@ -109,3 +109,40 @@ def test_packed_wire_kernels_compile(one_chip, rows, fused_mean):
     else:
         _compile(lambda b, rd, s, p: packed_wire_2d(
             b, rd, s, p, 8, interpret=False), shapes, one_chip)
+
+
+# deepseek-v2-lite serving widths: 16 heads over one latent key head of
+# 512 + 64, 16-token pages, the cell's 64 slots of 9 pages, a 64-token
+# chunk; the held experts' grouped matmuls at d 2048 and width 1408 over
+# 8 experts, for a decode step's 64 x 6 rows and a full chunk's
+MLA_B, MLA_H, MLA_D, MLA_NLP = 64, 16, 576, 9
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_mla_paged_kernels_compile(one_chip, kind):
+    from repro.kernels.decode_attention.ops import mla_decode_paged
+    from repro.kernels.prefill_attention.ops import mla_prefill_paged
+    pool = ((MLA_B * MLA_NLP, 1, PAGE, MLA_D), BF16)
+    tables = ((MLA_B, MLA_NLP), jnp.int32)
+    pos = ((MLA_B,), jnp.int32)
+    if kind == "decode":
+        c = _compile(lambda q, p, t, n: mla_decode_paged(
+            q, p, t, n, scale=0.1147, interpret=False),
+            [((MLA_B, MLA_H, MLA_D), BF16), pool, tables, pos], one_chip)
+    else:
+        c = _compile(lambda q, p, t, s: mla_prefill_paged(
+            q, p, t, s, scale=0.1147, interpret=False),
+            [((MLA_B, 64, MLA_H, MLA_D), BF16), pool, tables, pos],
+            one_chip)
+    assert f"mla_{kind}_paged" in c.as_text()
+
+
+@pytest.mark.parametrize("rows", [64 * 6, 4096 * 6])
+@pytest.mark.parametrize("proj", ["gate_up", "down"])
+def test_moe_gmm_kernel_compiles(one_chip, rows, proj):
+    from repro.kernels.moe_gmm.ops import KERNEL_NAME, moe_gmm
+    k, n = (2048, 1408) if proj == "gate_up" else (1408, 2048)
+    c = _compile(lambda x, w, g: moe_gmm(x, w, g, 0, interpret=False),
+                 [((rows, k), BF16), ((1, 8, k, n), BF16), ((8,), jnp.int32)],
+                 one_chip)
+    assert f"%{KERNEL_NAME}." in c.as_text()
