@@ -379,24 +379,60 @@ def _paged_from_dense(kc, vc, page):
     return jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables)
 
 
-def test_paged_decode_kernel_matches_dense_jnp():
+@pytest.mark.parametrize("hkv,g,hd,page,lengths,window,kind", [
+    pytest.param(2, 4, 64, 16, [1, 17, 40, 64], 0, "gqa", id="grouped"),
+    pytest.param(16, 1, 64, 16, [0, 1, 16, 32, 64], 0, "gqa",
+                 id="qwen-heads-edge-lengths"),
+    pytest.param(2, 4, 64, 16, [0, 1, 16, 32, 64], 0, "gqa",
+                 id="grouped-edge-lengths"),
+    pytest.param(2, 4, 64, 16, [1, 17, 40, 64], 20, "gqa", id="window"),
+    pytest.param(2, 4, 64, 4, [0, 1, 16, 79, 80], 0, "gqa",
+                 id="blocks-of-pages"),
+    pytest.param(2, 4, 64, 4, [1, 17, 70, 80], 9, "gqa",
+                 id="blocks-of-pages-window"),
+    pytest.param(1, 16, 576, 16, [0, 1, 16, 32, 64], 0, "mla", id="mla"),
+    pytest.param(16, 1, 64, 16, [1, 16, 33, 64], 0, "nan",
+                 id="nan-pages-past-length"),
+])
+def test_paged_decode_kernel_matches_dense_jnp(hkv, g, hd, page, lengths,
+                                               window, kind):
     """Paged flash decode through per-slot page tables == dense jnp
-    attention over the gathered view, at per-slot lengths."""
+    attention over the gathered view, at per-slot lengths: 0, 1, page
+    boundaries and the full table; a table of 20 pages of 4 spans more
+    than one block of pages. A slot of length 0 has written nothing
+    (its cache is zeros), so the reference's all-masked softmax is the
+    zero vector the kernel returns. `nan`: every table entry past a
+    slot's length points at a page of NaN, which the kernel must never
+    read."""
     from repro.models.layers import decode_attention_jnp, paged_view
     key = jax.random.PRNGKey(23)
     kq, kk, kv = jax.random.split(key, 3)
-    b, hkv, g, s, hd, page = 4, 2, 4, 64, 64, 16
+    b, s = len(lengths), max(lengths)
+    lengths = jnp.array(lengths, jnp.int32)
+    empty = (lengths == 0)[:, None, None, None]
     q = jax.random.normal(kq, (b, hkv * g, hd), jnp.float32)
-    kc = jax.random.normal(kk, (b, hkv, s, hd), jnp.float32)
-    vc = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
+    kc = jnp.where(empty, 0, jax.random.normal(kk, (b, hkv, s, hd)))
+    vc = jnp.where(empty, 0, jax.random.normal(kv, (b, hkv, s, hd)))
     kp, vp, tables = _paged_from_dense(kc, vc, page)
-    lengths = jnp.array([1, 17, 40, 64], jnp.int32)
-    out = da_ops.gqa_decode_paged(q, kp, vp, tables, lengths,
-                                  interpret=True)
     view_k = paged_view(kp, tables)
     view_v = paged_view(vp, tables)
     np.testing.assert_array_equal(np.asarray(view_k), np.asarray(kc))
-    ref = decode_attention_jnp(q, view_k, view_v, lengths)
+    if kind == "mla":
+        out = da_ops.mla_decode_paged(q, kp, tables, lengths, scale=0.125,
+                                      interpret=True)
+        ref = decode_attention_jnp(q, view_k, view_k, lengths, scale=0.125)
+    else:
+        if kind == "nan":
+            n_pages = kp.shape[0]
+            nan = jnp.full((1,) + kp.shape[1:], jnp.nan, jnp.float32)
+            past = (jnp.arange(tables.shape[1])[None]
+                    >= (lengths[:, None] + page - 1) // page)
+            tables = jnp.where(past, n_pages, tables)
+            kp, vp = jnp.concatenate([kp, nan]), jnp.concatenate([vp, nan])
+        out = da_ops.gqa_decode_paged(q, kp, vp, tables, lengths,
+                                      window=window, interpret=True)
+        ref = decode_attention_jnp(q, view_k, view_v, lengths, window=window)
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 

@@ -137,6 +137,31 @@ def test_mla_paged_kernels_compile(one_chip, kind):
     assert f"mla_{kind}_paged" in c.as_text()
 
 
+# a 32k context: 2048 pages of 16 a slot
+LONG_NLP = 2048
+
+
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_paged_decode_kernels_compile_at_long_table(one_chip, kind):
+    """The paged decode kernels at a 32k table still compile: the pages
+    a block holds are bounded, so VMEM does not grow with the table."""
+    from repro.kernels.decode_attention.ops import (gqa_decode_paged,
+                                                    mla_decode_paged)
+    tables = ((B, LONG_NLP), jnp.int32)
+    pos = ((B,), jnp.int32)
+    if kind == "gqa":
+        pool = ((B * LONG_NLP, H, PAGE, HD), BF16)
+        c = _compile(lambda q, k, v, t, n: gqa_decode_paged(
+            q, k, v, t, n, interpret=False),
+            [((B, H, HD), BF16), pool, pool, tables, pos], one_chip)
+    else:
+        pool = ((B * LONG_NLP, 1, PAGE, MLA_D), BF16)
+        c = _compile(lambda q, p, t, n: mla_decode_paged(
+            q, p, t, n, scale=0.1147, interpret=False),
+            [((B, MLA_H, MLA_D), BF16), pool, tables, pos], one_chip)
+    assert f"{kind}_decode_paged" in c.as_text()
+
+
 @pytest.mark.parametrize("rows", [64 * 6, 4096 * 6])
 @pytest.mark.parametrize("proj", ["gate_up", "down"])
 def test_moe_gmm_kernel_compiles(one_chip, rows, proj):
