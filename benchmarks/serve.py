@@ -1,7 +1,7 @@
-"""Serving benchmark: continuous vs static batching AND chunked vs
-token-by-token prefill on the semantic link — tokens/s, request-latency
-and TTFT percentiles vs concurrent users, plus the paged-KV capacity
-factor (BENCH_serve.json).
+"""Serving benchmark: continuous vs static batching on the semantic
+link — tokens/s, request-latency and TTFT percentiles vs concurrent
+users, plus the paged-KV capacity factor (BENCH_serve.json). CPU counts
+(cycles, pages, bits); its walls are CPU walls, not device numbers.
 
 The paper serves one user at a time; this benchmark measures the
 engine that serves MANY. For each user count a mixed-length
@@ -9,19 +9,12 @@ engine that serves MANY. For each user count a mixed-length
 through `ServeEngine` on a fading bounded-ARQ radio:
 
 * `continuous` vs `static` — in-flight admission vs the barrier
-  (`speedup_cycles` > 1 at every width wherever lengths are mixed).
-* `prefill=chunked` vs `prefill=token` — bucketed chunk admission vs
-  one prompt token per cycle. Generated tokens and radio bills are
-  BIT-IDENTICAL (admission is pure scheduling); time-to-first-token
-  p50/p99 — in decode cycles AND wall seconds — must improve at every
-  width, most dramatically on the long-prompt mixed case where a
-  token-mode prompt pins its slot for P cycles.
-* paged KV capacity — the `longprompt` case replays on the reduced
-  transformer with a dense cache and with the shared page pool at the
-  same tokens: `capacity_factor` = dense reserved KV columns
+  (`speedup_cycles` > 1 at every width wherever lengths are mixed), on
+  the same schedule-invariant radio bill.
+* paged KV capacity — a long-prompt mix on the reduced transformer:
+  `capacity_factor` = the KV columns a per-slot reservation would hold
   (n_slots * max_seq_len) over the pool's peak in-flight columns
-  (peak_pages * page_size) — >=2x fewer resident columns for the same
-  trace, same tokens, same bill.
+  (peak_pages * page_size).
 
     PYTHONPATH=src python -m benchmarks.run --only serve
 """
@@ -53,10 +46,10 @@ def _case_dict(rep) -> dict:
 
 
 def _paged_capacity(seed: int) -> dict:
-    """Dense vs paged KV on the reduced transformer: one long-prompt
-    request drives max_seq_len while short requests churn — the dense
-    layout reserves n_slots * S columns for the whole run; the pool
-    holds only the tokens actually in flight."""
+    """Paged KV on the reduced transformer: one long-prompt request
+    drives max_seq_len while short requests churn — a per-slot
+    reservation would hold n_slots * S columns for the whole run; the
+    pool holds only the tokens actually in flight."""
     from repro.serve import Request, RequestTrace
     cfg = get_arch("qwen1.5-0.5b").reduced()
     params = init_params(jax.random.PRNGKey(seed), M.param_specs(cfg))
@@ -66,23 +59,18 @@ def _paged_capacity(seed: int) -> dict:
         for rid in range(1, 10))
     trace = RequestTrace(31, reqs)
     n_slots = 4
-    dense = ServeEngine(cfg, params, n_slots=n_slots, kv="dense",
+    paged = ServeEngine(cfg, params, n_slots=n_slots, page_size=page,
                         chunk_size=CHUNK).serve(trace)
-    paged = ServeEngine(cfg, params, n_slots=n_slots, kv="paged",
-                        page_size=page, chunk_size=CHUNK).serve(trace)
-    assert [r.tokens for r in paged.results] == \
-           [r.tokens for r in dense.results]
-    assert paged.bits == dense.bits
+    assert all(r.status == "ok" for r in paged.results)
     S = trace.max_seq_len()
-    dense_cols = n_slots * S
+    slot_cols = n_slots * S
     paged_cols = paged.peak_pages * page
     return {
         "arch": cfg.name, "n_slots": n_slots, "page_size": page,
-        "max_seq_len": S, "dense_reserved_cols": dense_cols,
+        "max_seq_len": S, "slot_reserved_cols": slot_cols,
         "paged_peak_cols": paged_cols,
         "peak_pages": paged.peak_pages, "n_pages": paged.n_pages,
-        "capacity_factor": dense_cols / max(paged_cols, 1),
-        "tokens_bit_identical": True,
+        "capacity_factor": slot_cols / max(paged_cols, 1),
     }
 
 
@@ -94,16 +82,14 @@ def run(full: bool = False, seed: int = 0) -> dict:
     # more users than slots, else there is only one batch and nothing
     # for the barrier to lose
     user_counts = (16, 32, 64, 128) if full else (16, 32)
-    engines = {pf: ServeEngine(cfg, params, n_slots=n_slots, radio=radio,
-                               prefill=pf, chunk_size=CHUNK)
-               for pf in ("chunked", "token")}
+    engine = ServeEngine(cfg, params, n_slots=n_slots, radio=radio,
+                         chunk_size=CHUNK)
 
     out = {"arch": cfg.name, "n_slots": n_slots, "snr_db": radio.snr_db,
            "arq_max_tx": radio.arq_max_tx, "chunk_size": CHUNK,
            "cases": {}}
     specs = [(f"users{u}", u, (4, 16)) for u in user_counts]
-    # the long-prompt mix: token-mode admission pins a slot for up to
-    # 96 cycles before its first token — the adversarial TTFT case
+    # the long-prompt mix: prompts of up to six chunks
     specs.append(("longprompt16", 16, (8, 96)))
     for name, users, plens in specs:
         trace = make_trace(seed + users + (97 if "long" in name else 0),
@@ -111,24 +97,12 @@ def run(full: bool = False, seed: int = 0) -> dict:
                            new_tokens=(1, 12), mean_gap=0.0)
         case = {}
         for mode in ("continuous", "static"):
-            engines["chunked"].serve(trace, mode)   # warm the jit caches
-            case[mode] = _case_dict(engines["chunked"].serve(trace, mode))
+            engine.serve(trace, mode)               # warm the jit caches
+            case[mode] = _case_dict(engine.serve(trace, mode))
         case["speedup_cycles"] = (case["static"]["cycles"]
                                   / max(case["continuous"]["cycles"], 1))
         # same trace, same radio draws: the bill is schedule-invariant
         assert case["continuous"]["bits"] == case["static"]["bits"]
-
-        engines["token"].serve(trace)               # warm
-        tok = _case_dict(engines["token"].serve(trace))
-        case["prefill_token"] = tok
-        chk = case["continuous"]                    # chunked continuous
-        # admission plane is pure scheduling: bills bit-for-bit
-        assert tok["bits"] == chk["bits"]
-        assert tok["erased_bits"] == chk["erased_bits"]
-        case["ttft_speedup_p99_cycles"] = (tok["p99_ttft_cycles"]
-                                           / max(chk["p99_ttft_cycles"], 1))
-        case["ttft_speedup_p50_cycles"] = (tok["p50_ttft_cycles"]
-                                           / max(chk["p50_ttft_cycles"], 1))
         out["cases"][name] = case
     out["paged_kv"] = _paged_capacity(seed)
     return out
@@ -141,7 +115,7 @@ def main(full: bool = False) -> list[str]:
         json.dump(res, f, indent=1)
     rows = []
     for case, rec in res["cases"].items():
-        for mode in ("continuous", "static", "prefill_token"):
+        for mode in ("continuous", "static"):
             d = rec[mode]
             rows.append(f"serve,{case}/{mode},cycles,{d['cycles']}")
             rows.append(f"serve,{case}/{mode},tokens_per_cycle,"
@@ -162,8 +136,6 @@ def main(full: bool = False) -> list[str]:
                         f"{d['erased_bits']:.0f}")
         rows.append(f"serve,{case},speedup_cycles,"
                     f"{rec['speedup_cycles']:.2f}")
-        rows.append(f"serve,{case},ttft_speedup_p99_cycles,"
-                    f"{rec['ttft_speedup_p99_cycles']:.2f}")
     pk = res["paged_kv"]
     rows.append(f"serve,paged_kv,capacity_factor,"
                 f"{pk['capacity_factor']:.2f}")

@@ -145,7 +145,7 @@ def compare_attention_routes(cfg, params, *, n_slots: int = 8,
     nv2 = jnp.asarray(np.maximum(C - 3 * np.arange(B), 1), jnp.int32)
     out = {}
     for kernel in (True, False):
-        pf = jax.jit(make_paged_prefill_step(cfg, sc, page_size, "fused",
+        pf = jax.jit(make_paged_prefill_step(cfg, sc, page_size,
                                              kernel=kernel))
         dec = jax.jit(make_paged_decode_step(cfg, sc, page_size,
                                              kernel=kernel))

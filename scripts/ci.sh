@@ -169,45 +169,39 @@ ok = ok and d["steady_wall_s"] <= 1.25 * b4["steady_wall_s"]
 sys.exit(0 if ok else 1)
 EOF
 
-echo "=== serving smoke (continuous vs static + chunked vs token prefill, BENCH_serve.json) ==="
+echo "=== serving smoke (continuous vs static, BENCH_serve.json) ==="
 python -m benchmarks.run --only serve
 python - <<'EOF'
 import json, sys
 res = json.load(open("benchmarks/results/BENCH_serve.json"))
 ok = True
 for case, rec in res["cases"].items():
-    c, s, t = rec["continuous"], rec["static"], rec["prefill_token"]
+    c, s = rec["continuous"], rec["static"]
     print(f"serve {case}: continuous {c['cycles']} cycles "
           f"({c['tokens_per_cycle']:.2f} tok/cyc, p99 "
           f"{c['p99_latency_cycles']:.0f}) vs static {s['cycles']} "
           f"({s['tokens_per_cycle']:.2f} tok/cyc, p99 "
           f"{s['p99_latency_cycles']:.0f}) -> "
-          f"{rec['speedup_cycles']:.2f}x | ttft p99 chunked "
-          f"{c['p99_ttft_cycles']:.0f} vs token {t['p99_ttft_cycles']:.0f} "
-          f"cycles ({rec['ttft_speedup_p99_cycles']:.1f}x) | "
+          f"{rec['speedup_cycles']:.2f}x | ttft p99 "
+          f"{c['p99_ttft_cycles']:.0f} cycles | "
           f"{c['bits']:.0f} bits ({c['erased_bits']:.0f} erased)")
-    # the tentpole claims: in-flight admission beats the barrier at
-    # mixed lengths, and chunked prefill beats token-by-token TTFT at
-    # EVERY width — both on the SAME schedule-invariant radio bill
+    # the tentpole claim: in-flight admission beats the barrier at
+    # mixed lengths, on the SAME schedule-invariant radio bill
     ok = ok and rec["speedup_cycles"] > 1.0
-    ok = ok and c["bits"] == s["bits"] == t["bits"]
-    ok = ok and c["erased_bits"] == t["erased_bits"]
-    ok = ok and c["p99_ttft_cycles"] < t["p99_ttft_cycles"]
-    ok = ok and c["p50_ttft_cycles"] <= t["p50_ttft_cycles"]
-    for d in (c, s, t):
+    ok = ok and c["bits"] == s["bits"]
+    for d in (c, s):
         ok = ok and abs(d["delivered_bits"] + d["erased_bits"]
                         - d["bits"]) < 1e-6
 # the bounded-ARQ link actually erased something somewhere
 ok = ok and any(rec["continuous"]["erased_bits"] > 0
                 for rec in res["cases"].values())
-# paged KV: same tokens in >=2x fewer resident KV columns than the
-# dense per-slot reservation on the long-prompt mix
+# paged KV: >=2x fewer resident KV columns than a per-slot reservation
+# on the long-prompt mix
 pk = res["paged_kv"]
-print(f"serve paged_kv: dense {pk['dense_reserved_cols']} cols vs "
+print(f"serve paged_kv: per-slot {pk['slot_reserved_cols']} cols vs "
       f"paged peak {pk['paged_peak_cols']} -> "
-      f"{pk['capacity_factor']:.2f}x (tokens bit-identical: "
-      f"{pk['tokens_bit_identical']})")
-ok = ok and pk["capacity_factor"] >= 2.0 and pk["tokens_bit_identical"]
+      f"{pk['capacity_factor']:.2f}x")
+ok = ok and pk["capacity_factor"] >= 2.0
 sys.exit(0 if ok else 1)
 EOF
 

@@ -1,4 +1,3 @@
-from repro.kernels.decode_attention.ops import gqa_decode, gqa_decode_paged
-from repro.kernels.decode_attention.kernel import (decode_attention,
-                                                  paged_decode_attention)
+from repro.kernels.decode_attention.ops import gqa_decode_paged
+from repro.kernels.decode_attention.kernel import paged_decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref

@@ -1,11 +1,7 @@
-"""Flash-decode attention kernels: one query token per row against a KV
-cache, with online softmax, causal length masking and sliding windows.
-Head-group dim G (= H / Hkv) rides the sublane axis, hd the lanes.
-
-`decode_attention` reads a dense cache [B, Hkv, S, hd]. Grid (B, Hkv,
-S/bs); the S axis is the innermost (sequential on TPU) grid dim, so the
-running (m, l, acc) state lives in VMEM scratch across KV blocks
-(ops.py pads G and hd to hardware multiples).
+"""Flash-decode attention kernel: one query token per row against the
+paged KV cache, with online softmax, causal length masking and sliding
+windows. Head-group dim G (= H / Hkv) rides the sublane axis, hd the
+lanes.
 
 `paged_decode_attention` reads the shared page pool [n_pages, Hkv,
 page, hd] through per-slot page tables, at the pool's stored width.
@@ -33,51 +29,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
-BLOCK_S = 512
 NEG_INF = -1e30
 # the paged kernel's page buffers: at most this many pages a block, in at
 # most this much VMEM
 MAX_PAGES_PER_BLOCK = 16
 PAGE_BLOCK_VMEM = 4 * 1024 * 1024
-
-
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale: float, window: int,
-                   block_s: int):
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0]                       # [G, hd]
-    k = k_ref[0, 0]                       # [bs, hd]
-    v = v_ref[0, 0]                       # [bs, hd]
-    length = len_ref[pl.program_id(0)]    # this batch row's valid prefix
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [G, bs]
-    pos = j * block_s + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
-    valid = pos < length
-    if window:
-        valid &= pos >= length - window
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_scr[...]                   # [G, 1]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)                # [G, bs]
-    corr = jnp.exp(m_prev - m_new)        # [G, 1]
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-
-    @pl.when(j == nj - 1)
-    def _done():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_decode_kernel(fetch_ref, len_ref, q_ref, *refs, scale: float,
@@ -238,40 +194,3 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         interpret=resolve_interpret(interpret),
     )(fetch, length, q, *[pool for pool in pools for _ in range(ppb)])
 
-
-def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     length: jax.Array, window: int = 0,
-                     scale: float | None = None,
-                     interpret: bool | None = None) -> jax.Array:
-    """q [B, Hkv, G, hd]; k/v [B, Hkv, S, hd]; length scalar int32 OR a
-    per-batch-row [B] vector (continuous-batching decode: every slot
-    masks its own prefix; a scalar is broadcast to all rows).
-    `scale` defaults to 1/sqrt(hd) — pass explicitly when hd is padded.
-    Returns [B, Hkv, G, hd] fp32."""
-    B, Hkv, G, hd = q.shape
-    S = k.shape[2]
-    bs = min(BLOCK_S, S)
-    assert S % bs == 0, (S, bs)
-    if scale is None:
-        scale = 1.0 / (hd ** 0.5)
-    grid = (B, Hkv, S // bs)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, window=window,
-                          block_s=bs),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,)),
-      q, k, v)

@@ -1,7 +1,6 @@
-"""jit'd wrappers. The dense decode pads G to a sublane multiple (8) and
-hd to a lane multiple (128); the paged decode pads only G, and reads
-the pools at their stored width. Padded queries and value columns are
-sliced away after the kernel."""
+"""jit'd wrappers of the paged decode kernel. The GQA wrapper pads only
+G to a sublane multiple (8), and reads the pools at their stored width;
+padded queries are sliced away after the kernel."""
 from __future__ import annotations
 
 import functools
@@ -9,33 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attention.kernel import (decode_attention,
-                                                  paged_decode_attention)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def gqa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-               length: jax.Array, window: int = 0,
-               interpret: bool | None = None) -> jax.Array:
-    """q [B, H, hd]; caches [B, Hkv, S, hd]; `length` a scalar or a
-    per-row [B] vector of valid-prefix counts. Returns [B, H, hd] fp32."""
-    B, H, hd = q.shape
-    Hkv = k_cache.shape[1]
-    G = H // Hkv
-    qg = q.reshape(B, Hkv, G, hd)
-
-    gp = (-G) % 8
-    dp = (-hd) % 128
-    if gp:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp), (0, 0)))
-    if dp:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, dp)))
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, 0), (0, dp)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, 0), (0, dp)))
-
-    out = decode_attention(qg, k_cache, v_cache, length, window=window,
-                           scale=1.0 / (hd ** 0.5), interpret=interpret)
-    return out[:, :, :G, :hd].reshape(B, H, hd)
+from repro.kernels.decode_attention.kernel import paged_decode_attention
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))

@@ -1,10 +1,13 @@
 """Flash-prefill GQA attention kernel: a whole prompt chunk of C query
-tokens vs. a blocked KV cache with online softmax — the serving engine's
-admission hot path (one launch per chunk instead of C decode launches).
+tokens vs. the paged KV cache with online softmax — the serving
+engine's admission hot path (one launch per chunk instead of C decode
+launches).
 
-Grid (B, Hkv, S/bs); the S axis is the innermost (sequential on TPU)
-grid dim, so the running (m, l, acc) state lives in VMEM scratch across
-KV blocks. The C chunk positions and the G head-group dim are flattened
+Grid (B, Hkv, n_lp); KV block j is pool page tables[b, j], routed by
+the scalar-prefetched BlockSpec index maps, whose logical columns start
+at j * page. The page axis is the innermost (sequential on TPU) grid
+dim, so the running (m, l, acc) state lives in VMEM scratch across
+pages. The C chunk positions and the G head-group dim are flattened
 onto the sublane axis as C*G query rows; row r is chunk position r // G,
 whose global query position is start[b] + r // G. Causality is
 per-query-row: row r attends cache columns <= start[b] + r // G (with an
@@ -23,13 +26,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
-BLOCK_S = 512
 NEG_INF = -1e30
 
 
-def _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
-                    m_scr, l_scr, acc_scr, *, scale: float, window: int,
-                    block_s: int, g: int):
+def _paged_prefill_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
+                          m_scr, l_scr, acc_scr, *, scale: float,
+                          window: int, page: int, g: int):
+    del tbl_ref  # consumed by the KV BlockSpecs' index maps
     j = pl.program_id(2)
     nj = pl.num_programs(2)
 
@@ -40,14 +43,15 @@ def _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0]                       # [C*G, hd]
-    k = k_ref[0, 0]                       # [bs, hd]
-    v = v_ref[0, 0]                       # [bs, hd]
+    k = k_ref[0, 0]                       # [page, hd]
+    v = v_ref[0, 0]                       # [page, hd]
     start = start_ref[pl.program_id(0)]   # this row's first chunk position
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [CG, bs]
+    # [CG, page]
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     rows = q.shape[0]
     qpos = start + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // g
-    kpos = j * block_s + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
+    kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     valid = kpos <= qpos                  # causal: own position included
     if window:
         valid &= kpos > qpos - window
@@ -55,7 +59,7 @@ def _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
 
     m_prev = m_scr[...]                   # [CG, 1]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)                # [CG, bs]
+    p = jnp.exp(s - m_new)                # [CG, page]
     corr = jnp.exp(m_prev - m_new)        # [CG, 1]
     l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * corr + jnp.dot(
@@ -66,18 +70,6 @@ def _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
     def _done():
         o_ref[0, 0] = (acc_scr[...] /
                        jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_prefill_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_scr, l_scr, acc_scr, *, scale: float,
-                          window: int, page: int, g: int):
-    # identical math to the dense kernel: KV block j is pool page
-    # tables[b, j] (routed by the BlockSpec index maps), whose logical
-    # columns start at j * page.
-    del tbl_ref
-    _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
-                    m_scr, l_scr, acc_scr, scale=scale, window=window,
-                    block_s=page, g=g)
 
 
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
@@ -127,42 +119,3 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
       jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (B,)),
       q, k_pool, v_pool)
 
-
-def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                      start: jax.Array, g: int, window: int = 0,
-                      scale: float | None = None,
-                      interpret: bool | None = None) -> jax.Array:
-    """q [B, Hkv, C*G, hd] (chunk-major query rows: row r = chunk
-    position r // G, head-group member r % G); k/v [B, Hkv, S, hd];
-    `start` [B] int32 — per-row global position of chunk token 0 (the
-    cache must already hold the chunk's own K/V columns). `scale`
-    defaults to 1/sqrt(hd) — pass explicitly when hd is padded.
-    Returns [B, Hkv, C*G, hd] fp32."""
-    B, Hkv, CG, hd = q.shape
-    assert CG % g == 0, (CG, g)
-    S = k.shape[2]
-    bs = min(BLOCK_S, S)
-    assert S % bs == 0, (S, bs)
-    if scale is None:
-        scale = 1.0 / (hd ** 0.5)
-    grid = (B, Hkv, S // bs)
-    return pl.pallas_call(
-        functools.partial(_prefill_kernel, scale=scale, window=window,
-                          block_s=bs, g=g),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, CG, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, CG, hd), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, CG, hd), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((CG, 1), jnp.float32),
-            pltpu.VMEM((CG, 1), jnp.float32),
-            pltpu.VMEM((CG, hd), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (B,)),
-      q, k, v)

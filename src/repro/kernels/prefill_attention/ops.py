@@ -1,7 +1,7 @@
-"""jit'd wrapper with hardware-alignment padding: the head-group dim G
-is padded to a sublane multiple (8) so the flattened C*G query rows stay
-aligned, hd to a lane multiple (128); padded rows/columns are sliced
-away after the kernel."""
+"""jit'd wrappers of the paged prefill kernel. The GQA wrapper pads the
+head-group dim G to a sublane multiple (8) so the flattened C*G query
+rows stay aligned, and hd to a lane multiple (128); padded rows/columns
+are sliced away after the kernel."""
 from __future__ import annotations
 
 import functools
@@ -9,40 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.prefill_attention.kernel import (paged_prefill_attention,
-                                                    prefill_attention)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def gqa_prefill(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                start: jax.Array, window: int = 0,
-                interpret: bool | None = None) -> jax.Array:
-    """q [B, C, H, hd] — a C-token prompt chunk per slot; caches
-    [B, Hkv, S, hd] already holding the chunk's own K/V columns;
-    `start` [B] per-row global position of chunk token 0.
-    Returns [B, C, H, hd] fp32."""
-    B, C, H, hd = q.shape
-    Hkv = k_cache.shape[1]
-    G = H // Hkv
-    # [B, C, H, hd] -> chunk-major query rows [B, Hkv, C, G, hd]
-    qg = q.reshape(B, C, Hkv, G, hd).transpose(0, 2, 1, 3, 4)
-
-    gp = (-G) % 8
-    dp = (-hd) % 128
-    Gp = G + gp
-    if gp:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, gp), (0, 0)))
-    if dp:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, 0), (0, dp)))
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, 0), (0, dp)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, 0), (0, dp)))
-
-    qf = qg.reshape(B, Hkv, C * Gp, hd + dp)
-    out = prefill_attention(qf, k_cache, v_cache, start, g=Gp,
-                            window=window, scale=1.0 / (hd ** 0.5),
-                            interpret=interpret)
-    out = out.reshape(B, Hkv, C, Gp, hd + dp)[:, :, :, :G, :hd]
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, C, H, hd)
+from repro.kernels.prefill_attention.kernel import paged_prefill_attention
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))

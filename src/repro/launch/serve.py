@@ -59,15 +59,6 @@ def parse_args(argv=None):
     ap.add_argument("--arq-max-tx", type=int, default=0,
                     help=">0: bounded ARQ — exhausted uplinks are "
                          "erased and the request abandoned")
-    ap.add_argument("--prefill", default="chunked",
-                    choices=["chunked", "token"],
-                    help="admission plane: bucketed prompt chunks (one "
-                         "launch per chunk) or the token-by-token path; "
-                         "tokens and bills are bit-identical either way")
-    ap.add_argument("--kv", default="paged", choices=["paged", "dense"],
-                    help="slot KV layout: shared page pool (capacity "
-                         "bounded by tokens in flight) or dense "
-                         "per-slot [B, S] cache")
     ap.add_argument("--chunk-size", type=int, default=32,
                     help="max prompt tokens absorbed per cycle "
                          "(chunked prefill)")
@@ -75,7 +66,7 @@ def parse_args(argv=None):
                     help="paged-KV page length in tokens")
     ap.add_argument("--page-budget", type=int, default=0,
                     help=">0: cap the shared page pool at this many "
-                         "pages (0 = dense-parity capacity)")
+                         "pages (0 = batch * ceil(S/page-size))")
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--greedy", action="store_true")
     ap.add_argument("--mesh", default="none", choices=["none", "test"],
@@ -214,8 +205,7 @@ def main(argv=None) -> dict:
                              M.param_specs(cfg))
         engine = ServeEngine(cfg, params, n_slots=args.batch, radio=radio,
                              temperature=args.temperature,
-                             greedy=args.greedy, prefill=args.prefill,
-                             kv=args.kv, chunk_size=args.chunk_size,
+                             greedy=args.greedy, chunk_size=args.chunk_size,
                              page_size=args.page_size,
                              page_budget=args.page_budget)
         if args.aot_warmup:

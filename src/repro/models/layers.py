@@ -4,18 +4,14 @@ param dicts; activation shardings via logical-axis constraints."""
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.kernels import resolve_interpret
 from repro.nn import Spec, constrain
 
 NEG_INF = -1e30
-import os as _os
-USE_DIST_DECODE = _os.environ.get("REPRO_DIST_DECODE", "0") == "1"
 
 
 # ---------------------------------------------------------------- norms
@@ -330,67 +326,43 @@ def _pages_kernel(pages) -> bool:
     return use_attn_kernel(None if pages is None else pages.get("kernel"))
 
 
-def decode_attention_slots_paged(q, k_pool, v_pool, tables, lengths,
-                                 window: int = 0, kernel: bool = False):
-    """Per-slot flash-decode over the shared page pool: q [B,H,hd],
-    pools [n_pages,Hkv,page,hd], `tables` [B,n_lp], `lengths` [B].
-    `kernel=True` streams pool pages straight off the scalar-prefetched
-    page table (Pallas); the jnp route gathers a dense per-slot view
-    first — both are bit-equivalent to dense decode on the valid
-    prefix."""
-    if kernel:
-        from repro.kernels.decode_attention.ops import gqa_decode_paged
-        return gqa_decode_paged(q, k_pool, v_pool, tables, lengths,
-                                window=window).astype(q.dtype)
-    return decode_attention_jnp(q, paged_view(k_pool, tables),
-                                paged_view(v_pool, tables), lengths,
-                                window=window).astype(q.dtype)
+def no_pages():
+    """Raise: per-slot serving attention (GQA or latent), and any cache
+    of latent attention, live only in the shared page pool."""
+    raise NotImplementedError("per-slot attention serves from the paged "
+                              "pool only: pass `pages`")
 
 
 def attention_prefill_slots(p, x, cfg, cache_k, cache_v, start, n_valid,
                             window=0, pages=None):
-    """Fused chunk prefill: x [B,C,d] — C prompt tokens per slot
-    starting at per-row cache position `start` [B]; chunk positions
-    >= n_valid[b] are padded tail and masked out of the KV insert. One
-    bulk K/V column write + one chunk-vs-cache attention launch replace
-    C decode steps. `pages` = {"tables": [B,n_lp], "page_size": int,
-    "active": [B] bool or None, "kernel": bool or None} switches the
-    cache to the shared page pool and picks the attention route
+    """Fused chunk prefill over the shared page pool: x [B,C,d] — C
+    prompt tokens per slot starting at per-row cache position `start`
+    [B]; chunk positions >= n_valid[b] are padded tail and masked out of
+    the KV insert. One bulk K/V column write + one chunk-vs-cache
+    attention launch replace C decode steps. `pages` = {"tables":
+    [B,n_lp], "page_size": int, "active": [B] bool or None, "kernel":
+    bool or None} is required: the caches are the pools
+    [n_pages,Hkv,page,hd], and `kernel` picks the attention route
     (`use_attn_kernel`). Returns (out [B,C,d], new_k, new_v)."""
+    if pages is None:
+        no_pages()
     B, C, _ = x.shape
     hd = cfg.hd
     positions = start[:, None] + jnp.arange(C)[None]        # [B, C]
     q, k, v = _qkv(p, x, cfg, positions)                    # [B,C,H|Hkv,hd]
-    valid = jnp.arange(C)[None, :] < n_valid[:, None]       # [B, C]
-    kernel = _pages_kernel(pages)
-    if pages is not None:
-        keep = valid
-        if pages.get("active") is not None:
-            keep &= pages["active"][:, None]
-        cache_k = paged_insert(cache_k, pages["tables"], positions, k, keep)
-        cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
-        if kernel:
-            from repro.kernels.prefill_attention.ops import gqa_prefill_paged
-            out = gqa_prefill_paged(q, cache_k, cache_v, pages["tables"],
-                                    start, window=window)
-        else:
-            out = prefill_attention_jnp(q, paged_view(cache_k, pages["tables"]),
-                                        paged_view(cache_v, pages["tables"]),
-                                        start, window=window)
+    keep = jnp.arange(C)[None, :] < n_valid[:, None]        # [B, C]
+    if pages.get("active") is not None:
+        keep &= pages["active"][:, None]
+    cache_k = paged_insert(cache_k, pages["tables"], positions, k, keep)
+    cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
+    if _pages_kernel(pages):
+        from repro.kernels.prefill_attention.ops import gqa_prefill_paged
+        out = gqa_prefill_paged(q, cache_k, cache_v, pages["tables"],
+                                start, window=window)
     else:
-        S = cache_k.shape[2]
-        rows = jnp.arange(B)[:, None]
-        cols = jnp.where(valid, positions, S)               # OOB -> drop
-        cache_k = cache_k.at[rows, :, cols, :].set(
-            k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[rows, :, cols, :].set(
-            v.astype(cache_v.dtype), mode="drop")
-        if kernel:
-            from repro.kernels.prefill_attention.ops import gqa_prefill
-            out = gqa_prefill(q, cache_k, cache_v, start, window=window)
-        else:
-            out = prefill_attention_jnp(q, cache_k, cache_v, start,
-                                        window=window)
+        out = prefill_attention_jnp(q, paged_view(cache_k, pages["tables"]),
+                                    paged_view(cache_v, pages["tables"]),
+                                    start, window=window)
     out = out.reshape(B, C, cfg.n_heads * hd).astype(x.dtype)
     return constrain(linear(p["wo"], out), "batch", "seq",
                      "act_embed"), cache_k, cache_v
@@ -406,109 +378,40 @@ def attention_train(p, x, cfg, positions=None, causal=True, window=0):
     return constrain(linear(p["wo"], out), "batch", "seq", "act_embed")
 
 
-def decode_attention_dist(q, k_cache, v_cache, length, window, mesh,
-                          axis: str = "model"):
-    """Distributed flash-decode over a sequence-sharded cache: each shard
-    of the `axis`-sharded kv_seq dim computes masked partial softmax
-    stats over its LOCAL cache slice; partials combine with one tiny
-    psum (log-sum-exp combine). Replaces both the full-cache read and
-    the dynamic window slice, which XLA could only realize by
-    all-gathering the entire cache (350 GB/step for long_500k —
-    EXPERIMENTS.md §Perf-3)."""
-    B, Hkv, S, hd = k_cache.shape
-    H = q.shape[1]
-    G = H // Hkv
-    n_sh = mesh.shape[axis]
-    bax = tuple(a for a in ("pod", "data") if a in mesh.shape)
-    bspec = bax if (bax and B % math.prod(mesh.shape[a] for a in bax) == 0) \
-        else None
-
-    def f(ql, kl, vl):
-        j = jax.lax.axis_index(axis)
-        Bl = kl.shape[0]
-        S_loc = kl.shape[2]
-        offset = j * S_loc
-        qf = ql.reshape(Bl, Hkv, G, hd)
-        logits = jnp.einsum("bhgd,bhsd->bhgs", qf, kl.astype(qf.dtype))
-        logits = logits.astype(jnp.float32) / math.sqrt(hd)
-        pos = offset + jnp.arange(S_loc)
-        valid = pos < length
-        if window:
-            valid &= pos >= length - window
-        logits = jnp.where(valid[None, None, None, :], logits, NEG_INF)
-        m = logits.max(-1)                                   # [B,Hkv,G]
-        p = jnp.where(valid[None, None, None, :],
-                      jnp.exp(logits - m[..., None]), 0.0)
-        s = p.sum(-1)
-        acc = jnp.einsum("bhgs,bhsd->bhgd", p.astype(vl.dtype),
-                         vl).astype(jnp.float32)
-        gm = jax.lax.pmax(m, axis)
-        corr = jnp.exp(m - gm)                               # 0 if local -inf
-        s = jax.lax.psum(s * corr, axis)
-        acc = jax.lax.psum(acc * corr[..., None], axis)
-        out = acc / jnp.maximum(s[..., None], 1e-30)
-        return out.reshape(Bl, H, hd).astype(ql.dtype)
-
-    return jax.shard_map(
-        f, mesh=mesh,
-        in_specs=(P(bspec, None, None), P(bspec, None, axis, None),
-                  P(bspec, None, axis, None)),
-        out_specs=P(bspec, None, None),
-        check_vma=False,
-    )(q, k_cache, v_cache)
-
-
-def decode_attention_slots(q, k_cache, v_cache, lengths, window: int = 0,
-                           kernel: bool = False):
-    """Per-slot flash-decode: q [B,H,hd], caches [B,Hkv,S,hd],
-    `lengths` [B] — each row attends its OWN prefix (the serving
-    engine's hot path, where every slot is at a different depth).
-    `kernel=True` routes through the Pallas decode_attention kernel;
-    the pure-jnp masked softmax is the bit-equivalent plain route."""
-    if kernel:
-        from repro.kernels.decode_attention.ops import gqa_decode
-        return gqa_decode(q, k_cache, v_cache, lengths,
-                          window=window).astype(q.dtype)
-    return decode_attention_jnp(q, k_cache, v_cache, lengths,
-                                window=window).astype(q.dtype)
-
-
 def attention_decode_slots(p, x, cfg, cache_k, cache_v, indices, window=0,
                            pages=None):
-    """Slot-axis decode: x [B,1,d], `indices` [B] — each row writes its
-    k/v at its own cache position and attends its own prefix. The
-    continuous-batching analogue of `attention_decode`; rows are fully
-    independent, so admitting a new request into a freed slot never
-    perturbs its neighbours. With `pages` = {"tables", "page_size",
-    "active"} the caches are the shared page pool [n_pages,Hkv,page,hd]
-    and writes land through each slot's page table (inactive rows'
-    writes are dropped — the pool has no batch axis to select over);
-    `pages["kernel"]` picks the attention route (`use_attn_kernel`)."""
+    """Slot-axis decode over the shared page pool: x [B,1,d], `indices`
+    [B] — each row writes its k/v at its own cache position and attends
+    its own prefix. The continuous-batching analogue of
+    `attention_decode`; rows are fully independent, so admitting a new
+    request into a freed slot never perturbs its neighbours. `pages` =
+    {"tables", "page_size", "active", "kernel"} is required: the caches
+    are the pools [n_pages,Hkv,page,hd], writes land through each slot's
+    page table (inactive rows' writes are dropped — the pool has no
+    batch axis to select over), and `pages["kernel"]` picks the
+    attention route (`use_attn_kernel`): the Pallas kernel streams pool
+    pages straight off the scalar-prefetched page table, the jnp route
+    gathers a dense per-slot view first."""
+    if pages is None:
+        no_pages()
     B = x.shape[0]
     hd = cfg.hd
     positions = indices[:, None]                           # [B,1]
     q, k, v = _qkv(p, x, cfg, positions)
-    kernel = _pages_kernel(pages)
-    if pages is not None:
-        keep = jnp.ones((B, 1), bool) if pages.get("active") is None \
-            else pages["active"][:, None]
-        cache_k = paged_insert(cache_k, pages["tables"], positions, k, keep)
-        cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
-        out = decode_attention_slots_paged(q[:, 0], cache_k, cache_v,
-                                           pages["tables"], indices + 1,
-                                           window, kernel)
+    keep = jnp.ones((B, 1), bool) if pages.get("active") is None \
+        else pages["active"][:, None]
+    cache_k = paged_insert(cache_k, pages["tables"], positions, k, keep)
+    cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
+    q, tables, lengths = q[:, 0], pages["tables"], indices + 1
+    if _pages_kernel(pages):
+        from repro.kernels.decode_attention.ops import gqa_decode_paged
+        out = gqa_decode_paged(q, cache_k, cache_v, tables, lengths,
+                               window=window)
     else:
-        S = cache_k.shape[2]
-        hit = jnp.arange(S)[None, :] == indices[:, None]   # [B,S]
-        cache_k = jnp.where(hit[:, None, :, None],
-                            k.transpose(0, 2, 1, 3).astype(cache_k.dtype),
-                            cache_k)
-        cache_v = jnp.where(hit[:, None, :, None],
-                            v.transpose(0, 2, 1, 3).astype(cache_v.dtype),
-                            cache_v)
-        out = decode_attention_slots(q[:, 0], cache_k, cache_v, indices + 1,
-                                     window, kernel)
-    out = out.reshape(B, 1, cfg.n_heads * hd).astype(x.dtype)
+        out = decode_attention_jnp(q, paged_view(cache_k, tables),
+                                   paged_view(cache_v, tables), lengths,
+                                   window=window)
+    out = out.astype(x.dtype).reshape(B, 1, cfg.n_heads * hd)
     return constrain(linear(p["wo"], out), "batch", "seq",
                      "act_embed"), cache_k, cache_v
 
@@ -516,11 +419,9 @@ def attention_decode_slots(p, x, cfg, cache_k, cache_v, indices, window=0,
 def attention_decode(p, x, cfg, cache_k, cache_v, index, window=0,
                      pages=None):
     """x [B,1,d]; cache [B,Hkv,S,hd]; index = scalar write position, or
-    a per-slot [B] vector (dispatches to `attention_decode_slots`; the
-    scalar path stays bitwise the legacy decode).
+    a per-slot [B] vector (dispatches to `attention_decode_slots`, over
+    the paged pool; the scalar path stays bitwise the legacy decode).
     Returns (out [B,1,d], new_k, new_v)."""
-    from repro.nn.sharding import current_mesh
-
     if jnp.asarray(index).ndim:
         return attention_decode_slots(p, x, cfg, cache_k, cache_v, index,
                                       window, pages=pages)
@@ -532,19 +433,8 @@ def attention_decode(p, x, cfg, cache_k, cache_v, index, window=0,
         cache_k, k.transpose(0, 2, 1, 3).astype(cache_k.dtype), index, axis=2)
     cache_v = jax.lax.dynamic_update_slice_in_dim(
         cache_v, v.transpose(0, 2, 1, 3).astype(cache_v.dtype), index, axis=2)
-    S = cache_k.shape[2]
-    mesh = current_mesh()
-    # decode_attention_dist is available but OFF by default: measured
-    # neutral on collectives and 2-3x worse on the memory term vs XLA's
-    # native handling of the seq-sharded masked softmax (§Perf-C2).
-    if USE_DIST_DECODE and mesh is not None \
-            and mesh.shape.get("model", 1) > 1 \
-            and S % mesh.shape["model"] == 0:
-        out = decode_attention_dist(q[:, 0], cache_k, cache_v, index + 1,
-                                    window, mesh)
-    else:
-        out = decode_attention_jnp(q[:, 0], cache_k, cache_v, index + 1,
-                                   window=window)
+    out = decode_attention_jnp(q[:, 0], cache_k, cache_v, index + 1,
+                               window=window)
     out = out.reshape(B, 1, cfg.n_heads * hd).astype(x.dtype)
     return constrain(linear(p["wo"], out), "batch", "seq", "act_embed"), cache_k, cache_v
 

@@ -113,11 +113,6 @@ def _absorbed_out(p, o, cfg, dtype):
     return out.reshape(out.shape[:-2] + (H * dv,))
 
 
-def _no_pages():
-    raise NotImplementedError("latent attention serves from the paged "
-                              "latent pool only (kv='paged')")
-
-
 def mla_decode_slots(p, x, cfg, pool, indices, pages, kernel: bool):
     """One token per slot: x [B,1,d] at per-slot positions `indices` [B];
     its [c | k_pe] row is written to the pool [n_pages,1,page,r+dr]
@@ -125,7 +120,7 @@ def mla_decode_slots(p, x, cfg, pool, indices, pages, kernel: bool):
     absorbed query attends the slot's prefix. Returns (out [B,1,d],
     new pool)."""
     if pages is None:
-        _no_pages()
+        L.no_pages()
     B = x.shape[0]
     positions = indices[:, None]
     with jax.named_scope("mla"):
@@ -154,7 +149,7 @@ def mla_prefill_slots(p, x, cfg, pool, start, n_valid, pages, kernel: bool):
     n_valid[b] are masked out of the pool write. Returns (out [B,C,d],
     new pool)."""
     if pages is None:
-        _no_pages()
+        L.no_pages()
     B, C, _ = x.shape
     positions = start[:, None] + jnp.arange(C)[None]
     with jax.named_scope("mla"):

@@ -204,7 +204,7 @@ def forward(params: dict, batch: dict, cfg, window: int = 0) -> tuple:
 # ------------------------------------------------------------- decode
 def init_cache_shapes(cfg, batch_size: int, seq_len: int):
     if cfg.is_mla:
-        mla._no_pages()
+        L.no_pages()
     hd = cfg.hd
     shape = (cfg.n_layers, batch_size, cfg.n_kv_heads, seq_len, hd)
     axes = ("layers", "batch", "kv_heads", "kv_seq", None)
@@ -286,14 +286,15 @@ def _serve_layers(params, cache, x, cfg, block):
 def decode_step(params: dict, cache: dict, token: jax.Array, index: jax.Array,
                 cfg, window: int = 0, pages=None, stats: bool = False
                 ) -> tuple:
-    """token [B,1] int32; index scalar int32 (current position) or a
-    per-slot [B] vector. Returns (logits [B,1,V], new_cache), and with
-    `stats` a third element: the held-expert counters summed over the
-    layers (int32 [moe.N_STATS] for an MoE model, [0] otherwise). With
+    """token [B,1] int32; index scalar int32 (current position, over the
+    dense cache of `init_cache`) or a per-slot [B] vector, which needs
     `pages` = {"tables": [B,n_lp], "page_size": int, "active": [B] bool
-    or None} the cache leaves are the shared page pool from
-    `init_paged_cache` and writes route through each slot's page table;
-    inactive rows are left out of the expert dispatch too."""
+    or None, "kernel": bool or None}: the cache leaves are then the
+    shared page pool from `init_paged_cache`, writes route through each
+    slot's page table, and inactive rows are left out of the expert
+    dispatch too. Returns (logits [B,1,V], new_cache), and with `stats`
+    a third element: the held-expert counters summed over the layers
+    (int32 [moe.N_STATS] for an MoE model, [0] otherwise)."""
     x = L.embed_lookup(params["embed"], token, cfg.dtype)
     valid = None
     if pages is not None and pages.get("active") is not None:
@@ -312,15 +313,17 @@ def decode_step(params: dict, cache: dict, token: jax.Array, index: jax.Array,
 def prefill_step(params: dict, cache: dict, tokens: jax.Array,
                  start: jax.Array, n_valid: jax.Array, cfg,
                  window: int = 0, pages=None, stats: bool = False) -> tuple:
-    """Fused chunk prefill: tokens [B,C] — one prompt chunk per slot,
-    row b's chunk starting at cache position start[b] with n_valid[b]
-    real tokens (the rest padded tail, masked out of the cache insert
-    and the expert dispatch; a row with n_valid=0 is untouched). One
-    launch writes the chunk's cache columns in bulk and attends the
-    whole chunk, instead of C decode steps. Returns (last_logits [B,V]
-    fp32 — the logits of each row's LAST valid chunk token, exactly what
-    sampling the first generated token needs — and new_cache), and the
-    counters with `stats` (see `decode_step`)."""
+    """Fused chunk prefill over the shared page pool: tokens [B,C] — one
+    prompt chunk per slot, row b's chunk starting at cache position
+    start[b] with n_valid[b] real tokens (the rest padded tail, masked
+    out of the cache insert and the expert dispatch; a row with
+    n_valid=0 is untouched). `pages` is required, as in `decode_step`
+    with a per-slot index. One launch writes the chunk's cache columns
+    in bulk and attends the whole chunk, instead of C decode steps.
+    Returns (last_logits [B,V] fp32 — the logits of each row's LAST
+    valid chunk token, exactly what sampling the first generated token
+    needs — and new_cache), and the counters with `stats` (see
+    `decode_step`)."""
     B, C = tokens.shape
     x = L.embed_lookup(params["embed"], tokens, cfg.dtype)
 

@@ -1,24 +1,22 @@
 """Decode (serving) steps: ONE new token — or one bucketed prompt CHUNK —
-against a seq_len KV/state cache, dense or paged.
+per slot, against the family's serving cache. The family decides the
+cache kind:
 
-The chunked-prefill contract: `make_prefill_step(...)` returns
-    prefill(params, cache, tokens [B,C], start [B], n_valid [B])
+  * dense per-slot state, for a family without a pageable cache (the
+    paper classifier's O(1) recurrent state): `make_decode_step`, and
+    `make_prefill_step`, which replays the family's OWN decode_step
+    position by position inside one lax.scan, masking cache updates per
+    row — the exact prefill of any family that has a decode step;
+  * the shared page pool, for every family with
+    `ModelApi.paged_cache_shapes`: `make_paged_decode_step`, and
+    `make_paged_prefill_step`, the family's fused `prefill_step` — a
+    bulk cache insert and one chunk-attention launch per layer.
+
+The chunked-prefill contract:
+    prefill(params, cache, tokens [B,C], start [B], n_valid [B] [, tables])
         -> (last_logits [B,V] fp32, new_cache)
 where row b consumes chunk tokens 0..n_valid[b]-1 at cache positions
-start[b].. and rows with n_valid=0 are untouched. Two implementations:
-
-  * "scan"  — replays the family's OWN decode_step position-by-position
-    inside one lax.scan, masking cache updates per row. Same primitive
-    sequence as the token-by-token admission path, so cache contents and
-    last-token logits are BIT-IDENTICAL to it by construction, on any
-    backend, for every SLOT_FAMILY (including the paper classifier's
-    O(1) streaming cache — its conv tap buffer / pending pool / LSTM h,c
-    admit via this one batched scan).
-  * "fused" — the family's vectorized prefill_step (transformer
-    families): bulk KV column insert + one flash-prefill kernel launch
-    per chunk. The TPU hot path; float-tolerance (not bitwise) vs scan.
-
-"auto" resolves to fused on TPU when the family has one, scan elsewhere.
+start[b].. and rows with n_valid=0 are untouched.
 
 The paged steps take `kernel`: True routes attention through the Pallas
 paged kernels, False through the plain jnp attention, None (default) —
@@ -29,7 +27,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import resolve_interpret
 from repro.models import api as M
 from repro.runtime.train_step import window_for
 
@@ -101,17 +98,6 @@ def make_paged_decode_step(cfg, shape_cfg, page_size: int,
 
 
 # ------------------------------------------------------------- prefill
-def _resolve_prefill_impl(model, impl: str) -> str:
-    if impl == "auto":
-        impl = "fused" if (not resolve_interpret()
-                           and model.prefill_step is not None) else "scan"
-    if impl == "fused" and model.prefill_step is None:
-        raise ValueError("family has no fused prefill_step")
-    if impl not in ("scan", "fused"):
-        raise ValueError(f"unknown prefill impl {impl!r}")
-    return impl
-
-
 def _batch_mask(mask, new, old, axes):
     """Per-leaf batch-row select (the cache leaf's own axes name where
     its batch dim sits)."""
@@ -121,23 +107,18 @@ def _batch_mask(mask, new, old, axes):
     return jnp.where(mask.reshape(shape), new, old)
 
 
-def _logit_width(cfg) -> int:
+def logit_width(cfg) -> int:
+    """Width of the logits a serve step samples from: the paper
+    classifier's two labels, else the vocabulary."""
     return 2 if cfg.family == "tiny" else cfg.vocab_size
 
 
-def make_prefill_step(cfg, shape_cfg, impl: str = "auto"):
-    """Chunked prefill over a DENSE per-slot cache."""
+def make_prefill_step(cfg, shape_cfg):
+    """Chunked prefill over a DENSE per-slot cache: the exact scan of the
+    family's decode step (module docstring)."""
     model = M.get_model(cfg)
     window = window_for(cfg, shape_cfg)
-    impl = _resolve_prefill_impl(model, impl)
-    V = _logit_width(cfg)
-
-    if impl == "fused":
-        def prefill_fused(params, cache, tokens, start, n_valid):
-            return model.prefill_step(params, cache, tokens, start, n_valid,
-                                      cfg, window)
-        return prefill_fused
-
+    V = logit_width(cfg)
     shapes = model.cache_shapes(cfg, shape_cfg.global_batch,
                                 shape_cfg.seq_len)
     axes = {k: ax for k, (sh, ax, dt) in shapes.items()}
@@ -166,43 +147,19 @@ def make_prefill_step(cfg, shape_cfg, impl: str = "auto"):
 
 
 def make_paged_prefill_step(cfg, shape_cfg, page_size: int,
-                            impl: str = "auto", kernel: bool | None = None,
-                            stats: bool = False):
-    """Chunked prefill over the shared page pool; the step additionally
-    takes `tables` [B, n_lp]. Row masking happens at the pool write
-    (dropped scatters), not by batch select. With `stats` it returns the
-    counters too, as the paged decode step."""
+                            kernel: bool | None = None, stats: bool = False):
+    """Chunked prefill over the shared page pool through the family's
+    fused `prefill_step`; the step additionally takes `tables` [B, n_lp].
+    Row masking happens at the pool write (dropped scatters), not by
+    batch select. With `stats` it returns the counters too, as the paged
+    decode step."""
     model = _paged_model(cfg)
     window = window_for(cfg, shape_cfg)
-    impl = _resolve_prefill_impl(model, impl)
-    V = _logit_width(cfg)
 
-    if impl == "fused":
-        def prefill_fused(params, cache, tokens, start, n_valid, tables):
-            pages = {"tables": tables, "page_size": page_size,
-                     "active": None, "kernel": kernel}
-            return model.prefill_step(params, cache, tokens, start, n_valid,
-                                      cfg, window, pages=pages, stats=stats)
-        return prefill_fused
+    def prefill_fused(params, cache, tokens, start, n_valid, tables):
+        pages = {"tables": tables, "page_size": page_size,
+                 "active": None, "kernel": kernel}
+        return model.prefill_step(params, cache, tokens, start, n_valid,
+                                  cfg, window, pages=pages, stats=stats)
 
-    def prefill_scan(params, cache, tokens, start, n_valid, tables):
-        B, C = tokens.shape
-
-        def body(carry, i):
-            cache, lg = carry
-            tok = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=1)
-            pages = {"tables": tables, "page_size": page_size,
-                     "active": i < n_valid, "kernel": kernel}
-            logits, cache, st = model.decode_step(
-                params, cache, tok, start + i, cfg, window, pages=pages,
-                stats=True)
-            lg = jnp.where((i == n_valid - 1)[:, None],
-                           logits[:, 0].astype(jnp.float32), lg)
-            return (cache, lg), st
-
-        (cache, lg), st = jax.lax.scan(
-            body, (cache, jnp.zeros((B, V), jnp.float32)),
-            jnp.arange(C, dtype=jnp.int32))
-        return (lg, cache, st.sum(0)) if stats else (lg, cache)
-
-    return prefill_scan
+    return prefill_fused

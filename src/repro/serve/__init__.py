@@ -4,9 +4,9 @@ The serving tier of the repro stack: `RequestTrace` is the
 deterministic replay format (arrival cycles + per-user SNR),
 `ServeEngine` runs the slot-based continuous- or static-batching
 decode loop with exact Delivery billing per user — chunked bucketed
-prefill for admission and a paged shared-pool KV cache by default
-(`PagePool` owns page allocation). See docs/ARCHITECTURE.md §Serving
-and docs/ACCOUNTING.md §Serving.
+prefill for admission, and a paged shared-pool KV cache for attention
+families (`PagePool` owns page allocation). See docs/ARCHITECTURE.md
+§Serving and docs/ACCOUNTING.md §Serving.
 """
 from repro.serve.trace import (Request, RequestTrace, make_trace,
                                uniform_trace)

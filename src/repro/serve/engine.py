@@ -10,42 +10,33 @@ between requests (`mode="continuous"`). `mode="static"` is the
 classical baseline: a batch is admitted only when EVERY slot is free,
 so the whole batch drains at the pace of its slowest member.
 
-Two admission planes (`prefill=`):
+Every admitted prompt enters through bucketed prefill chunks: up to
+`chunk_size` prompt tokens per cycle in ONE launch, chunk shapes
+bucketed to powers of two (serve/paging.prefill_buckets) so distinct
+prompt lengths share executables. Time-to-first-token is
+ceil(P/chunk_size) cycles. The family decides the cache kind; no caller
+sets it:
 
-* "chunked" (default) — an admitted prompt enters through bucketed
-  prefill chunks (runtime/serve_step.make_prefill_step): up to
-  `chunk_size` prompt tokens per cycle in ONE launch, chunk shapes
-  bucketed to powers of two (serve/paging.prefill_buckets) so distinct
-  prompt lengths share executables. Time-to-first-token is
-  ceil(P/chunk_size) cycles instead of P. The default "scan"
-  implementation replays the family's own decode_step inside one
-  lax.scan, so cache contents and first-token logits are BIT-IDENTICAL
-  to the token path — including the paper classifier's O(1) streaming
-  cache (conv taps / pending pool / LSTM h,c admit via that one batched
-  scan). On a TPU, attention families take the "fused" implementation
-  instead: the vectorized bulk-insert + flash-prefill-kernel path.
-* "token" — the PR-7 path, kept bitwise: the prompt feeds through the
-  per-slot decode step one token per cycle.
+* Paged (attention families: every family with
+  `ModelApi.paged_cache_shapes`) — slot KV lives in fixed-size pages of
+  one shared pool (serve/paging.PagePool; models/transformer paged
+  cache). A request reserves ceil((P+N-1)/page_size) pages at admission
+  and frees them at completion, so memory is bounded by TOKENS IN
+  FLIGHT, not n_slots * max_len. `page_budget` caps the pool (default
+  n_slots * ceil(S/page_size) pages). Prompts admit through the
+  family's fused `prefill_step` (runtime/serve_step
+  .make_paged_prefill_step): a bulk cache insert and one chunk
+  attention launch per layer.
+* Dense (the paper classifier's O(1) recurrent state: conv taps,
+  pending pool, LSTM h,c) — per-slot state with nothing to page.
+  Prompts admit through the exact scan of the family's own decode step
+  (runtime/serve_step.make_prefill_step).
 
-Two KV layouts (`kv=`):
-
-* "paged" (default) — slot KV lives in fixed-size pages from one
-  shared pool (serve/paging.PagePool; models/transformer paged cache);
-  a request reserves ceil((P+N-1)/page_size) pages at admission and
-  frees them at completion, so memory is bounded by TOKENS IN FLIGHT,
-  not n_slots * max_len, and one long_500k-shaped request can't starve
-  short ones of cache. `page_budget` caps the pool (default: parity
-  with dense, n_slots * ceil(S/page_size) pages). The paper tiny
-  classifier's cache is O(1) recurrent state — nothing to page — so
-  `kv="paged"` silently degrades to dense for it.
-* "dense" — per-slot [B, Hkv, S, hd] cache, kept bitwise.
-
-Billing is INDEPENDENT of both switches by construction: prompt tokens
-ride the user's uplink via `Radio.send_tokens` on the same fold-4242
-ARQ stream before the first chunk runs, every radio draw is keyed only
-by (rid, leg, attempt), and sampling keys only by (rid, 9, t) — so
-bills and generated tokens are bit-for-bit across prefill/kv modes
-(docs/ACCOUNTING.md §Serving).
+Billing is independent of the cache kind by construction: prompt
+tokens ride the user's uplink via `Radio.send_tokens` on the same
+fold-4242 ARQ stream before the first chunk runs, every radio draw is
+keyed only by (rid, leg, attempt), and sampling keys only by (rid, 9,
+t) (docs/ACCOUNTING.md §Serving).
 
 Engine invariants (pinned by tests/test_serve.py):
 
@@ -99,7 +90,8 @@ import numpy as np
 from repro.configs.base import ShapeConfig
 from repro.models import api as M
 from repro.models.moe import N_STATS
-from repro.runtime.serve_step import (init_paged_cache, make_decode_step,
+from repro.runtime.serve_step import (init_paged_cache, logit_width,
+                                      make_decode_step,
                                       make_paged_decode_step,
                                       make_paged_prefill_step,
                                       make_prefill_step)
@@ -154,8 +146,7 @@ class ServeReport:
     results: Tuple[RequestResult, ...]
     cycles: int
     wall_s: float
-    prefill: str = "token"
-    kv: str = "dense"
+    kv: str = "dense"            # the cache kind that served: paged | dense
     n_pages: int = 0             # paged: pool size (0 for dense)
     peak_pages: int = 0          # paged: high-water pages in use
     #: blocking device-to-host reads the engine made (not the Radio's)
@@ -218,7 +209,7 @@ class ServeReport:
     def to_dict(self) -> dict:
         return {
             "mode": self.mode, "n_slots": self.n_slots,
-            "prefill": self.prefill, "kv": self.kv,
+            "kv": self.kv,
             "n_pages": self.n_pages, "peak_pages": self.peak_pages,
             "cycles": self.cycles, "wall_s": self.wall_s,
             "generated_tokens": self.generated_tokens,
@@ -246,10 +237,14 @@ class ServeEngine:
     (`Radio.from_wcfg(..., snr_db=...)`). `None` = ideal noiseless
     links — still billed (a perfect link is noiseless, not free).
 
-    `prefill`/`kv` pick the admission plane and the KV layout (module
-    docstring); `chunk_size` bounds prompt tokens absorbed per cycle,
-    `page_size` is the paged-KV page length in tokens, `page_budget`
-    caps the shared pool (0 = dense-parity capacity)."""
+    The family picks the cache kind (module docstring); `chunk_size`
+    bounds prompt tokens absorbed per cycle, `page_size` is the paged-KV
+    page length in tokens, `page_budget` caps the shared pool (0 =
+    n_slots * ceil(S/page_size) pages).
+
+    `prefill` and `kv` accept only their one value, "chunked" and
+    "paged", and raise on any other: they exist for callers that still
+    pass them (`benchmarks/chip/drivers/serve_waves.py`)."""
 
     def __init__(self, cfg, params, *, n_slots: int = 8,
                  radio: Optional[Radio] = None, temperature: float = 1.0,
@@ -263,10 +258,12 @@ class ServeEngine:
                 f"serving supports {SLOT_FAMILIES}")
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
-        if prefill not in ("chunked", "token"):
-            raise ValueError(f"unknown prefill mode {prefill!r}")
-        if kv not in ("paged", "dense"):
-            raise ValueError(f"unknown kv layout {kv!r}")
+        if prefill != "chunked":
+            raise ValueError(f"prefill={prefill!r}: prompts are admitted "
+                             f"only in chunks ('chunked')")
+        if kv != "paged":
+            raise ValueError(f"kv={kv!r}: the family picks the cache kind; "
+                             f"'paged' is the only value accepted")
         if chunk_size < 1:
             raise ValueError("chunk_size must be >= 1")
         if page_size < 1:
@@ -279,13 +276,12 @@ class ServeEngine:
         self.temperature = float(temperature)
         self.greedy = bool(greedy)
         self.max_link_tries = max(1, int(max_link_tries))
-        self.prefill = prefill
-        # recurrent O(1) caches have nothing to page — degrade to dense
-        self.kv = kv if cfg.family in PAGED_FAMILIES else "dense"
+        # recurrent O(1) caches have nothing to page: they stay dense
+        self.kv = "paged" if cfg.family in PAGED_FAMILIES else "dense"
         self.chunk_size = int(chunk_size)
         self.page_size = int(page_size)
         self.page_budget = int(page_budget)
-        self.out_vocab = 2 if cfg.family == "tiny" else cfg.vocab_size
+        self.out_vocab = logit_width(cfg)
         self._model = M.get_model(cfg)
         self._compiled = {}      # max_len -> dict of jitted entry points
 
@@ -336,19 +332,18 @@ class ServeEngine:
             out["decode"] = step_sample
             out["zero_pages"] = zero_pages
 
-            if self.prefill == "chunked":
-                pf = make_paged_prefill_step(cfg, sc, self.page_size,
-                                             stats=True)
+            pf = make_paged_prefill_step(cfg, sc, self.page_size,
+                                         stats=True)
 
-                @partial(jax.jit, static_argnames=("greedy",))
-                def prefill_sample(params, cache, tokens, start, n_valid,
-                                   tables, base, ids, temperature, greedy):
-                    lg, cache, st = pf(params, cache, tokens, start, n_valid,
-                                       tables)
-                    nxt = sample(lg, base, ids, temperature, greedy)
-                    return with_counters(nxt, st), cache
+            @partial(jax.jit, static_argnames=("greedy",))
+            def prefill_sample(params, cache, tokens, start, n_valid,
+                               tables, base, ids, temperature, greedy):
+                lg, cache, st = pf(params, cache, tokens, start, n_valid,
+                                   tables)
+                nxt = sample(lg, base, ids, temperature, greedy)
+                return with_counters(nxt, st), cache
 
-                out["prefill_sample"] = prefill_sample
+            out["prefill_sample"] = prefill_sample
         else:
             step = make_decode_step(cfg, sc)
             axes = {k: ax for k, (sh, ax, dt) in
@@ -383,17 +378,16 @@ class ServeEngine:
             out["decode"] = step_sample
             out["reset"] = reset_slot
 
-            if self.prefill == "chunked":
-                pf = make_prefill_step(cfg, sc)
+            pf = make_prefill_step(cfg, sc)
 
-                @partial(jax.jit, static_argnames=("greedy",))
-                def prefill_sample(params, cache, tokens, start, n_valid,
-                                   base, ids, temperature, greedy):
-                    lg, cache = pf(params, cache, tokens, start, n_valid)
-                    nxt = sample(lg, base, ids, temperature, greedy)
-                    return nxt.astype(jnp.int32), cache
+            @partial(jax.jit, static_argnames=("greedy",))
+            def prefill_sample(params, cache, tokens, start, n_valid,
+                               base, ids, temperature, greedy):
+                lg, cache = pf(params, cache, tokens, start, n_valid)
+                nxt = sample(lg, base, ids, temperature, greedy)
+                return nxt.astype(jnp.int32), cache
 
-                out["prefill_sample"] = prefill_sample
+            out["prefill_sample"] = prefill_sample
 
         self._compiled[S] = out
         return out
@@ -415,9 +409,8 @@ class ServeEngine:
     def lower(self, max_seq_len: int) -> dict:
         """Lower, on abstract inputs, every jitted entry point the serve
         loop will hit for `max_seq_len`: {"decode": the batched
-        decode-sample step, "zero_pages" (paged KV), and (chunked mode)
-        "prefill_<C>": one prefill-sample program per power-of-two
-        bucket C}."""
+        decode-sample step, "zero_pages" (paged KV), and "prefill_<C>":
+        one prefill-sample program per power-of-two bucket C}."""
         S = max(8, int(max_seq_len))
         built = self._build(S)
         cfg, B = self.cfg, self.n_slots
@@ -451,18 +444,17 @@ class ServeEngine:
             lowered["decode"] = built["decode"].lower(
                 params_sds, cache_sds, tok, idx, base, ids, act, temp,
                 greedy=self.greedy)
-        if "prefill_sample" in built:
-            for C in built["buckets"]:
-                toks = jax.ShapeDtypeStruct((B, C), i32)
-                nv = jax.ShapeDtypeStruct((B,), i32)
-                if paged:
-                    lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
-                        params_sds, cache_sds, toks, idx, nv, tbl, base,
-                        ids, temp, greedy=self.greedy)
-                else:
-                    lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
-                        params_sds, cache_sds, toks, idx, nv, base, ids,
-                        temp, greedy=self.greedy)
+        for C in built["buckets"]:
+            toks = jax.ShapeDtypeStruct((B, C), i32)
+            nv = jax.ShapeDtypeStruct((B,), i32)
+            if paged:
+                lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
+                    params_sds, cache_sds, toks, idx, nv, tbl, base, ids,
+                    temp, greedy=self.greedy)
+            else:
+                lowered[f"prefill_{C}"] = built["prefill_sample"].lower(
+                    params_sds, cache_sds, toks, idx, nv, base, ids, temp,
+                    greedy=self.greedy)
         return lowered
 
     # ------------------------------------------------------------- radio
@@ -502,11 +494,9 @@ class ServeEngine:
         cfg, B = self.cfg, self.n_slots
         reqs = trace.sorted()
         if not reqs:
-            return ServeReport(mode, B, (), 0, 0.0, prefill=self.prefill,
-                               kv=self.kv)
+            return ServeReport(mode, B, (), 0, 0.0, kv=self.kv)
         S = max(8, trace.max_seq_len())
         built = self._build(S)
-        chunked = self.prefill == "chunked"
         paged = self.kv == "paged"
         base = jax.random.PRNGKey(trace.seed + SERVE_STREAM)
 
@@ -651,11 +641,9 @@ class ServeEngine:
 
                 tables_j = jnp.asarray(tables) if paged else None
                 pre = [b for b, st in enumerate(slots)
-                       if st is not None and chunked
-                       and st["pos"] < st["r"].prompt_len]
+                       if st is not None and st["pos"] < st["r"].prompt_len]
                 dec = [b for b, st in enumerate(slots)
-                       if st is not None and not (
-                           chunked and st["pos"] < st["r"].prompt_len)]
+                       if st is not None and st["pos"] >= st["r"].prompt_len]
 
                 # ---- bucketed prefill chunks over the prefilling slots
                 if pre:
@@ -708,14 +696,10 @@ class ServeEngine:
                     rows = []
                     for b in dec:
                         st = slots[b]
-                        P = st["r"].prompt_len
-                        toks[b, 0] = st["prompt"][st["pos"]] \
-                            if st["pos"] < P else st["last"]
+                        toks[b, 0] = st["last"]
                         idx[b] = st["pos"]
                         active[b] = True
-                        t = st["pos"] - (P - 1)
-                        if t >= 0:
-                            rows.append((b, t))
+                        rows.append((b, len(st["new"])))
                     ids = sample_ids(rows)
                     if paged:
                         nxt, cache = built["decode"](
@@ -735,10 +719,7 @@ class ServeEngine:
                     counters[:len(nxt) - B] += nxt[B:]
                     for b in dec:
                         st = slots[b]
-                        if st is None:
-                            continue
-                        if st["pos"] >= st["r"].prompt_len - 1:
-                            push_token(st, int(nxt[b]))
+                        push_token(st, int(nxt[b]))
                         st["pos"] += 1
                         if len(st["new"]) >= st["r"].max_new_tokens:
                             complete(st)
@@ -748,7 +729,7 @@ class ServeEngine:
         wall = time.perf_counter() - t0
         ordered = tuple(results[r.rid] for r in reqs)
         return ServeReport(mode, B, ordered, cycle, wall,
-                           prefill=self.prefill, kv=self.kv,
+                           kv=self.kv,
                            n_pages=built.get("n_pages", 0) if paged else 0,
                            peak_pages=pool.peak_pages if paged else 0,
                            host_syncs=syncs,

@@ -19,28 +19,6 @@ import numpy as np
 from repro.launch.mesh import make_mesh
 
 
-def check_decode_attention_dist():
-    """Sharded flash-decode == single-device reference."""
-    from repro.models.layers import decode_attention_jnp, \
-        decode_attention_dist
-    mesh = make_mesh((2, 4), ("data", "model"))
-    key = jax.random.PRNGKey(0)
-    kq, kk, kv = jax.random.split(key, 3)
-    B, Hkv, G, S, hd = 2, 4, 2, 64, 16
-    q = jax.random.normal(kq, (B, Hkv * G, hd), jnp.float32)
-    kc = jax.random.normal(kk, (B, Hkv, S, hd), jnp.float32)
-    vc = jax.random.normal(kv, (B, Hkv, S, hd), jnp.float32)
-    for length, window in ((50, 0), (50, 16), (3, 32), (64, 0)):
-        ref = decode_attention_jnp(q, kc, vc, jnp.int32(length),
-                                   window=window)
-        with mesh:
-            out = jax.jit(lambda q, k, v: decode_attention_dist(
-                q, k, v, jnp.int32(length), window, mesh))(q, kc, vc)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-    print("OK decode_attention_dist")
-
-
 def check_moe_ep():
     """Expert-parallel shard_map MoE == chunked single-device MoE."""
     from repro.configs import get_arch
@@ -223,7 +201,6 @@ def check_chip_smoke_pod():
 
 
 CHECKS = {
-    "decode_attention_dist": check_decode_attention_dist,
     "moe_ep": check_moe_ep,
     "train_step_sharded": check_train_step_sharded,
     "fl_pod_step": check_fl_pod_step,

@@ -19,8 +19,8 @@ def run_check(name: str):
     assert f"OK {name}" in res.stdout
 
 
-@pytest.mark.parametrize("name", ["decode_attention_dist", "moe_ep",
-                                  "train_step_sharded", "fl_pod_step",
-                                  "fleet_pod", "chip_smoke_pod"])
+@pytest.mark.parametrize("name", ["moe_ep", "train_step_sharded",
+                                  "fl_pod_step", "fleet_pod",
+                                  "chip_smoke_pod"])
 def test_distributed(name):
     run_check(name)

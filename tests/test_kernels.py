@@ -141,6 +141,17 @@ def test_lstm_layer_matches_model():
 
 
 # --------------------------------------------------------- decode_attention
+# the dense [B, Hkv, S, hd] caches of these tests are scattered into a
+# shared page pool (`_paged_from_dense`) and attended through the paged
+# kernel, against the references on the dense cache
+PAGE = 16
+
+
+def _gqa_decode_paged(q, k, v, length, **kw):
+    kp, vp, tables = _paged_from_dense(k, v, PAGE)
+    return da_ops.gqa_decode_paged(q, kp, vp, tables, length, **kw)
+
+
 @HS
 @given(b=st.sampled_from([1, 2]),
        hkv=st.sampled_from([1, 2, 8]),
@@ -156,7 +167,7 @@ def test_decode_attention_matches_ref(b, hkv, g, s, hd, seed):
     k = jax.random.normal(kk, (b, hkv, s, hd), jnp.float32)
     v = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
     length = jax.random.randint(kl, (), 1, s)
-    out = da_ops.gqa_decode(q, k, v, length, interpret=True)
+    out = _gqa_decode_paged(q, k, v, length, interpret=True)
     ref = decode_attention_ref(q.reshape(b, hkv, g, hd), k, v, length)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.reshape(b, H, hd)),
@@ -172,7 +183,7 @@ def test_decode_attention_sliding_window(window):
     k = jax.random.normal(kk, (b, hkv, s, hd), jnp.float32)
     v = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
     length = jnp.int32(200)
-    out = da_ops.gqa_decode(q, k, v, length, window=window)
+    out = _gqa_decode_paged(q, k, v, length, window=window)
     ref = decode_attention_ref(q.reshape(b, hkv, g, hd), k, v, length,
                                window=window)
     np.testing.assert_allclose(np.asarray(out),
@@ -223,10 +234,10 @@ def test_decode_attention_masks_future():
     k = jax.random.normal(kk, (b, hkv, s, hd), jnp.float32)
     v = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
     length = jnp.int32(64)
-    out1 = da_ops.gqa_decode(q, k, v, length)
+    out1 = _gqa_decode_paged(q, k, v, length)
     k2 = k.at[:, :, 64:].set(1e9)
     v2 = v.at[:, :, 64:].set(-1e9)
-    out2 = da_ops.gqa_decode(q, k2, v2, length)
+    out2 = _gqa_decode_paged(q, k2, v2, length)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-5, atol=1e-5)
 
@@ -243,7 +254,9 @@ def test_decode_attention_per_slot_lengths():
     k = jax.random.normal(kk, (b, hkv, s, hd), jnp.float32)
     v = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
     lengths = jnp.array([1, 7, 16, 33, 64, 100, 127, 128], jnp.int32)
-    out = da_ops.gqa_decode(q, k, v, lengths, interpret=True)
+    kp, vp, tables = _paged_from_dense(k, v, PAGE)
+    out = da_ops.gqa_decode_paged(q, kp, vp, tables, lengths,
+                                  interpret=True)
     ref = decode_attention_ref(q.reshape(b, hkv, g, hd), k, v, lengths)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.reshape(b, hkv * g, hd)),
@@ -253,8 +266,8 @@ def test_decode_attention_per_slot_lengths():
                                rtol=2e-4, atol=2e-4)
     # row independence: each slot's output equals its own scalar run
     for i in range(b):
-        one = da_ops.gqa_decode(q[i:i + 1], k[i:i + 1], v[i:i + 1],
-                                lengths[i], interpret=True)
+        one = da_ops.gqa_decode_paged(q[i:i + 1], kp, vp, tables[i:i + 1],
+                                      lengths[i], interpret=True)
         np.testing.assert_allclose(np.asarray(out[i:i + 1]),
                                    np.asarray(one), rtol=1e-6, atol=1e-6)
 
@@ -306,7 +319,8 @@ def test_prefill_kernel_matches_jnp_at_engine_buckets(c):
     from repro.kernels.prefill_attention import ops as pf_ops
     from repro.models.layers import prefill_attention_jnp
     q, kc, vc, start = _prefill_fixture(31, c=c)
-    out = pf_ops.gqa_prefill(q, kc, vc, start, interpret=True)
+    kp, vp, tables = _paged_from_dense(kc, vc, PAGE)
+    out = pf_ops.gqa_prefill_paged(q, kp, vp, tables, start, interpret=True)
     ref = prefill_attention_jnp(q, kc, vc, start)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -316,7 +330,8 @@ def test_prefill_kernel_matches_jnp_at_engine_buckets(c):
 def test_prefill_kernel_layout_matches_ref(window):
     """Kernel-layout entry point vs its own ref.py oracle, with and
     without the sliding window."""
-    from repro.kernels.prefill_attention.kernel import prefill_attention
+    from repro.kernels.prefill_attention.kernel import \
+        paged_prefill_attention
     from repro.kernels.prefill_attention.ref import prefill_attention_ref
     b, hkv, g, s, hd, c = 4, 2, 8, 128, 128, 8
     key = jax.random.PRNGKey(3)
@@ -325,8 +340,9 @@ def test_prefill_kernel_layout_matches_ref(window):
     kc = jax.random.normal(kk, (b, hkv, s, hd), jnp.float32)
     vc = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
     start = jnp.array([0, 5, 32, 77], jnp.int32)
-    out = prefill_attention(q, kc, vc, start, g, window=window,
-                            interpret=True)
+    kp, vp, tables = _paged_from_dense(kc, vc, PAGE)
+    out = paged_prefill_attention(q, kp, vp, tables, start, g,
+                                  window=window, interpret=True)
     ref = prefill_attention_ref(q, kc, vc, start, g, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -338,13 +354,18 @@ def test_prefill_kernel_is_causal():
     own future columns — leaves the output unchanged."""
     from repro.kernels.prefill_attention import ops as pf_ops
     q, kc, vc, start = _prefill_fixture(17, c=8)
-    out1 = pf_ops.gqa_prefill(q, kc, vc, start, interpret=True)
+
+    def gqa_prefill_paged(kc, vc):
+        kp, vp, tables = _paged_from_dense(kc, vc, PAGE)
+        return pf_ops.gqa_prefill_paged(q, kp, vp, tables, start,
+                                        interpret=True)
+
+    out1 = gqa_prefill_paged(kc, vc)
     kc2, vc2 = np.asarray(kc).copy(), np.asarray(vc).copy()
     for b, st in enumerate(np.asarray(start)):
         kc2[b, :, st + 8:] = 1e9
         vc2[b, :, st + 8:] = -1e9
-    out2 = pf_ops.gqa_prefill(q, jnp.asarray(kc2), jnp.asarray(vc2),
-                              start, interpret=True)
+    out2 = gqa_prefill_paged(kc2, vc2)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-5, atol=1e-5)
     # and token 0 of each chunk only sees columns <= start: poisoning
@@ -353,8 +374,7 @@ def test_prefill_kernel_is_causal():
     for b, st in enumerate(np.asarray(start)):
         kc3[b, :, st + 1:] = 1e9
         vc3[b, :, st + 1:] = -1e9
-    out3 = pf_ops.gqa_prefill(q, jnp.asarray(kc3), jnp.asarray(vc3),
-                              start, interpret=True)
+    out3 = gqa_prefill_paged(kc3, vc3)
     np.testing.assert_allclose(np.asarray(out1)[:, 0],
                                np.asarray(out3)[:, 0],
                                rtol=1e-5, atol=1e-5)

@@ -32,17 +32,29 @@ HARSH = Radio(snr_db=5.0, fading=True, arq_max_tx=1, arq_attempts=1,
 @pytest.mark.parametrize("cfg,tol", [(TINY, 1e-6), (QWEN, 2e-4)],
                          ids=["paper-tinylstm", "qwen1.5-0.5b-reduced"])
 def test_decode_matches_teacher_forced_prefill(cfg, tol):
-    """Per-slot decode over the serving cache reproduces the batch
-    forward pass: every decode-step logit equals the teacher-forced
-    logit at that position (the KV cache holds exactly the right
-    keys/values). Slots run at DIFFERENT depths via the vector index."""
+    """Per-slot decode over the serving cache (the classifier's dense
+    state; the transformer's paged pool, identity page tables)
+    reproduces the batch forward pass: every decode-step logit equals
+    the teacher-forced logit at that position (the cache holds exactly
+    the right keys/values). Slots run at DIFFERENT depths via the
+    vector index."""
+    from repro.runtime.serve_step import init_paged_cache
     model = M.get_model(cfg)
     params = params_for(cfg)
     B, S = 4, 12
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 1,
                                 cfg.vocab_size, jnp.int32)
     ref, _ = model.forward(params, {"tokens": tokens}, cfg, 0)
-    cache = model.init_cache(cfg, B, S)
+    kw = {}
+    if cfg.family == "tiny":
+        cache = model.init_cache(cfg, B, S)
+    else:
+        page = 4
+        n_lp = S // page
+        cache = init_paged_cache(cfg, B * n_lp, page)
+        kw["pages"] = {"tables": jnp.arange(B * n_lp, dtype=jnp.int32)
+                       .reshape(B, n_lp), "page_size": page,
+                       "active": None, "kernel": None}
     # stagger the slots: slot b starts b steps late, so the batched
     # step always carries a genuine per-slot index vector
     offs = np.arange(B) % 3
@@ -54,7 +66,7 @@ def test_decode_matches_teacher_forced_prefill(cfg, tol):
         tk = np.array([tokens[b, min(max(pos[b], 0), S - 1)]
                        for b in range(B)], np.int32)[:, None]
         logits, cache = model.decode_step(params, cache, jnp.asarray(tk),
-                                          jnp.asarray(idx), cfg, 0)
+                                          jnp.asarray(idx), cfg, 0, **kw)
         lg = np.asarray(logits, np.float32)
         for b in range(B):
             if 0 <= pos[b] < S:
@@ -212,7 +224,7 @@ def test_engine_rejects_scalar_families():
 
 def test_transformer_engine_e2e():
     """The reduced transformer serves a mixed trace end-to-end through
-    the SAME engine loop (per-slot KV cache + decode_attention path)."""
+    the SAME engine loop (paged KV pool, fused chunk prefill)."""
     params = params_for(QWEN)
     eng = ServeEngine(QWEN, params, n_slots=4,
                       radio=Radio(snr_db=10.0, fading=True))
@@ -227,10 +239,6 @@ def test_transformer_engine_e2e():
 
 
 # --------------------------------------- chunked prefill + paged KV
-MODES = [("token", "dense"), ("chunked", "dense"),
-         ("chunked", "paged"), ("token", "paged")]
-
-
 def _staggered_trace():
     """Mixed trace exercising every prefill bucket: prompts shorter than
     the bucket floor, longer than one chunk, arrivals staggered so
@@ -248,75 +256,69 @@ def _bill_rows(rep):
              r.uplink_bits, r.downlink_bits) for r in rep.results]
 
 
-@pytest.mark.parametrize("cfg", [TINY, QWEN],
-                         ids=["paper-tinylstm", "qwen1.5-0.5b-reduced"])
-def test_prefill_kv_modes_bitwise_equal(cfg):
-    """Every (prefill, kv) combination generates BIT-IDENTICAL tokens,
-    statuses, and radio bills on the same trace — chunked admission and
-    the paged pool are pure scheduling/layout changes (ISSUE 10's core
-    acceptance). The ARQ link is lossy so the bills are non-trivial."""
-    params = params_for(cfg)
-    trace = _staggered_trace()
-    radio = Radio(snr_db=10.0, fading=True, arq_max_tx=6, arq_attempts=2)
-    reps = {}
-    for pf, kv in MODES:
-        eng = ServeEngine(cfg, params, n_slots=3, radio=radio,
-                          temperature=0.8, prefill=pf, kv=kv,
-                          chunk_size=16, page_size=8)
-        reps[(pf, kv)] = eng.serve(trace)
-    ref = reps[("token", "dense")]
-    for mode, rep in reps.items():
-        assert [(r.rid, r.tokens) for r in rep.results] == \
-               [(r.rid, r.tokens) for r in ref.results], mode
-        assert _bill_rows(rep) == _bill_rows(ref), mode
-    # chunked admission finishes the same work in strictly fewer cycles
-    assert reps[("chunked", "paged")].cycles < ref.cycles
-    # paged degrades to dense for the O(1) recurrent classifier
-    expect_kv = "dense" if cfg.family == "tiny" else "paged"
-    assert reps[("chunked", "paged")].kv == expect_kv
+#: fused chunk prefill vs the same chunk fed through decode steps, on
+#: the reduced transformer in float32: one bulk insert and one chunk
+#: attention sum in another order than C single-token steps, so the two
+#: agree to float32 rounding, not bitwise
+FUSED_TOL = 1e-5
 
 
 @pytest.mark.parametrize("cfg", [TINY, QWEN],
                          ids=["paper-tinylstm", "qwen1.5-0.5b-reduced"])
 def test_prefill_scan_bitwise_matches_token_steps(cfg):
-    """Runtime-level pin of the bit-parity contract: make_prefill_step's
-    scan produces a cache AND last-valid-token logits bitwise equal to
-    feeding the same chunk through decode_step one position at a time
-    with the engine's per-row active masking (staggered starts and
-    ragged n_valid, so the masking genuinely matters)."""
+    """Runtime-level pin of each cache kind's prefill: the same chunk
+    fed through the family's decode step one position at a time, with
+    the engine's per-row active masking (staggered starts and ragged
+    n_valid, so the masking genuinely matters), gives the same cache and
+    last-valid-token logits. The classifier's dense scan is BITWISE the
+    token steps (same primitive sequence); the transformer's paged fused
+    prefill agrees with paged decode steps to FUSED_TOL, and a row with
+    n_valid=0 writes nothing."""
     from repro.configs.base import ShapeConfig
-    from repro.runtime.serve_step import make_prefill_step
+    from repro.runtime import serve_step as SS
     model = M.get_model(cfg)
     params = params_for(cfg)
-    B, S, C = 4, 32, 8
+    B, S, C, page = 4, 32, 8, 8
     sc = ShapeConfig("serve", S, B, "decode")
     tokens = jax.random.randint(jax.random.PRNGKey(5), (B, C), 1,
                                 cfg.vocab_size, jnp.int32)
     start = jnp.array([0, 3, 9, 17], jnp.int32)
     n_valid = jnp.array([8, 1, 0, 5], jnp.int32)
-    cache0 = model.init_cache(cfg, B, S)
+    V = SS.logit_width(cfg)
 
-    prefill = jax.jit(make_prefill_step(cfg, sc))
-    lg_scan, cache_scan = prefill(params, cache0, tokens, start, n_valid)
+    if cfg.family == "tiny":
+        shapes = model.cache_shapes(cfg, B, S)
+        axes = {k: ax for k, (sh, ax, dt) in shapes.items()}
+        cache0 = model.init_cache(cfg, B, S)
+        lg_pf, cache_pf = jax.jit(SS.make_prefill_step(cfg, sc))(
+            params, cache0, tokens, start, n_valid)
 
-    shapes = model.cache_shapes(cfg, B, S)
-    axes = {k: ax for k, (sh, ax, dt) in shapes.items()}
-    V = 2 if cfg.family == "tiny" else cfg.vocab_size
+        # the token path exactly as a batched masked decode runs it:
+        # ONE jitted step (same primitive sequence as the scan body)
+        @jax.jit
+        def token_step(cache, tok, idx, sel):
+            logits, new_cache = model.decode_step(params, cache, tok, idx,
+                                                  cfg, 0)
 
-    # the token path exactly as the engine runs it: ONE jitted masked
-    # step (same primitive sequence as the scan body), driven from host
-    @jax.jit
-    def token_step(cache, tok, idx, sel):
-        logits, new_cache = model.decode_step(params, cache, tok, idx,
-                                              cfg, 0)
-        def pick(new, old, ax):
-            j = list(ax).index("batch")
-            m = sel.reshape([-1 if d == j else 1
-                             for d in range(new.ndim)])
-            return jnp.where(m, new, old)
-        cache = {k: pick(new_cache[k], cache[k], axes[k])
-                 for k in new_cache}
-        return logits[:, 0].astype(jnp.float32), cache
+            def pick(new, old, ax):
+                j = list(ax).index("batch")
+                m = sel.reshape([-1 if d == j else 1
+                                 for d in range(new.ndim)])
+                return jnp.where(m, new, old)
+            cache = {k: pick(new_cache[k], cache[k], axes[k])
+                     for k in new_cache}
+            return logits[:, 0].astype(jnp.float32), cache
+    else:
+        n_lp = S // page
+        tables = jnp.arange(B * n_lp, dtype=jnp.int32).reshape(B, n_lp)
+        cache0 = SS.init_paged_cache(cfg, B * n_lp, page)
+        lg_pf, cache_pf = jax.jit(SS.make_paged_prefill_step(cfg, sc, page))(
+            params, cache0, tokens, start, n_valid, tables)
+        decode = jax.jit(SS.make_paged_decode_step(cfg, sc, page))
+
+        def token_step(cache, tok, idx, sel):
+            logits, cache = decode(params, cache, tok, idx, tables, sel)
+            return logits[:, 0].astype(jnp.float32), cache
 
     cache = cache0
     lg = np.zeros((B, V), np.float32)
@@ -326,10 +328,21 @@ def test_prefill_scan_bitwise_matches_token_steps(cfg):
                                 start + jnp.int32(i), sel)
         take = i == np.asarray(n_valid) - 1
         lg[take] = np.asarray(row)[take]
+    if cfg.family == "tiny":
+        for k in cache:
+            np.testing.assert_array_equal(np.asarray(cache_pf[k]),
+                                          np.asarray(cache[k]), err_msg=k)
+        np.testing.assert_array_equal(np.asarray(lg_pf), lg)
+        return
     for k in cache:
-        np.testing.assert_array_equal(np.asarray(cache_scan[k]),
-                                      np.asarray(cache[k]), err_msg=k)
-    np.testing.assert_array_equal(np.asarray(lg_scan), lg)
+        np.testing.assert_allclose(np.asarray(cache_pf[k]),
+                                   np.asarray(cache[k]), rtol=FUSED_TOL,
+                                   atol=FUSED_TOL, err_msg=k)
+        idle = np.asarray(cache_pf[k])[:, 2 * n_lp:3 * n_lp]  # row 2's pages
+        assert not idle.any(), k
+    live = np.asarray(n_valid) > 0
+    np.testing.assert_allclose(np.asarray(lg_pf)[live], lg[live],
+                               rtol=FUSED_TOL, atol=FUSED_TOL)
 
 
 def test_paged_page_reuse_no_stale_cache():
@@ -355,21 +368,23 @@ def test_paged_capacity_bounded_by_tokens_not_slots():
     """The pool admits by TOKENS IN FLIGHT: a budget far below
     n_slots * ceil(S/page) still serves the whole trace (admission
     blocks FIFO until completions free pages), and a long request never
-    deadlocks the queue. Tokens stay bit-identical to the dense run."""
+    deadlocks the queue. Tokens stay bit-identical to the run on the
+    default-budget pool, where nothing waits for pages."""
     params = params_for(QWEN)
     reqs = (Request(0, 0, 40, 8),) + tuple(
         Request(rid, 0, 4, 3) for rid in range(1, 7))
     trace = RequestTrace(13, reqs)
-    dense = ServeEngine(QWEN, params, n_slots=4, kv="dense",
+    roomy = ServeEngine(QWEN, params, n_slots=4, page_size=4,
                         chunk_size=8).serve(trace)
-    # dense-parity capacity would be 4 * ceil(47/4) = 48 pages; 16 is
-    # enough for the long request (12 pages) plus one short at a time
+    # the default budget is 4 * ceil(47/4) = 48 pages; 16 is enough for
+    # the long request (12 pages) plus one short at a time
     paged = ServeEngine(QWEN, params, n_slots=4, kv="paged", page_size=4,
                         page_budget=16, chunk_size=8).serve(trace)
+    assert roomy.n_pages == 48
     assert [r.tokens for r in paged.results] == \
-           [r.tokens for r in dense.results]
+           [r.tokens for r in roomy.results]
     assert all(r.status == "ok" for r in paged.results)
-    assert paged.peak_pages <= 16
+    assert paged.peak_pages <= 16 < roomy.peak_pages
     assert paged.n_pages == 16
 
 
@@ -383,57 +398,76 @@ def test_paged_rejects_never_fitting_request():
 
 def test_chunked_ttft_beats_token_and_is_recorded():
     """Long prompts: chunked admission reaches the first token in
-    ceil(P/chunk) cycles instead of P — TTFT must drop at the recorded
-    per-request level, and the report quantiles must be populated."""
+    ceil(P/chunk) cycles, not P — TTFT is recorded per request at that
+    level, and the report quantiles are populated."""
     params = params_for(TINY)
     trace = RequestTrace(3, tuple(Request(rid, 0, 64, 4)
                                   for rid in range(4)))
-    tok = ServeEngine(TINY, params, n_slots=4,
-                      prefill="token").serve(trace)
-    chk = ServeEngine(TINY, params, n_slots=4, prefill="chunked",
+    chk = ServeEngine(TINY, params, n_slots=4,
                       chunk_size=16).serve(trace)
-    for r in chk.results + tok.results:
-        assert r.first_token_cycle >= 0
-        assert r.ttft_cycles >= 1 and r.ttft_s >= 0.0
-    assert chk.ttft_quantile(0.99) < tok.ttft_quantile(0.99)
-    assert chk.ttft_quantile(0.5) <= 64 // 16 + 1
-    assert [r.tokens for r in chk.results] == \
-           [r.tokens for r in tok.results]
+    for r in chk.results:
+        assert r.first_token_cycle >= 0 and r.ttft_s >= 0.0
+        assert 1 <= r.ttft_cycles <= -(-r.prompt_len // 16) + 1
     d = chk.to_dict()
     assert d["p50_ttft_cycles"] == chk.ttft_quantile(0.5)
     assert d["p99_ttft_s"] >= 0.0
 
 
-@pytest.mark.parametrize("prefill", ["chunked", "token"])
-def test_replay_deterministic_and_billing_exact_per_prefill(prefill):
+@pytest.mark.parametrize("cfg", [TINY, QWEN],
+                         ids=["paper-tinylstm", "qwen1.5-0.5b-reduced"])
+def test_replay_deterministic_and_billing_exact_per_prefill(cfg):
     """Replay determinism and the exact-billing identity hold under
-    BOTH admission planes, on a harsh ARQ link with real abandonments —
-    and the two planes' bills agree request for request."""
-    params = params_for(TINY)
+    BOTH cache kinds' prefills (the classifier's dense scan, the
+    transformer's paged fused chunks), on a harsh ARQ link with real
+    abandonments — and the static schedule's bills agree with the
+    continuous one's request for request."""
+    params = params_for(cfg)
     tr = make_trace(3, 12, prompt_lens=(3, 40), new_tokens=(2, 4),
                     snr_dbs=(5.0,))
-    eng = ServeEngine(TINY, params, n_slots=4, radio=HARSH,
-                      max_link_tries=2, prefill=prefill)
+    eng = ServeEngine(cfg, params, n_slots=4, radio=HARSH,
+                      max_link_tries=2)
     a, b = eng.serve(tr), eng.serve(tr)
     assert [r.tokens for r in a.results] == [r.tokens for r in b.results]
     assert _bill_rows(a) == _bill_rows(b)
+    assert any(r.status == "uplink_erased" for r in a.results)
     for r in a.results:
         assert (r.bits - r.erased_bits) + r.erased_bits == r.bits
         if r.status == "uplink_erased":
             assert r.tokens == () and r.erased_bits > 0
-    other = ServeEngine(TINY, params, n_slots=4, radio=HARSH,
-                        max_link_tries=2,
-                        prefill="token" if prefill == "chunked"
-                        else "chunked")
-    assert _bill_rows(other.serve(tr)) == _bill_rows(a)
+    assert _bill_rows(eng.serve(tr, "static")) == _bill_rows(a)
 
 
 def test_engine_validates_prefill_kv_flags():
+    """`prefill` and `kv` take only "chunked" and "paged"; the family,
+    not the caller, decides the cache kind."""
     params = params_for(TINY)
-    with pytest.raises(ValueError, match="prefill"):
-        ServeEngine(TINY, params, prefill="speculative")
-    with pytest.raises(ValueError, match="kv"):
-        ServeEngine(TINY, params, kv="compressed")
+    for bad in ("speculative", "token"):
+        with pytest.raises(ValueError, match="prefill"):
+            ServeEngine(TINY, params, prefill=bad)
+    for bad in ("compressed", "dense"):
+        with pytest.raises(ValueError, match="kv"):
+            ServeEngine(TINY, params, kv=bad)
+    assert ServeEngine(TINY, params, kv="paged").kv == "dense"
+    assert ServeEngine(QWEN, params_for(QWEN)).kv == "paged"
+
+
+def test_per_slot_attention_needs_pages():
+    """Per-slot (vector-index) GQA decode and chunk prefill serve from
+    the paged pool only: without `pages` they raise, as latent attention
+    does; the scalar-index decode over the dense cache still runs."""
+    model = M.get_model(QWEN)
+    params = params_for(QWEN)
+    B, S = 2, 8
+    cache = model.init_cache(QWEN, B, S)
+    tok = jnp.ones((B, 1), jnp.int32)
+    with pytest.raises(NotImplementedError, match="paged"):
+        model.decode_step(params, cache, tok, jnp.zeros(B, jnp.int32), QWEN)
+    with pytest.raises(NotImplementedError, match="paged"):
+        model.prefill_step(params, cache, jnp.ones((B, 4), jnp.int32),
+                           jnp.zeros(B, jnp.int32),
+                           jnp.full((B,), 4, jnp.int32), QWEN)
+    logits, _ = model.decode_step(params, cache, tok, jnp.int32(0), QWEN)
+    assert logits.shape == (B, 1, QWEN.vocab_size)
 
 
 def test_page_pool_deterministic_alloc_and_guards():
@@ -552,8 +586,7 @@ def test_sample_keys_match_the_host_schedule():
 #: tokens of `_staggered_trace()` at T = 0.8 (chunk 16, pages of 8, a
 #: perfect link), served by the engine as it was when the host derived
 #: each sampling key eagerly (`fold_in`, then a read-back) and passed
-#: the keys into the step programs; on CPU, the same for every
-#: (prefill, kv) mode.
+#: the keys into the step programs; on CPU.
 SAMPLED_GOLDEN = {
     "qwen1.5-0.5b-reduced":
         [[512, 609, 907, 320, 832, 897],
@@ -566,31 +599,32 @@ SAMPLED_GOLDEN = {
 }
 
 
-@pytest.mark.parametrize("cfg,kv", [(QWEN, "paged"), (QWEN, "dense"),
-                                    (TINY, "dense")],
+@pytest.mark.parametrize("cfg,kv", [(QWEN, "paged"), (TINY, "dense")],
                          ids=["qwen1.5-0.5b-reduced-paged",
-                              "qwen1.5-0.5b-reduced-dense",
                               "paper-tinylstm-dense"])
 def test_sampled_tokens_match_the_host_key_goldens(cfg, kv):
     """Keys derived inside the step programs sample the very tokens the
     host-derived keys sampled (`SAMPLED_GOLDEN`, produced by running the
-    earlier engine on CPU)."""
+    earlier engine on CPU); the family picks the cache kind `kv`."""
     eng = ServeEngine(cfg, params_for(cfg), n_slots=3, temperature=0.8,
-                      kv=kv, chunk_size=16, page_size=8)
+                      chunk_size=16, page_size=8)
     rep = eng.serve(_staggered_trace())
     assert eng.kv == kv
     name = "paper-tinylstm" if cfg is TINY else "qwen1.5-0.5b-reduced"
     assert [list(r.tokens) for r in rep.results] == SAMPLED_GOLDEN[name]
 
 
-@pytest.mark.parametrize("kv", ["paged", "dense"])
-def test_new_trace_seed_does_not_recompile(kv):
+@pytest.mark.parametrize("cfg", [QWEN, TINY],
+                         ids=["qwen1.5-0.5b-reduced-paged",
+                              "paper-tinylstm-dense"])
+def test_new_trace_seed_does_not_recompile(cfg):
     """The trace's sampling base key is an argument of the step
     programs, not a constant: serving a second trace with another seed
-    through one engine compiles nothing more (one decode program, the
-    same prefill buckets) and samples other tokens."""
-    eng = ServeEngine(QWEN, params_for(QWEN), n_slots=3, kv=kv,
-                      chunk_size=8, page_size=8)
+    through one engine (either cache kind) compiles nothing more (one
+    decode program, the same prefill buckets) and samples other
+    tokens."""
+    eng = ServeEngine(cfg, params_for(cfg), n_slots=3, chunk_size=8,
+                      page_size=8)
     reqs = tuple(Request(rid, 0, 3 + 2 * rid, 4) for rid in range(3))
     a = eng.serve(RequestTrace(21, reqs))
     built = eng._compiled[max(8, RequestTrace(21, reqs).max_seq_len())]
@@ -600,3 +634,23 @@ def test_new_trace_seed_does_not_recompile(kv):
     assert sizes["decode"] == 1
     assert {k: built[k]._cache_size() for k in sizes} == sizes
     assert [r.tokens for r in a.results] != [r.tokens for r in b.results]
+
+
+@pytest.mark.parametrize("page_size", [4, 16])
+def test_page_size_is_layout_only(page_size):
+    """The page length is a layout of the pool, not a result: greedy
+    serving of the staggered trace on pages of 4 or 16 tokens gives the
+    same tokens, statuses and bills as on pages of 8."""
+    params = params_for(QWEN)
+    trace = _staggered_trace()
+    radio = Radio(snr_db=10.0, fading=True, arq_max_tx=6, arq_attempts=2)
+
+    def serve(page):
+        return ServeEngine(QWEN, params, n_slots=3, radio=radio,
+                           greedy=True, chunk_size=16,
+                           page_size=page).serve(trace)
+
+    ref, rep = serve(8), serve(page_size)
+    assert [(r.rid, r.status, r.tokens) for r in rep.results] == \
+           [(r.rid, r.status, r.tokens) for r in ref.results]
+    assert _bill_rows(rep) == _bill_rows(ref)

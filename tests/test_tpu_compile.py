@@ -14,6 +14,10 @@ from jax.sharding import SingleDeviceSharding
 # pages, 8 slots, bfloat16 caches; a 32-token prefill chunk
 B, H, HD, PAGE, N_LP, CHUNK = 8, 16, 64, 16, 8, 32
 BF16 = jnp.bfloat16
+# (slots, pages a slot, chunk) of the smoke run above and of the
+# qwen05b-serve-decode cell: 32 slots of 9 pages (prompts to 35 tokens,
+# replies to 101), 64-token chunks
+SERVE_SHAPES = {"smoke": (B, N_LP, CHUNK), "cell": (32, 9, 64)}
 
 
 @pytest.fixture(scope="module")
@@ -48,35 +52,23 @@ def _compile(fn, shapes, sharding):
     return compiled
 
 
+@pytest.mark.parametrize("shape", list(SERVE_SHAPES))
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
-def test_paged_attention_kernels_compile(one_chip, kind):
+def test_paged_attention_kernels_compile(one_chip, kind, shape):
     from repro.kernels.decode_attention.ops import gqa_decode_paged
     from repro.kernels.prefill_attention.ops import gqa_prefill_paged
-    pool = ((B * N_LP, H, PAGE, HD), BF16)
-    tables = ((B, N_LP), jnp.int32)
-    pos = ((B,), jnp.int32)
+    b, n_lp, chunk = SERVE_SHAPES[shape]
+    pool = ((b * n_lp, H, PAGE, HD), BF16)
+    tables = ((b, n_lp), jnp.int32)
+    pos = ((b,), jnp.int32)
     if kind == "decode":
         _compile(lambda q, k, v, t, n: gqa_decode_paged(
             q, k, v, t, n, interpret=False),
-            [((B, H, HD), BF16), pool, pool, tables, pos], one_chip)
+            [((b, H, HD), BF16), pool, pool, tables, pos], one_chip)
     else:
         _compile(lambda q, k, v, t, s: gqa_prefill_paged(
             q, k, v, t, s, interpret=False),
-            [((B, CHUNK, H, HD), BF16), pool, pool, tables, pos], one_chip)
-
-
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
-def test_dense_attention_kernels_compile(one_chip, kind):
-    from repro.kernels.decode_attention.ops import gqa_decode
-    from repro.kernels.prefill_attention.ops import gqa_prefill
-    cache = ((B, H, N_LP * PAGE, HD), BF16)
-    pos = ((B,), jnp.int32)
-    if kind == "decode":
-        _compile(lambda q, k, v, n: gqa_decode(q, k, v, n, interpret=False),
-                 [((B, H, HD), BF16), cache, cache, pos], one_chip)
-    else:
-        _compile(lambda q, k, v, s: gqa_prefill(q, k, v, s, interpret=False),
-                 [((B, CHUNK, H, HD), BF16), cache, cache, pos], one_chip)
+            [((b, chunk, H, HD), BF16), pool, pool, tables, pos], one_chip)
 
 
 def _paper_fl_rows() -> int:
