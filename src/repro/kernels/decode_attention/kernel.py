@@ -3,8 +3,12 @@ paged KV cache, with online softmax, causal length masking and sliding
 windows. Head-group dim G (= H / Hkv) rides the sublane axis, hd the
 lanes.
 
-`paged_decode_attention` reads the shared page pool [n_pages, Hkv,
-page, hd] through per-slot page tables, at the pool's stored width.
+`paged_decode_attention` reads one layer of the stacked page pool [L,
+n_pages, Hkv, page, hd], shared by all slots, through per-slot page
+tables, at the pool's stored width. The layer index is
+scalar-prefetched into the page BlockSpecs' index maps, as
+`kernels/moe_gmm` reads its stacked experts, so no per-layer slice of
+the pool is ever made.
 Grid (B, blocks): a step takes one slot and one block of up to
 `_pages_per_block` pages, with all its KV heads; where the table fits
 one block, as in serving, that is one step per slot. Each page of the
@@ -36,9 +40,10 @@ MAX_PAGES_PER_BLOCK = 16
 PAGE_BLOCK_VMEM = 4 * 1024 * 1024
 
 
-def _paged_decode_kernel(fetch_ref, len_ref, q_ref, *refs, scale: float,
-                         window: int, page: int, ppb: int, shared_kv: bool):
-    del fetch_ref  # consumed by the page BlockSpecs' index maps
+def _paged_decode_kernel(fetch_ref, len_ref, layer_ref, q_ref, *refs,
+                         scale: float, window: int, page: int, ppb: int,
+                         shared_kv: bool):
+    del fetch_ref, layer_ref  # consumed by the page BlockSpecs' index maps
     k_refs = refs[:ppb]
     v_refs = k_refs if shared_kv else refs[ppb:2 * ppb]
     o_ref, m_scr, l_scr, acc_scr = refs[-4:]
@@ -132,16 +137,17 @@ def _fetch_table(tables, length, window: int, page: int, ppb: int):
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array | None, tables: jax.Array,
-                           length: jax.Array, window: int = 0,
-                           scale: float | None = None,
+                           v_pool: jax.Array | None, layer: jax.Array,
+                           tables: jax.Array, length: jax.Array,
+                           window: int = 0, scale: float | None = None,
                            interpret: bool | None = None) -> jax.Array:
     """Flash-decode over a PAGED cache: q [B, Hkv, G, hd]; pools
-    [n_pages, Hkv, page, hd] shared by all slots, read at their stored
-    width; `tables` [B, n_lp] int32 maps each row's logical page j to its
-    physical pool page; `length` [B] (or scalar) valid-prefix counts.
-    `v_pool` None means the values are the keys (latent attention):
-    each page is then fetched once.
+    [L, n_pages, Hkv, page, hd], stacked over the layers and shared by
+    all slots, of which layer `layer` (int32 scalar) is read in place at
+    its stored width; `tables` [B, n_lp] int32 maps each row's logical
+    page j to its physical pool page; `length` [B] (or scalar)
+    valid-prefix counts. `v_pool` None means the values are the keys
+    (latent attention): each page is then fetched once.
 
     Grid (B, blocks): one step per slot and block of `_pages_per_block`
     pages, all KV heads at once; where the whole table fits one block,
@@ -156,7 +162,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     softmax is carried across a slot's blocks. Returns [B, Hkv, G, hd]
     fp32."""
     B, Hkv, G, hd = q.shape
-    _, _, page, _ = k_pool.shape
+    page = k_pool.shape[3]
     n_lp = tables.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
@@ -171,15 +177,17 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     def page_spec(j):
         return pl.BlockSpec(
-            (1, Hkv, page, hd),
-            lambda b, i, f, ln: (f[(b * n_blk + i) * ppb + j], 0, 0, 0))
+            (None, 1, Hkv, page, hd),
+            lambda b, i, f, ln, lay: (lay[0], f[(b * n_blk + i) * ppb + j],
+                                      0, 0, 0))
 
-    slot_spec = pl.BlockSpec((1, Hkv, G, hd), lambda b, i, f, ln: (b, 0, 0, 0))
+    slot_spec = pl.BlockSpec((1, Hkv, G, hd),
+                             lambda b, i, f, ln, lay: (b, 0, 0, 0))
     return pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, window=window,
                           page=page, ppb=ppb, shared_kv=v_pool is None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, n_blk),
             in_specs=[slot_spec] + [page_spec(j) for j in range(ppb)]
             * len(pools),
@@ -192,5 +200,6 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(fetch, length, q, *[pool for pool in pools for _ in range(ppb)])
+    )(fetch, length, jnp.reshape(layer, (1,)).astype(jnp.int32), q,
+      *[pool for pool in pools for _ in range(ppb)])
 

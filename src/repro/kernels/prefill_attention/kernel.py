@@ -3,17 +3,19 @@ tokens vs. the paged KV cache with online softmax — the serving
 engine's admission hot path (one launch per chunk instead of C decode
 launches).
 
-Grid (B, Hkv, n_lp); KV block j is pool page tables[b, j], routed by
-the scalar-prefetched BlockSpec index maps, whose logical columns start
-at j * page. The page axis is the innermost (sequential on TPU) grid
-dim, so the running (m, l, acc) state lives in VMEM scratch across
-pages. The C chunk positions and the G head-group dim are flattened
-onto the sublane axis as C*G query rows; row r is chunk position r // G,
-whose global query position is start[b] + r // G. Causality is
-per-query-row: row r attends cache columns <= start[b] + r // G (with an
-optional sliding window), so a single launch covers every token of the
-chunk including its self-causal triangle. ops.py pads G to a sublane
-multiple and hd to a lane multiple.
+Grid (B, Hkv, n_lp); KV block j is page tables[b, j] of layer `layer`
+of the pools stacked over the layers, routed by the scalar-prefetched
+BlockSpec index maps (the layer index too, as `kernels/moe_gmm` reads
+its stacked experts, so no per-layer slice of the pool is made), whose
+logical columns start at j * page. The page axis is the innermost
+(sequential on TPU) grid dim, so the running (m, l, acc) state lives in
+VMEM scratch across pages. The C chunk positions and the G head-group
+dim are flattened onto the sublane axis as C*G query rows; row r is
+chunk position r // G, whose global query position is start[b] + r //
+G. Causality is per-query-row: row r attends cache columns <= start[b]
++ r // G (with an optional sliding window), so a single launch covers
+every token of the chunk including its self-causal triangle. ops.py
+pads G to a sublane multiple; the pools are read at their stored width.
 """
 from __future__ import annotations
 
@@ -29,10 +31,10 @@ from repro.kernels import resolve_interpret
 NEG_INF = -1e30
 
 
-def _paged_prefill_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_scr, l_scr, acc_scr, *, scale: float,
+def _paged_prefill_kernel(tbl_ref, start_ref, layer_ref, q_ref, k_ref, v_ref,
+                          o_ref, m_scr, l_scr, acc_scr, *, scale: float,
                           window: int, page: int, g: int):
-    del tbl_ref  # consumed by the KV BlockSpecs' index maps
+    del tbl_ref, layer_ref  # consumed by the KV BlockSpecs' index maps
     j = pl.program_id(2)
     nj = pl.num_programs(2)
 
@@ -73,12 +75,14 @@ def _paged_prefill_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
-                            v_pool: jax.Array, tables: jax.Array,
-                            start: jax.Array, g: int, window: int = 0,
-                            scale: float | None = None,
+                            v_pool: jax.Array, layer: jax.Array,
+                            tables: jax.Array, start: jax.Array, g: int,
+                            window: int = 0, scale: float | None = None,
                             interpret: bool | None = None) -> jax.Array:
     """Flash-prefill over a PAGED cache: q [B, Hkv, C*G, hd] chunk-major
-    query rows; pools [n_pages, Hkv, page, hd]; `tables` [B, n_lp]
+    query rows; pools [L, n_pages, Hkv, page, hd] stacked over the
+    layers, of which layer `layer` (int32 scalar) is read in place;
+    `tables` [B, n_lp]
     per-slot page tables (scalar-prefetched into the KV BlockSpec index
     maps); `start` [B] global position of chunk token 0. Logical
     columns past each query's causal horizon are masked, so placeholder
@@ -86,27 +90,28 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     fp32."""
     B, Hkv, CG, hd = q.shape
     assert CG % g == 0, (CG, g)
-    n_pages, _, page, _ = k_pool.shape
+    page = k_pool.shape[3]
     n_lp = tables.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     grid = (B, Hkv, n_lp)
+    kv_spec = pl.BlockSpec((None, 1, 1, page, hd),
+                           lambda b, h, j, t, st, lay: (lay[0], t[b, j], h,
+                                                        0, 0))
     return pl.pallas_call(
         functools.partial(_paged_prefill_kernel, scale=scale, window=window,
                           page=page, g=g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, CG, hd),
-                             lambda b, h, j, t, st: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, page, hd),
-                             lambda b, h, j, t, st: (t[b, j], h, 0, 0)),
-                pl.BlockSpec((1, 1, page, hd),
-                             lambda b, h, j, t, st: (t[b, j], h, 0, 0)),
+                             lambda b, h, j, t, st, lay: (b, h, 0, 0)),
+                kv_spec,
+                kv_spec,
             ],
             out_specs=pl.BlockSpec((1, 1, CG, hd),
-                                   lambda b, h, j, t, st: (b, h, 0, 0)),
+                                   lambda b, h, j, t, st, lay: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((CG, 1), jnp.float32),
                 pltpu.VMEM((CG, 1), jnp.float32),
@@ -117,5 +122,5 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         interpret=resolve_interpret(interpret),
     )(jnp.asarray(tables, jnp.int32),
       jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (B,)),
-      q, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q, k_pool, v_pool)
 

@@ -287,31 +287,56 @@ def prefill_attention_jnp(q, k_cache, v_cache, start, window: int = 0,
 
 
 # ---------------------------------------------------------------- paged KV
-def paged_view(pool, tables):
-    """Gather a slot-major dense view [B, Hkv, n_lp*page, hd] out of a
-    shared page pool [n_pages, Hkv, page, hd] via per-slot page tables
-    [B, n_lp]: logical column c of row b lives at
-    pool[tables[b, c // page], :, c % page]. Placeholder table entries
+LANES = 128
+
+
+def kv_heads_per_row(n_kv_heads: int, hd: int) -> int:
+    """KV heads a pool row holds side by side: as many hd-wide heads as
+    fill a 128-lane row, where hd divides 128 and the heads split evenly
+    (else 1). A TPU stores a pool whose rows are narrower than 128 lanes
+    with its page axis minor-most, while the paged kernels read it
+    row-major; a pool of full rows is stored row-major, so the step
+    programs neither relay it out nor pad its rows."""
+    if hd >= LANES or LANES % hd:
+        return 1
+    return math.gcd(n_kv_heads, LANES // hd)
+
+
+def paged_view(pool, layer, tables, hd: int | None = None):
+    """Gather a slot-major dense view [B, Hkv, n_lp*page, hd] of layer
+    `layer` of the stacked page pool [L, n_pages, Hkv/p, page, p*hd]
+    (p = `kv_heads_per_row`, head i of a row in lanes [i*hd, (i+1)*hd);
+    `hd` defaults to the row width, p = 1) via per-slot page tables
+    [B, n_lp]: logical column c of row b lives at pool[layer,
+    tables[b, c // page], :, c % page]. Placeholder table entries
     surface whatever the pool holds there — always masked downstream by
     the valid-prefix length, so they contribute exact zeros."""
     B, n_lp = tables.shape
-    n_pages, Hkv, page, hd = pool.shape
-    v = pool[tables]                                  # [B, n_lp, Hkv, page, hd]
-    return v.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, n_lp * page, hd)
+    _, _, hr, page, w = pool.shape
+    hd = hd or w
+    p = w // hd
+    v = pool[layer, tables]                    # [B, n_lp, Hkv/p, page, p*hd]
+    v = v.reshape(B, n_lp, hr, page, p, hd).transpose(0, 2, 4, 1, 3, 5)
+    return v.reshape(B, hr * p, n_lp * page, hd)
 
 
-def paged_insert(pool, tables, cols, vals, keep):
-    """Scatter vals [B, C, Hkv, hd] into the pool at each slot's logical
-    columns `cols` [B, C]; positions with keep=False route out of
-    bounds and are dropped. The pool has no batch axis — slots share
-    it — so per-row masking (inactive slots, padded chunk tails) must
-    happen here at the write, not by a post-hoc batch select."""
-    n_pages, Hkv, page, hd = pool.shape
-    phys = jnp.take_along_axis(tables, cols // page, axis=1)    # [B, C]
-    phys = jnp.where(keep, phys, n_pages)                       # OOB -> drop
-    off = cols % page
-    return pool.at[phys, :, off, :].set(vals.astype(pool.dtype),
-                                        mode="drop")
+def paged_insert(pool, layer, tables, start, vals, keep):
+    """Write C consecutive rows a slot, vals [B, C, Hkv, hd], into layer
+    `layer` of the stacked pool [L, n_pages, Hkv/p, page, p*hd] in place
+    (see `paged_view`): row c of slot b at its logical column start[b] +
+    c, through its page table. Rows with keep [B, C] False are dropped:
+    the pool has no batch axis — slots share it — so per-row masking
+    (inactive slots, padded chunk tails) must happen here at the write,
+    not by a post-hoc batch select. Each pool row is its own scatter
+    window, so XLA writes it in the pool's row-major layout."""
+    n_pages, hr, page, w = pool.shape[1:]
+    B, C = keep.shape
+    cols = start[:, None] + jnp.arange(C)[None]                # [B, C]
+    phys = jnp.take_along_axis(tables, cols // page, axis=1)
+    phys = jnp.where(keep, phys, n_pages)[..., None]            # OOB -> drop
+    rows = vals.reshape(B, C, hr, w).astype(pool.dtype)
+    return pool.at[layer, phys, jnp.arange(hr), (cols % page)[..., None]] \
+        .set(rows, mode="drop")
 
 
 def use_attn_kernel(kernel: bool | None = None) -> bool:
@@ -333,8 +358,8 @@ def no_pages():
                               "pool only: pass `pages`")
 
 
-def attention_prefill_slots(p, x, cfg, cache_k, cache_v, start, n_valid,
-                            window=0, pages=None):
+def attention_prefill_slots(p, x, cfg, cache_k, cache_v, layer, start,
+                            n_valid, window=0, pages=None):
     """Fused chunk prefill over the shared page pool: x [B,C,d] — C
     prompt tokens per slot starting at per-row cache position `start`
     [B]; chunk positions >= n_valid[b] are padded tail and masked out of
@@ -342,8 +367,10 @@ def attention_prefill_slots(p, x, cfg, cache_k, cache_v, start, n_valid,
     attention launch replace C decode steps. `pages` = {"tables":
     [B,n_lp], "page_size": int, "active": [B] bool or None, "kernel":
     bool or None} is required: the caches are the pools
-    [n_pages,Hkv,page,hd], and `kernel` picks the attention route
-    (`use_attn_kernel`). Returns (out [B,C,d], new_k, new_v)."""
+    [L,n_pages,Hkv/p,page,p*hd] stacked over the layers (`paged_view`),
+    written and read in place at layer `layer`, and `kernel` picks the
+    attention route (`use_attn_kernel`). Returns (out [B,C,d], new_k,
+    new_v)."""
     if pages is None:
         no_pages()
     B, C, _ = x.shape
@@ -353,15 +380,16 @@ def attention_prefill_slots(p, x, cfg, cache_k, cache_v, start, n_valid,
     keep = jnp.arange(C)[None, :] < n_valid[:, None]        # [B, C]
     if pages.get("active") is not None:
         keep &= pages["active"][:, None]
-    cache_k = paged_insert(cache_k, pages["tables"], positions, k, keep)
-    cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
+    tables = pages["tables"]
+    cache_k = paged_insert(cache_k, layer, tables, start, k, keep)
+    cache_v = paged_insert(cache_v, layer, tables, start, v, keep)
     if _pages_kernel(pages):
         from repro.kernels.prefill_attention.ops import gqa_prefill_paged
-        out = gqa_prefill_paged(q, cache_k, cache_v, pages["tables"],
-                                start, window=window)
+        out = gqa_prefill_paged(q, cache_k, cache_v, layer, tables, start,
+                                window=window)
     else:
-        out = prefill_attention_jnp(q, paged_view(cache_k, pages["tables"]),
-                                    paged_view(cache_v, pages["tables"]),
+        out = prefill_attention_jnp(q, paged_view(cache_k, layer, tables, hd),
+                                    paged_view(cache_v, layer, tables, hd),
                                     start, window=window)
     out = out.reshape(B, C, cfg.n_heads * hd).astype(x.dtype)
     return constrain(linear(p["wo"], out), "batch", "seq",
@@ -378,20 +406,21 @@ def attention_train(p, x, cfg, positions=None, causal=True, window=0):
     return constrain(linear(p["wo"], out), "batch", "seq", "act_embed")
 
 
-def attention_decode_slots(p, x, cfg, cache_k, cache_v, indices, window=0,
-                           pages=None):
+def attention_decode_slots(p, x, cfg, cache_k, cache_v, layer, indices,
+                           window=0, pages=None):
     """Slot-axis decode over the shared page pool: x [B,1,d], `indices`
     [B] — each row writes its k/v at its own cache position and attends
     its own prefix. The continuous-batching analogue of
     `attention_decode`; rows are fully independent, so admitting a new
     request into a freed slot never perturbs its neighbours. `pages` =
     {"tables", "page_size", "active", "kernel"} is required: the caches
-    are the pools [n_pages,Hkv,page,hd], writes land through each slot's
-    page table (inactive rows' writes are dropped — the pool has no
-    batch axis to select over), and `pages["kernel"]` picks the
+    are the pools [L,n_pages,Hkv/p,page,p*hd] stacked over the layers,
+    written and read in place at layer `layer`; writes land through each
+    slot's page table (inactive rows' writes are dropped — the pool has
+    no batch axis to select over), and `pages["kernel"]` picks the
     attention route (`use_attn_kernel`): the Pallas kernel streams pool
-    pages straight off the scalar-prefetched page table, the jnp route
-    gathers a dense per-slot view first."""
+    pages straight off the scalar-prefetched page table and layer index,
+    the jnp route gathers a dense per-slot view first."""
     if pages is None:
         no_pages()
     B = x.shape[0]
@@ -400,31 +429,27 @@ def attention_decode_slots(p, x, cfg, cache_k, cache_v, indices, window=0,
     q, k, v = _qkv(p, x, cfg, positions)
     keep = jnp.ones((B, 1), bool) if pages.get("active") is None \
         else pages["active"][:, None]
-    cache_k = paged_insert(cache_k, pages["tables"], positions, k, keep)
-    cache_v = paged_insert(cache_v, pages["tables"], positions, v, keep)
-    q, tables, lengths = q[:, 0], pages["tables"], indices + 1
+    tables = pages["tables"]
+    cache_k = paged_insert(cache_k, layer, tables, indices, k, keep)
+    cache_v = paged_insert(cache_v, layer, tables, indices, v, keep)
+    q, lengths = q[:, 0], indices + 1
     if _pages_kernel(pages):
         from repro.kernels.decode_attention.ops import gqa_decode_paged
-        out = gqa_decode_paged(q, cache_k, cache_v, tables, lengths,
+        out = gqa_decode_paged(q, cache_k, cache_v, layer, tables, lengths,
                                window=window)
     else:
-        out = decode_attention_jnp(q, paged_view(cache_k, tables),
-                                   paged_view(cache_v, tables), lengths,
-                                   window=window)
+        out = decode_attention_jnp(q, paged_view(cache_k, layer, tables, hd),
+                                   paged_view(cache_v, layer, tables, hd),
+                                   lengths, window=window)
     out = out.astype(x.dtype).reshape(B, 1, cfg.n_heads * hd)
     return constrain(linear(p["wo"], out), "batch", "seq",
                      "act_embed"), cache_k, cache_v
 
 
-def attention_decode(p, x, cfg, cache_k, cache_v, index, window=0,
-                     pages=None):
-    """x [B,1,d]; cache [B,Hkv,S,hd]; index = scalar write position, or
-    a per-slot [B] vector (dispatches to `attention_decode_slots`, over
-    the paged pool; the scalar path stays bitwise the legacy decode).
-    Returns (out [B,1,d], new_k, new_v)."""
-    if jnp.asarray(index).ndim:
-        return attention_decode_slots(p, x, cfg, cache_k, cache_v, index,
-                                      window, pages=pages)
+def attention_decode(p, x, cfg, cache_k, cache_v, index, window=0):
+    """x [B,1,d]; one layer's dense cache [B,Hkv,S,hd]; index = scalar
+    write position (per-slot positions serve from the paged pool,
+    `attention_decode_slots`). Returns (out [B,1,d], new_k, new_v)."""
     B = x.shape[0]
     hd = cfg.hd
     positions = jnp.broadcast_to(index[None, None], (B, 1))

@@ -113,12 +113,12 @@ def _absorbed_out(p, o, cfg, dtype):
     return out.reshape(out.shape[:-2] + (H * dv,))
 
 
-def mla_decode_slots(p, x, cfg, pool, indices, pages, kernel: bool):
+def mla_decode_slots(p, x, cfg, pool, layer, indices, pages, kernel: bool):
     """One token per slot: x [B,1,d] at per-slot positions `indices` [B];
-    its [c | k_pe] row is written to the pool [n_pages,1,page,r+dr]
-    through each slot's page table (inactive rows dropped), then the
-    absorbed query attends the slot's prefix. Returns (out [B,1,d],
-    new pool)."""
+    its [c | k_pe] row is written in place to layer `layer` of the
+    stacked pool [L,n_pages,1,page,r+dr] through each slot's page table
+    (inactive rows dropped), then the absorbed query attends the slot's
+    prefix there. Returns (out [B,1,d], new pool)."""
     if pages is None:
         L.no_pages()
     B = x.shape[0]
@@ -129,14 +129,15 @@ def mla_decode_slots(p, x, cfg, pool, indices, pages, kernel: bool):
         row = jnp.concatenate([c, k_pe], -1)[:, :, None]       # [B,1,1,D]
         keep = jnp.ones((B, 1), bool) if pages.get("active") is None \
             else pages["active"][:, None]
-        pool = L.paged_insert(pool, pages["tables"], positions, row, keep)
+        tables = pages["tables"]
+        pool = L.paged_insert(pool, layer, tables, indices, row, keep)
         scale = softmax_scale(cfg)
         if kernel:
             from repro.kernels.decode_attention.ops import mla_decode_paged
-            o = mla_decode_paged(q[:, 0].astype(pool.dtype), pool,
-                                 pages["tables"], indices + 1, scale=scale)
+            o = mla_decode_paged(q[:, 0].astype(pool.dtype), pool, layer,
+                                 tables, indices + 1, scale=scale)
         else:
-            view = L.paged_view(pool, pages["tables"])
+            view = L.paged_view(pool, layer, tables)
             o = L.decode_attention_jnp(q[:, 0], view, view, indices + 1,
                                        scale=scale)
         out = _absorbed_out(p, o, cfg, x.dtype)[:, None]       # [B,1,H*dv]
@@ -144,10 +145,12 @@ def mla_decode_slots(p, x, cfg, pool, indices, pages, kernel: bool):
                      "act_embed"), pool
 
 
-def mla_prefill_slots(p, x, cfg, pool, start, n_valid, pages, kernel: bool):
-    """Prompt chunks: x [B,C,d] at positions start[b] + i; rows past
-    n_valid[b] are masked out of the pool write. Returns (out [B,C,d],
-    new pool)."""
+def mla_prefill_slots(p, x, cfg, pool, layer, start, n_valid, pages,
+                      kernel: bool):
+    """Prompt chunks: x [B,C,d] at positions start[b] + i, written in
+    place to layer `layer` of the stacked pool (see `mla_decode_slots`);
+    rows past n_valid[b] are masked out of the pool write. Returns
+    (out [B,C,d], new pool)."""
     if pages is None:
         L.no_pages()
     B, C, _ = x.shape
@@ -159,14 +162,15 @@ def mla_prefill_slots(p, x, cfg, pool, start, n_valid, pages, kernel: bool):
         keep = jnp.arange(C)[None, :] < n_valid[:, None]
         if pages.get("active") is not None:
             keep &= pages["active"][:, None]
-        pool = L.paged_insert(pool, pages["tables"], positions, row, keep)
+        tables = pages["tables"]
+        pool = L.paged_insert(pool, layer, tables, start, row, keep)
         scale = softmax_scale(cfg)
         if kernel:
             from repro.kernels.prefill_attention.ops import mla_prefill_paged
-            o = mla_prefill_paged(q.astype(pool.dtype), pool,
-                                  pages["tables"], start, scale=scale)
+            o = mla_prefill_paged(q.astype(pool.dtype), pool, layer, tables,
+                                  start, scale=scale)
         else:
-            view = L.paged_view(pool, pages["tables"])
+            view = L.paged_view(pool, layer, tables)
             o = L.prefill_attention_jnp(q, view, view, start, scale=scale)
         out = _absorbed_out(p, o, cfg, x.dtype)                # [B,C,H*dv]
     return constrain(L.linear(p["wo"], out), "batch", "seq",

@@ -107,12 +107,12 @@ def apply_block(lp: dict, x: jax.Array, cfg, positions=None, causal=True,
 
 
 def _serve_block(lp, x, cfg, attend, valid, kernel, li):
-    """One layer of a serve step: `attend(h)` -> (attn, new layer cache);
+    """One layer of a serve step: `attend(h)` -> (attn, new caches);
     an MoE layer's feed-forward is the dropless held-expert layer (its
     expert weights stacked over the stack's layers, `li` picking this
     one), with its counters; a dense layer's counters are zeros."""
     h = _norm(lp["ln_attn"], x, cfg)
-    attn, lc = attend(h)
+    attn, cache = attend(h)
     if not cfg.parallel_block:
         x = x + attn
         h = _norm(lp["ln_mlp"], x, cfg)
@@ -122,48 +122,57 @@ def _serve_block(lp, x, cfg, attend, valid, kernel, li):
         m = L.apply_mlp(lp["mlp"], h)
         stats = jnp.zeros((N_STATS,), jnp.int32)
     x = x + attn + m if cfg.parallel_block else x + m
-    return x, lc, stats
+    return x, cache, stats
 
 
-def apply_block_decode(lp: dict, x, cfg, lc: dict, index, window=0,
-                       pages=None, valid=None, li=None):
-    """One layer of a decode step over its cache `lc` ({"k", "v"}, or
-    {"latent"} for latent attention); for an MoE layer, `li` indexes its
-    stacked expert weights (see `moe.moe_held`). Returns (x, new lc,
-    counters)."""
+def apply_block_decode(lp: dict, x, cfg, cache: dict, layer, index,
+                       window=0, pages=None, valid=None, li=None):
+    """One layer of a decode step over the stacked caches `cache` ({"k",
+    "v"}, or {"latent"} for latent attention) at layer `layer`; for an
+    MoE layer, `li` indexes its stacked expert weights (see
+    `moe.moe_held`). A per-slot [B] `index` reads and writes the paged
+    pools in place; a scalar one is the dense per-row cache, whose layer
+    is sliced out and written back. Returns (x, new cache, counters)."""
     kernel = L._pages_kernel(pages)
 
     def attend(h):
         if cfg.is_mla:
             out, pool = mla.mla_decode_slots(lp["attn"], h, cfg,
-                                             lc["latent"], index, pages,
-                                             kernel)
+                                             cache["latent"], layer, index,
+                                             pages, kernel)
             return out, {"latent": pool}
-        out, ck, cv = L.attention_decode(lp["attn"], h, cfg, lc["k"],
-                                         lc["v"], index, window,
-                                         pages=pages)
-        return out, {"k": ck, "v": cv}
+        if jnp.ndim(index):
+            out, ck, cv = L.attention_decode_slots(
+                lp["attn"], h, cfg, cache["k"], cache["v"], layer, index,
+                window, pages=pages)
+            return out, {"k": ck, "v": cv}
+        out, ck, cv = L.attention_decode(
+            lp["attn"], h, cfg, cache["k"][layer], cache["v"][layer], index,
+            window)
+        return out, {k: jax.lax.dynamic_update_index_in_dim(
+            cache[k], c, layer, 0) for k, c in (("k", ck), ("v", cv))}
 
     return _serve_block(lp, x, cfg, attend, valid, kernel, li)
 
 
-def apply_block_prefill(lp: dict, x, cfg, lc: dict, start, n_valid,
-                        window=0, pages=None, li=None):
-    """Chunk analogue of `apply_block_decode`: x [B,C,d] prompt chunks at
-    per-row positions start[b]..start[b]+C-1, chunk tails >= n_valid[b]
-    masked out of the cache insert and of the expert dispatch."""
+def apply_block_prefill(lp: dict, x, cfg, cache: dict, layer, start,
+                        n_valid, window=0, pages=None, li=None):
+    """Chunk analogue of `apply_block_decode` over the paged pools: x
+    [B,C,d] prompt chunks at per-row positions start[b]..start[b]+C-1,
+    chunk tails >= n_valid[b] masked out of the cache insert and of the
+    expert dispatch."""
     kernel = L._pages_kernel(pages)
     valid = jnp.arange(x.shape[1])[None, :] < n_valid[:, None]
 
     def attend(h):
         if cfg.is_mla:
             out, pool = mla.mla_prefill_slots(lp["attn"], h, cfg,
-                                              lc["latent"], start, n_valid,
-                                              pages, kernel)
+                                              cache["latent"], layer, start,
+                                              n_valid, pages, kernel)
             return out, {"latent": pool}
         out, ck, cv = L.attention_prefill_slots(lp["attn"], h, cfg,
-                                                lc["k"], lc["v"], start,
-                                                n_valid, window, pages)
+                                                cache["k"], cache["v"], layer,
+                                                start, n_valid, window, pages)
         return out, {"k": ck, "v": cv}
 
     return _serve_block(lp, x, cfg, attend, valid, kernel, li)
@@ -225,14 +234,17 @@ def paged_cache_shapes(cfg, n_pages: int, page_size: int):
     axis; slots map logical columns onto pool pages via per-slot page
     tables (serve/paging.py owns allocation). Capacity is bounded by
     total tokens in flight (n_pages * page_size), not B * seq_len.
-    Latent attention caches one pool, `latent`, of [c | k_pe] rows under
-    a single head."""
+    A K or V pool row holds `layers.kv_heads_per_row` heads side by
+    side. Latent attention caches one pool, `latent`, of [c | k_pe] rows
+    under a single head."""
     axes = ("layers", None, "kv_heads", None, None)
     if cfg.is_mla:
         shape = (cfg.n_layers, n_pages, 1, page_size, mla.pool_width(cfg))
         return {"latent": (shape, (axes[0], None, None, None, None),
                            cfg.dtype)}
-    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.hd)
+    p = L.kv_heads_per_row(cfg.n_kv_heads, cfg.hd)
+    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads // p, page_size,
+             p * cfg.hd)
     return {
         "k": (shape, axes, cfg.dtype),
         "v": (shape, axes, cfg.dtype),
@@ -246,16 +258,17 @@ def init_paged_cache(cfg, n_pages: int, page_size: int) -> dict:
 
 
 def _serve_layers(params, cache, x, cfg, block):
-    """Every layer stack in order over the stacked [L, ...] caches, the
-    caches riding the scan CARRY and updated in place with
-    dynamic_update_slice — scanning them as xs/ys makes XLA allocate a
-    second full cache for the stacked ys (a whole extra cache copy in
-    HBM; §Perf-3). The held experts' weights are not scanned either:
-    each layer's MoE block gets them stacked, with its index in the
-    stack, so the grouped matmul reads them in place. `block(lp, x,
-    layer cache, index in the stack)` -> (x, layer cache, counters).
-    Returns (x, cache, counters summed over the layers: the held-expert
-    layer's rows, busiest expert's rows and experts used)."""
+    """Every layer stack in order. The stacked [L, ...] caches ride the
+    scan CARRY whole — scanning them as xs/ys would make XLA allocate a
+    second full cache for the stacked ys (§Perf-3) — and each layer's
+    block reads and writes its own layer of them in place, at its index
+    in the stack, as the paged kernels read the pools. The held experts'
+    weights are not scanned either: each layer's MoE block gets them
+    stacked, with its index in their stack, so the grouped matmul reads
+    them in place. `block(lp, x, cache, layer, index in the stack)` ->
+    (x, cache, counters). Returns (x, cache, counters summed over the
+    layers: the held-expert layer's rows, busiest expert's rows and
+    experts used)."""
     counts = jnp.zeros((N_STATS,), jnp.int32)
     for stack, first in _stacks(params, cfg):
         experts = {}
@@ -269,11 +282,7 @@ def _serve_layers(params, cache, x, cfg, block):
             lp, l = lp_l
             if experts:
                 lp = dict(lp, moe=dict(lp["moe"], **experts))
-            lc = {k: jax.lax.dynamic_index_in_dim(v, l, 0, keepdims=False)
-                  for k, v in cache.items()}
-            x, lc, st = block(lp, x, lc, l - first)
-            cache = {k: jax.lax.dynamic_update_index_in_dim(
-                v, lc[k].astype(v.dtype), l, 0) for k, v in cache.items()}
+            x, cache, st = block(lp, x, cache, l, l - first)
             return (x, cache), st
 
         n = jax.tree.leaves(stack)[0].shape[0]
@@ -300,8 +309,8 @@ def decode_step(params: dict, cache: dict, token: jax.Array, index: jax.Array,
     if pages is not None and pages.get("active") is not None:
         valid = pages["active"][:, None]
 
-    def block(lp, x, lc, li):
-        return apply_block_decode(lp, x, cfg, lc, index, window,
+    def block(lp, x, cache, layer, li):
+        return apply_block_decode(lp, x, cfg, cache, layer, index, window,
                                   pages=pages, valid=valid, li=li)
 
     x, cache, counts = _serve_layers(params, cache, x, cfg, block)
@@ -327,9 +336,9 @@ def prefill_step(params: dict, cache: dict, tokens: jax.Array,
     B, C = tokens.shape
     x = L.embed_lookup(params["embed"], tokens, cfg.dtype)
 
-    def block(lp, x, lc, li):
-        return apply_block_prefill(lp, x, cfg, lc, start, n_valid, window,
-                                   pages=pages, li=li)
+    def block(lp, x, cache, layer, li):
+        return apply_block_prefill(lp, x, cfg, cache, layer, start, n_valid,
+                                   window, pages=pages, li=li)
 
     x, cache, counts = _serve_layers(params, cache, x, cfg, block)
     x = _norm(params["ln_f"], x, cfg)

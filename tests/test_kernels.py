@@ -141,15 +141,18 @@ def test_lstm_layer_matches_model():
 
 
 # --------------------------------------------------------- decode_attention
-# the dense [B, Hkv, S, hd] caches of these tests are scattered into a
-# shared page pool (`_paged_from_dense`) and attended through the paged
-# kernel, against the references on the dense cache
+# the dense [B, Hkv, S, hd] caches of these tests are scattered into layer
+# LAYER of a shared page pool stacked over N_LAYERS layers
+# (`_paged_from_dense`; the other layers hold NaN, so a read of them
+# shows) and attended through the paged kernel, against the references
+# on the dense cache
 PAGE = 16
+N_LAYERS, LAYER = 3, 1
 
 
 def _gqa_decode_paged(q, k, v, length, **kw):
     kp, vp, tables = _paged_from_dense(k, v, PAGE)
-    return da_ops.gqa_decode_paged(q, kp, vp, tables, length, **kw)
+    return da_ops.gqa_decode_paged(q, kp, vp, LAYER, tables, length, **kw)
 
 
 @HS
@@ -255,7 +258,7 @@ def test_decode_attention_per_slot_lengths():
     v = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
     lengths = jnp.array([1, 7, 16, 33, 64, 100, 127, 128], jnp.int32)
     kp, vp, tables = _paged_from_dense(k, v, PAGE)
-    out = da_ops.gqa_decode_paged(q, kp, vp, tables, lengths,
+    out = da_ops.gqa_decode_paged(q, kp, vp, LAYER, tables, lengths,
                                   interpret=True)
     ref = decode_attention_ref(q.reshape(b, hkv, g, hd), k, v, lengths)
     np.testing.assert_allclose(np.asarray(out),
@@ -266,8 +269,9 @@ def test_decode_attention_per_slot_lengths():
                                rtol=2e-4, atol=2e-4)
     # row independence: each slot's output equals its own scalar run
     for i in range(b):
-        one = da_ops.gqa_decode_paged(q[i:i + 1], kp, vp, tables[i:i + 1],
-                                      lengths[i], interpret=True)
+        one = da_ops.gqa_decode_paged(q[i:i + 1], kp, vp, LAYER,
+                                      tables[i:i + 1], lengths[i],
+                                      interpret=True)
         np.testing.assert_allclose(np.asarray(out[i:i + 1]),
                                    np.asarray(one), rtol=1e-6, atol=1e-6)
 
@@ -320,7 +324,8 @@ def test_prefill_kernel_matches_jnp_at_engine_buckets(c):
     from repro.models.layers import prefill_attention_jnp
     q, kc, vc, start = _prefill_fixture(31, c=c)
     kp, vp, tables = _paged_from_dense(kc, vc, PAGE)
-    out = pf_ops.gqa_prefill_paged(q, kp, vp, tables, start, interpret=True)
+    out = pf_ops.gqa_prefill_paged(q, kp, vp, LAYER, tables, start,
+                                   interpret=True)
     ref = prefill_attention_jnp(q, kc, vc, start)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -341,7 +346,7 @@ def test_prefill_kernel_layout_matches_ref(window):
     vc = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
     start = jnp.array([0, 5, 32, 77], jnp.int32)
     kp, vp, tables = _paged_from_dense(kc, vc, PAGE)
-    out = paged_prefill_attention(q, kp, vp, tables, start, g,
+    out = paged_prefill_attention(q, kp, vp, LAYER, tables, start, g,
                                   window=window, interpret=True)
     ref = prefill_attention_ref(q, kc, vc, start, g, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -357,7 +362,7 @@ def test_prefill_kernel_is_causal():
 
     def gqa_prefill_paged(kc, vc):
         kp, vp, tables = _paged_from_dense(kc, vc, PAGE)
-        return pf_ops.gqa_prefill_paged(q, kp, vp, tables, start,
+        return pf_ops.gqa_prefill_paged(q, kp, vp, LAYER, tables, start,
                                         interpret=True)
 
     out1 = gqa_prefill_paged(kc, vc)
@@ -380,42 +385,60 @@ def test_prefill_kernel_is_causal():
                                rtol=1e-5, atol=1e-5)
 
 
-def _paged_from_dense(kc, vc, page):
-    """Scatter a dense [B,Hkv,S,hd] cache into a shared pool with a
-    non-trivial (reversed per slot) page mapping."""
+def _paged_from_dense(kc, vc, page, heads_per_row=1):
+    """Scatter a dense [B,Hkv,S,hd] cache into layer LAYER of a shared
+    pool stacked over N_LAYERS layers, with a non-trivial (reversed per
+    slot) page mapping; every other layer holds NaN. `heads_per_row` p
+    packs p KV heads side by side into each pool row, as
+    `layers.kv_heads_per_row` lays out the served pools: [N_LAYERS,
+    n_pages, Hkv/p, page, p*hd]."""
     b, hkv, s, hd = kc.shape
     n_lp = s // page
     n_pages = b * n_lp
-    kp = np.zeros((n_pages, hkv, page, hd), np.float32)
-    vp = np.zeros((n_pages, hkv, page, hd), np.float32)
+    kp = np.full((N_LAYERS, n_pages, hkv, page, hd), np.nan, np.float32)
+    vp = np.full((N_LAYERS, n_pages, hkv, page, hd), np.nan, np.float32)
     tables = np.zeros((b, n_lp), np.int32)
     order = np.arange(n_pages).reshape(b, n_lp)[:, ::-1]
     for bi in range(b):
         for j in range(n_lp):
             pid = order[bi, j]
             tables[bi, j] = pid
-            kp[pid] = np.asarray(kc)[bi, :, j * page:(j + 1) * page]
-            vp[pid] = np.asarray(vc)[bi, :, j * page:(j + 1) * page]
-    return jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables)
+            kp[LAYER, pid] = np.asarray(kc)[bi, :, j * page:(j + 1) * page]
+            vp[LAYER, pid] = np.asarray(vc)[bi, :, j * page:(j + 1) * page]
+    p = heads_per_row
+
+    def pack(pool):
+        pool = pool.reshape(N_LAYERS, n_pages, hkv // p, p, page, hd)
+        return pool.transpose(0, 1, 2, 4, 3, 5).reshape(
+            N_LAYERS, n_pages, hkv // p, page, p * hd)
+
+    return jnp.asarray(pack(kp)), jnp.asarray(pack(vp)), jnp.asarray(tables)
 
 
-@pytest.mark.parametrize("hkv,g,hd,page,lengths,window,kind", [
-    pytest.param(2, 4, 64, 16, [1, 17, 40, 64], 0, "gqa", id="grouped"),
-    pytest.param(16, 1, 64, 16, [0, 1, 16, 32, 64], 0, "gqa",
+@pytest.mark.parametrize("hkv,g,hd,page,lengths,window,kind,p", [
+    pytest.param(2, 4, 64, 16, [1, 17, 40, 64], 0, "gqa", 1, id="grouped"),
+    pytest.param(16, 1, 64, 16, [0, 1, 16, 32, 64], 0, "gqa", 1,
                  id="qwen-heads-edge-lengths"),
-    pytest.param(2, 4, 64, 16, [0, 1, 16, 32, 64], 0, "gqa",
+    pytest.param(2, 4, 64, 16, [0, 1, 16, 32, 64], 0, "gqa", 1,
                  id="grouped-edge-lengths"),
-    pytest.param(2, 4, 64, 16, [1, 17, 40, 64], 20, "gqa", id="window"),
-    pytest.param(2, 4, 64, 4, [0, 1, 16, 79, 80], 0, "gqa",
+    pytest.param(2, 4, 64, 16, [1, 17, 40, 64], 20, "gqa", 1, id="window"),
+    pytest.param(2, 4, 64, 4, [0, 1, 16, 79, 80], 0, "gqa", 1,
                  id="blocks-of-pages"),
-    pytest.param(2, 4, 64, 4, [1, 17, 70, 80], 9, "gqa",
+    pytest.param(2, 4, 64, 4, [1, 17, 70, 80], 9, "gqa", 1,
                  id="blocks-of-pages-window"),
-    pytest.param(1, 16, 576, 16, [0, 1, 16, 32, 64], 0, "mla", id="mla"),
-    pytest.param(16, 1, 64, 16, [1, 16, 33, 64], 0, "nan",
+    pytest.param(1, 16, 576, 16, [0, 1, 16, 32, 64], 0, "mla", 1,
+                 id="mla"),
+    pytest.param(16, 1, 64, 16, [1, 16, 33, 64], 0, "nan", 1,
                  id="nan-pages-past-length"),
+    pytest.param(16, 1, 64, 16, [0, 1, 16, 32, 64], 0, "gqa", 2,
+                 id="qwen-heads-two-a-row"),
+    pytest.param(2, 4, 64, 4, [1, 17, 70, 80], 9, "gqa", 2,
+                 id="grouped-two-a-row-window"),
+    pytest.param(4, 2, 32, 16, [1, 16, 33, 64], 0, "nan", 4,
+                 id="nan-four-a-row"),
 ])
 def test_paged_decode_kernel_matches_dense_jnp(hkv, g, hd, page, lengths,
-                                               window, kind):
+                                               window, kind, p):
     """Paged flash decode through per-slot page tables == dense jnp
     attention over the gathered view, at per-slot lengths: 0, 1, page
     boundaries and the full table; a table of 20 pages of 4 spans more
@@ -423,7 +446,8 @@ def test_paged_decode_kernel_matches_dense_jnp(hkv, g, hd, page, lengths,
     (its cache is zeros), so the reference's all-masked softmax is the
     zero vector the kernel returns. `nan`: every table entry past a
     slot's length points at a page of NaN, which the kernel must never
-    read."""
+    read. `p` > 1 packs p KV heads into each pool row, as the served
+    pools of 64-wide heads are."""
     from repro.models.layers import decode_attention_jnp, paged_view
     key = jax.random.PRNGKey(23)
     kq, kk, kv = jax.random.split(key, 3)
@@ -433,23 +457,25 @@ def test_paged_decode_kernel_matches_dense_jnp(hkv, g, hd, page, lengths,
     q = jax.random.normal(kq, (b, hkv * g, hd), jnp.float32)
     kc = jnp.where(empty, 0, jax.random.normal(kk, (b, hkv, s, hd)))
     vc = jnp.where(empty, 0, jax.random.normal(kv, (b, hkv, s, hd)))
-    kp, vp, tables = _paged_from_dense(kc, vc, page)
-    view_k = paged_view(kp, tables)
-    view_v = paged_view(vp, tables)
+    kp, vp, tables = _paged_from_dense(kc, vc, page, p)
+    view_k = paged_view(kp, LAYER, tables, hd)
+    view_v = paged_view(vp, LAYER, tables, hd)
     np.testing.assert_array_equal(np.asarray(view_k), np.asarray(kc))
     if kind == "mla":
-        out = da_ops.mla_decode_paged(q, kp, tables, lengths, scale=0.125,
-                                      interpret=True)
+        out = da_ops.mla_decode_paged(q, kp, LAYER, tables, lengths,
+                                      scale=0.125, interpret=True)
         ref = decode_attention_jnp(q, view_k, view_k, lengths, scale=0.125)
     else:
         if kind == "nan":
-            n_pages = kp.shape[0]
-            nan = jnp.full((1,) + kp.shape[1:], jnp.nan, jnp.float32)
+            n_pages = kp.shape[1]
+            nan = jnp.full(kp.shape[:1] + (1,) + kp.shape[2:], jnp.nan,
+                           jnp.float32)
             past = (jnp.arange(tables.shape[1])[None]
                     >= (lengths[:, None] + page - 1) // page)
             tables = jnp.where(past, n_pages, tables)
-            kp, vp = jnp.concatenate([kp, nan]), jnp.concatenate([vp, nan])
-        out = da_ops.gqa_decode_paged(q, kp, vp, tables, lengths,
+            kp = jnp.concatenate([kp, nan], axis=1)
+            vp = jnp.concatenate([vp, nan], axis=1)
+        out = da_ops.gqa_decode_paged(q, kp, vp, LAYER, tables, lengths,
                                       window=window, interpret=True)
         ref = decode_attention_jnp(q, view_k, view_v, lengths, window=window)
     assert np.isfinite(np.asarray(out)).all()
@@ -460,6 +486,16 @@ def test_paged_decode_kernel_matches_dense_jnp(hkv, g, hd, page, lengths,
 def test_paged_prefill_kernel_matches_dense_jnp():
     """Paged flash prefill through page tables == the dense jnp prefill
     oracle on the gathered view (staggered starts, chunk bucket 8)."""
+    _check_paged_prefill(1)
+
+
+def test_paged_prefill_kernel_reads_rows_of_two_heads():
+    """As above over pools whose rows hold two KV heads side by side, as
+    the served pools of 64-wide heads are (`layers.kv_heads_per_row`)."""
+    _check_paged_prefill(2)
+
+
+def _check_paged_prefill(p):
     from repro.kernels.prefill_attention import ops as pf_ops
     from repro.models.layers import paged_view, prefill_attention_jnp
     key = jax.random.PRNGKey(29)
@@ -468,33 +504,170 @@ def test_paged_prefill_kernel_matches_dense_jnp():
     q = jax.random.normal(kq, (b, c, hkv * g, hd), jnp.float32)
     kc = jax.random.normal(kk, (b, hkv, s, hd), jnp.float32)
     vc = jax.random.normal(kv, (b, hkv, s, hd), jnp.float32)
-    kp, vp, tables = _paged_from_dense(kc, vc, page)
+    kp, vp, tables = _paged_from_dense(kc, vc, page, p)
     start = jnp.array([0, 9, 24, 50], jnp.int32)
-    out = pf_ops.gqa_prefill_paged(q, kp, vp, tables, start,
+    out = pf_ops.gqa_prefill_paged(q, kp, vp, LAYER, tables, start,
                                    interpret=True)
-    ref = prefill_attention_jnp(q, paged_view(kp, tables),
-                                paged_view(vp, tables), start)
+    ref = prefill_attention_jnp(q, paged_view(kp, LAYER, tables, hd),
+                                paged_view(vp, LAYER, tables, hd), start)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
 
 def test_paged_insert_drops_masked_rows():
-    """paged_insert routes [B,C] column writes through page tables and
-    DROPS rows with keep=False — the write-site masking the batchless
-    shared pool relies on (garbage from inactive slots must never
-    land)."""
+    """paged_insert writes each slot's C consecutive rows into one layer
+    of the stacked pool through its page table and DROPS rows with
+    keep=False — the write-site masking the batchless shared pool relies
+    on (garbage from inactive slots must never land). Every other layer,
+    and every masked or unwritten row, keeps its bytes."""
     from repro.models.layers import paged_insert, paged_view
     hkv, page, n_lp, b, c = 2, 4, 3, 2, 4
-    pool = jnp.zeros((b * n_lp, hkv, page, 8), jnp.float32)
-    tables = jnp.asarray(np.arange(b * n_lp).reshape(b, n_lp), jnp.int32)
-    cols = jnp.asarray([[0, 1, 2, 3], [5, 6, 7, 8]], jnp.int32)
-    vals = jnp.ones((b, c, hkv, 8), jnp.float32)
+    before = jax.random.normal(jax.random.PRNGKey(5),
+                               (N_LAYERS, b * n_lp, hkv, page, 8))
+    tables = jnp.asarray(np.arange(b * n_lp).reshape(b, n_lp)[:, ::-1],
+                         jnp.int32)
+    start = jnp.asarray([0, 5], jnp.int32)     # columns 0-3 and 5-8
+    vals = jax.random.normal(jax.random.PRNGKey(6), (b, c, hkv, 8))
     keep = jnp.asarray([[True, True, False, True],
                         [True, False, True, True]])
-    out = paged_insert(pool, tables, cols, vals, keep)
-    view = np.asarray(paged_view(out, tables))    # [B, Hkv, S, hd]
-    written = (np.abs(view).sum(axis=(1, 3)) > 0)
-    expect = np.zeros((b, n_lp * page), bool)
-    expect[0, [0, 1, 3]] = True
-    expect[1, [5, 7, 8]] = True
-    np.testing.assert_array_equal(written, expect)
+    out = paged_insert(before, LAYER, tables, start, vals, keep)
+    for layer in range(N_LAYERS):
+        if layer != LAYER:
+            np.testing.assert_array_equal(np.asarray(out[layer]),
+                                          np.asarray(before[layer]))
+    view = np.asarray(paged_view(out, LAYER, tables))    # [B, Hkv, S, hd]
+    old = np.asarray(paged_view(before, LAYER, tables))
+    written = np.zeros((b, n_lp * page), bool)
+    written[0, [0, 1, 3]] = True
+    written[1, [5, 7, 8]] = True
+    np.testing.assert_array_equal(view.transpose(0, 2, 1, 3)[~written],
+                                  old.transpose(0, 2, 1, 3)[~written])
+    for bi, cols in enumerate(([0, 1, 3], [5, 7, 8])):
+        rows = [col - int(start[bi]) for col in cols]
+        want = np.asarray(vals[bi, rows]).swapaxes(0, 1)   # [Hkv, n, hd]
+        np.testing.assert_array_equal(view[bi][:, cols], want)
+
+
+def test_paged_insert_packs_heads_into_rows():
+    """Over a pool whose rows hold p KV heads side by side, paged_insert
+    puts head i of a written row in lanes [i*hd, (i+1)*hd) of pool row
+    (layer, page, head // p, offset), and paged_view reads them back as
+    separate heads."""
+    from repro.models.layers import kv_heads_per_row, paged_insert, paged_view
+    hkv, hd, page, n_lp, b = 4, 32, 4, 2, 2
+    p = kv_heads_per_row(hkv, hd)
+    assert p == 4 and kv_heads_per_row(16, 64) == 2
+    assert kv_heads_per_row(8, 128) == kv_heads_per_row(1, 64) == 1
+    pool = jnp.zeros((N_LAYERS, b * n_lp, hkv // p, page, p * hd))
+    tables = jnp.asarray([[3, 1], [0, 2]], jnp.int32)
+    start = jnp.asarray([5, 2], jnp.int32)
+    vals = jax.random.normal(jax.random.PRNGKey(7), (b, 1, hkv, hd))
+    out = paged_insert(pool, LAYER, tables, start, vals,
+                       jnp.ones((b, 1), bool))
+    for bi in range(b):
+        col = int(start[bi])
+        row = np.asarray(out[LAYER, int(tables[bi, col // page]), 0,
+                             col % page])
+        np.testing.assert_array_equal(row.reshape(p, hd),
+                                      np.asarray(vals[bi, 0]))
+    view = np.asarray(paged_view(out, LAYER, tables, hd))   # [B,Hkv,S,hd]
+    np.testing.assert_array_equal(view[[0, 1], :, [5, 2]],
+                                  np.asarray(vals[:, 0]))
+
+
+def _route_fixture(kind, step):
+    """A reduced GQA (qwen) or latent-attention (deepseek) layer, its
+    stacked pools of N_LAYERS layers filled with noise, per-slot page
+    tables (reversed), and a decode step's or a prompt chunk's inputs:
+    slot 2 inactive in decode, padded chunk tails in prefill."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.models import api as M
+    from repro.models import transformer as T
+    from repro.nn import init_params
+    if kind == "gqa":
+        cfg = dataclasses.replace(get_arch("qwen1.5-0.5b").reduced(),
+                                  dtype=jnp.float32)
+    else:
+        cfg = dataclasses.replace(
+            get_arch("deepseek-v2-lite"), n_layers=2, d_model=64, n_heads=8,
+            d_ff=128, moe_d_ff=32, vocab_size=256, n_experts=8, top_k=2,
+            kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+            dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), M.param_specs(cfg))
+    p = jax.tree.map(lambda a: a[0], params["layers"]["attn"])
+    b, page, n_lp = 4, 4, 6
+    shapes = T.paged_cache_shapes(cfg, b * n_lp, page)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(shapes))
+    pools = {name: jax.random.normal(k, (N_LAYERS,) + shp[0][1:])
+             for k, (name, shp) in zip(keys, shapes.items())}
+    tables = jnp.asarray(np.arange(b * n_lp).reshape(b, n_lp)[:, ::-1],
+                         jnp.int32)
+    c = 1 if step == "decode" else 8
+    x = jax.random.normal(jax.random.PRNGKey(2), (b, c, cfg.d_model))
+    start = jnp.asarray([0, 3, 9, 13], jnp.int32)
+    n_valid = jnp.asarray([c, c, 0, max(c - 3, 1)], jnp.int32)
+    active = None if step == "prefill" else jnp.asarray(
+        [True, True, False, True])
+    return cfg, p, pools, tables, x, start, n_valid, active
+
+
+def _route_step(kind, step, cfg, p, pools, layer, tables, x, start, n_valid,
+                active, kernel):
+    from repro.models import layers as L
+    from repro.models import mla
+    pages = {"tables": tables, "page_size": 4, "active": active,
+             "kernel": kernel}
+    if kind == "mla":
+        if step == "decode":
+            out, pool = mla.mla_decode_slots(p, x, cfg, pools["latent"],
+                                             layer, start, pages, kernel)
+        else:
+            out, pool = mla.mla_prefill_slots(p, x, cfg, pools["latent"],
+                                              layer, start, n_valid, pages,
+                                              kernel)
+        return out, {"latent": pool}
+    if step == "decode":
+        out, k, v = L.attention_decode_slots(p, x, cfg, pools["k"],
+                                             pools["v"], layer, start,
+                                             pages=pages)
+    else:
+        out, k, v = L.attention_prefill_slots(p, x, cfg, pools["k"],
+                                              pools["v"], layer, start,
+                                              n_valid, pages=pages)
+    return out, {"k": k, "v": v}
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_stacked_pool_kernel_route_matches_jnp(kind, step, layer):
+    """A serve step's attention at layer `layer` of a pool stacked over 3
+    layers: the paged kernels (interpret mode), which read the stack at
+    the layer index, give the jnp route's outputs on that layer, and
+    both routes leave the same pools — the step's rows written at that
+    layer, every other layer and every masked row (an inactive slot's,
+    a chunk's padded tail) byte-identical to before."""
+    cfg, p, pools, tables, x, start, n_valid, active = _route_fixture(
+        kind, step)
+    args = (cfg, p, pools, layer, tables, x, start, n_valid, active)
+    out_k, pools_k = _route_step(kind, step, *args, kernel=True)
+    out_j, pools_j = _route_step(kind, step, *args, kernel=False)
+    rows = np.asarray(n_valid if step == "prefill" else active)
+    np.testing.assert_allclose(np.asarray(out_k)[rows > 0],
+                               np.asarray(out_j)[rows > 0],
+                               rtol=2e-4, atol=2e-4)
+    page, C = 4, x.shape[1]
+    for name, before in pools.items():
+        new = np.asarray(pools_k[name])
+        np.testing.assert_array_equal(new, np.asarray(pools_j[name]))
+        old = np.asarray(before)
+        for other in set(range(N_LAYERS)) - {layer}:
+            np.testing.assert_array_equal(new[other], old[other])
+        kept = np.zeros(new.shape[1:4], bool)      # [n_pages, Hkv, page]
+        for b in range(x.shape[0]):
+            n = int(n_valid[b]) if step == "prefill" else int(rows[b])
+            for col in range(int(start[b]), int(start[b]) + min(n, C)):
+                kept[int(tables[b, col // page]), :, col % page] = True
+        np.testing.assert_array_equal(new[layer][~kept], old[layer][~kept])
+        assert not np.array_equal(new[layer][kept], old[layer][kept])

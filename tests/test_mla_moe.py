@@ -109,15 +109,15 @@ def test_absorbed_mla_matches_naive(kernel):
     B, S, C, page, n_lp = 2, 12, 8, 4, 4
     x = jax.random.normal(jax.random.PRNGKey(3), (B, S, cfg.d_model))
     want = mla.mla_train(p, x, cfg)
-    pool = jnp.zeros((B * n_lp, 1, page, mla.pool_width(cfg)))
+    pool = jnp.zeros((1, B * n_lp, 1, page, mla.pool_width(cfg)))
     pages = _pages(B, n_lp, page)
-    got, pool = mla.mla_prefill_slots(p, x[:, :C], cfg, pool,
+    got, pool = mla.mla_prefill_slots(p, x[:, :C], cfg, pool, 0,
                                       jnp.zeros(B, jnp.int32),
                                       jnp.full((B,), C, jnp.int32), pages,
                                       kernel)
     outs = [got]
     for i in range(C, S):
-        o, pool = mla.mla_decode_slots(p, x[:, i:i + 1], cfg, pool,
+        o, pool = mla.mla_decode_slots(p, x[:, i:i + 1], cfg, pool, 0,
                                        jnp.full((B,), i, jnp.int32),
                                        pages, kernel)
         outs.append(o)

@@ -5,14 +5,23 @@ would refuse (tile alignment, unsupported casts, too much VMEM) fails
 here at no chip time. The topology is described inside a fixture — never
 at import — so that every xdist worker collects the same tests and only
 the worker given this file loads the TPU library."""
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-# qwen1.5-0.5b serving widths: 16 query and 16 KV heads of 64, 16-token
-# pages, 8 slots, bfloat16 caches; a 32-token prefill chunk
+# qwen1.5-0.5b serving widths: 16 query and 16 KV heads of 64, two KV
+# heads a 128-lane pool row (`layers.kv_heads_per_row`), 16-token pages,
+# 8 slots, bfloat16 caches; a 32-token prefill chunk; the kernels read
+# one layer of pools stacked over N_LAYERS, at index LAYER
 B, H, HD, PAGE, N_LP, CHUNK = 8, 16, 64, 16, 8, 32
+P = 2
+N_LAYERS = 3
+LAYER = ((), jnp.int32)
 BF16 = jnp.bfloat16
 # (slots, pages a slot, chunk) of the smoke run above and of the
 # qwen05b-serve-decode cell: 32 slots of 9 pages (prompts to 35 tokens,
@@ -58,17 +67,18 @@ def test_paged_attention_kernels_compile(one_chip, kind, shape):
     from repro.kernels.decode_attention.ops import gqa_decode_paged
     from repro.kernels.prefill_attention.ops import gqa_prefill_paged
     b, n_lp, chunk = SERVE_SHAPES[shape]
-    pool = ((b * n_lp, H, PAGE, HD), BF16)
+    pool = ((N_LAYERS, b * n_lp, H // P, PAGE, P * HD), BF16)
     tables = ((b, n_lp), jnp.int32)
     pos = ((b,), jnp.int32)
     if kind == "decode":
-        _compile(lambda q, k, v, t, n: gqa_decode_paged(
-            q, k, v, t, n, interpret=False),
-            [((b, H, HD), BF16), pool, pool, tables, pos], one_chip)
+        _compile(lambda q, k, v, l, t, n: gqa_decode_paged(
+            q, k, v, l, t, n, interpret=False),
+            [((b, H, HD), BF16), pool, pool, LAYER, tables, pos], one_chip)
     else:
-        _compile(lambda q, k, v, t, s: gqa_prefill_paged(
-            q, k, v, t, s, interpret=False),
-            [((b, chunk, H, HD), BF16), pool, pool, tables, pos], one_chip)
+        _compile(lambda q, k, v, l, t, s: gqa_prefill_paged(
+            q, k, v, l, t, s, interpret=False),
+            [((b, chunk, H, HD), BF16), pool, pool, LAYER, tables, pos],
+            one_chip)
 
 
 def _paper_fl_rows() -> int:
@@ -114,17 +124,18 @@ MLA_B, MLA_H, MLA_D, MLA_NLP = 64, 16, 576, 9
 def test_mla_paged_kernels_compile(one_chip, kind):
     from repro.kernels.decode_attention.ops import mla_decode_paged
     from repro.kernels.prefill_attention.ops import mla_prefill_paged
-    pool = ((MLA_B * MLA_NLP, 1, PAGE, MLA_D), BF16)
+    pool = ((N_LAYERS, MLA_B * MLA_NLP, 1, PAGE, MLA_D), BF16)
     tables = ((MLA_B, MLA_NLP), jnp.int32)
     pos = ((MLA_B,), jnp.int32)
     if kind == "decode":
-        c = _compile(lambda q, p, t, n: mla_decode_paged(
-            q, p, t, n, scale=0.1147, interpret=False),
-            [((MLA_B, MLA_H, MLA_D), BF16), pool, tables, pos], one_chip)
+        c = _compile(lambda q, p, l, t, n: mla_decode_paged(
+            q, p, l, t, n, scale=0.1147, interpret=False),
+            [((MLA_B, MLA_H, MLA_D), BF16), pool, LAYER, tables, pos],
+            one_chip)
     else:
-        c = _compile(lambda q, p, t, s: mla_prefill_paged(
-            q, p, t, s, scale=0.1147, interpret=False),
-            [((MLA_B, 64, MLA_H, MLA_D), BF16), pool, tables, pos],
+        c = _compile(lambda q, p, l, t, s: mla_prefill_paged(
+            q, p, l, t, s, scale=0.1147, interpret=False),
+            [((MLA_B, 64, MLA_H, MLA_D), BF16), pool, LAYER, tables, pos],
             one_chip)
     assert f"mla_{kind}_paged" in c.as_text()
 
@@ -142,15 +153,15 @@ def test_paged_decode_kernels_compile_at_long_table(one_chip, kind):
     tables = ((B, LONG_NLP), jnp.int32)
     pos = ((B,), jnp.int32)
     if kind == "gqa":
-        pool = ((B * LONG_NLP, H, PAGE, HD), BF16)
-        c = _compile(lambda q, k, v, t, n: gqa_decode_paged(
-            q, k, v, t, n, interpret=False),
-            [((B, H, HD), BF16), pool, pool, tables, pos], one_chip)
+        pool = ((N_LAYERS, B * LONG_NLP, H // P, PAGE, P * HD), BF16)
+        c = _compile(lambda q, k, v, l, t, n: gqa_decode_paged(
+            q, k, v, l, t, n, interpret=False),
+            [((B, H, HD), BF16), pool, pool, LAYER, tables, pos], one_chip)
     else:
-        pool = ((B * LONG_NLP, 1, PAGE, MLA_D), BF16)
-        c = _compile(lambda q, p, t, n: mla_decode_paged(
-            q, p, t, n, scale=0.1147, interpret=False),
-            [((B, MLA_H, MLA_D), BF16), pool, tables, pos], one_chip)
+        pool = ((N_LAYERS, B * LONG_NLP, 1, PAGE, MLA_D), BF16)
+        c = _compile(lambda q, p, l, t, n: mla_decode_paged(
+            q, p, l, t, n, scale=0.1147, interpret=False),
+            [((B, MLA_H, MLA_D), BF16), pool, LAYER, tables, pos], one_chip)
     assert f"{kind}_decode_paged" in c.as_text()
 
 
@@ -163,3 +174,125 @@ def test_moe_gmm_kernel_compiles(one_chip, rows, proj):
                  [((rows, k), BF16), ((1, 8, k, n), BF16), ((8,), jnp.int32)],
                  one_chip)
     assert f"%{KERNEL_NAME}." in c.as_text()
+
+
+# The serve steps of the benchmark's attention cells, whole (abstract
+# weights, the kernel route), compiled for the chip: B's decode and
+# 64-token prefill (32 slots x 9 pages of 16), deepseek-v2-lite's decode
+# (64 slots x 9 pages, the 8 experts one chip of EP8 holds).
+SERVE_PROGRAMS = {"qwen-decode": ("qwen1.5-0.5b", 32, "decode"),
+                  "qwen-prefill": ("qwen1.5-0.5b", 32, "prefill"),
+                  "dsv2lite-decode": ("deepseek-v2-lite", 64, "decode")}
+# an array-valued HLO instruction: name, element type, dims, layout
+_ARRAY = re.compile(r"%([\w.\-]+) = (\w+)\[([\d,]*)\]\{([^}]*)\} ([\w-]+)\(")
+
+
+@pytest.fixture(scope="module")
+def serve_programs(one_chip):
+    """{program: (compiled HLO text, stacked pool shape, pool count)},
+    each program compiled once, when a test first asks for it."""
+    texts = {}
+
+    def get(name):
+        if name not in texts:
+            texts[name] = _compile_serve_step(one_chip, *SERVE_PROGRAMS[name])
+        return texts[name]
+
+    return get
+
+
+def _compile_serve_step(sharding, arch, slots, kind):
+    from repro.configs import get_arch
+    from repro.configs.base import ShapeConfig
+    from repro.models.api import param_specs
+    from repro.nn import shapes_tree
+    from repro.runtime import serve_step as SS
+    cfg = get_arch(arch)
+    if cfg.is_moe:
+        cfg = dataclasses.replace(cfg, experts_held=(0, 8),
+                                  param_dtype=BF16)
+    n_lp, page, chunk = 9, PAGE, 64
+    n_pages = slots * n_lp
+    sc = ShapeConfig("serve", n_lp * page, slots, "decode")
+
+    def sds(shape, dtype, sharding=sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          shapes_tree(param_specs(cfg)))
+    cache = {k: sds(a.shape, a.dtype) for k, a in
+             SS.paged_cache_specs(cfg, n_pages, page)[0].items()}
+    i32 = jnp.int32
+    if kind == "decode":
+        step = SS.make_paged_decode_step(cfg, sc, page, kernel=True,
+                                         stats=True)
+        args = (params, cache, sds((slots, 1), i32), sds((slots,), i32),
+                sds((slots, n_lp), i32), sds((slots,), jnp.bool_))
+    else:
+        step = SS.make_paged_prefill_step(cfg, sc, page, kernel=True,
+                                          stats=True)
+        args = (params, cache, sds((slots, chunk), i32), sds((slots,), i32),
+                sds((slots,), i32), sds((slots, n_lp), i32))
+    # the kernels resolve to interpret mode off a TPU; compile them for it
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(step).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    shape = next(iter(cache.values())).shape
+    return text, shape, len(cache)
+
+
+def _arrays(text):
+    """(name, dims without unit dims, minor-to-major order of those dims,
+    tiling and memory space, opcode) of each array-valued instruction."""
+    out = []
+    for name, _, dims, layout, op in _ARRAY.findall(text):
+        dims = [int(d) for d in dims.split(",") if d]
+        m2m, _, tiling = layout.partition(":")
+        keep = [i for i, d in enumerate(dims) if d != 1]
+        order = [keep.index(int(i)) for i in m2m.split(",")
+                 if i and int(i) in keep]
+        out.append((name, tuple(dims[i] for i in keep), tuple(order),
+                    tiling, op))
+    return out
+
+
+def _squeeze(shape):
+    return tuple(d for d in shape if d != 1)
+
+
+@pytest.mark.parametrize("program", list(SERVE_PROGRAMS))
+def test_serve_step_makes_no_layer_slice_of_the_pool(serve_programs,
+                                                     program):
+    """No instruction of the step outputs one layer of the pool, whatever
+    its shape: the layer loop neither slices the pool nor writes a layer
+    back, and nothing relays a layer out for the kernels or the write."""
+    text, shape, _ = serve_programs(program)
+    layer = math.prod(shape[1:])
+    layer_sized = [(name, dims, op) for name, dims, _, _, op
+                   in _arrays(text) if math.prod(dims) == layer]
+    assert not layer_sized
+
+
+@pytest.mark.parametrize("program", list(SERVE_PROGRAMS))
+def test_serve_step_keeps_one_pool_layout(serve_programs, program):
+    """The stacked pool has one layout from the program's entry through
+    the layer loop to the kernels and out: row-major, the layout the
+    paged kernels read."""
+    text, shape, _ = serve_programs(program)
+    layouts = {(order, tiling) for _, dims, order, tiling, _
+               in _arrays(text) if dims == _squeeze(shape)}
+    rank = len(_squeeze(shape))
+    assert {order for order, _ in layouts} == {tuple(range(rank))[::-1]}
+    assert len(layouts) == 1, layouts
+
+
+@pytest.mark.parametrize("program", list(SERVE_PROGRAMS))
+def test_serve_step_copies_each_pool_at_most_once(serve_programs, program):
+    """At most one whole-pool copy a pool: the copy of the step's input,
+    which the program does not donate."""
+    text, shape, n_pools = serve_programs(program)
+    copies = [name for name, dims, _, _, op in _arrays(text)
+              if op in ("copy", "transpose") and
+              math.prod(dims) == math.prod(shape)]
+    assert len(copies) <= n_pools, copies
